@@ -23,18 +23,15 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 )
 
-from tpusnap.test_utils import apply_platform_env
-
-apply_platform_env()
-
 import jax
 
-from tpusnap import PytreeState, Snapshot
+from tpusnap import PytreeState, Snapshot, compile_cache
 from tpusnap.models import EmbeddingCollection, TableConfig, make_mesh
 from tpusnap.rss_profiler import measure_rss_deltas
 
 
 def main() -> None:
+    compile_cache.enable()
     parser = argparse.ArgumentParser()
     parser.add_argument("--rows", type=int, default=1_000_000)
     parser.add_argument("--dim", type=int, default=64)

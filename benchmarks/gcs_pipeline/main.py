@@ -37,10 +37,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__
 sys.path.insert(0, _REPO)
 sys.path.insert(0, os.path.join(_REPO, "tests"))
 
-from tpusnap.test_utils import apply_platform_env
-
-apply_platform_env()
-
 
 def main() -> None:
     parser = argparse.ArgumentParser()

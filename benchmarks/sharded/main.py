@@ -21,19 +21,16 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 )
 
-from tpusnap.test_utils import apply_platform_env
-
-apply_platform_env()
-
 import jax
 import jax.numpy as jnp
 
-from tpusnap import PytreeState, Snapshot
+from tpusnap import PytreeState, Snapshot, compile_cache
 from tpusnap.models import Transformer, TransformerConfig, make_mesh
 from tpusnap.models.transformer import init_train_state
 
 
 def main() -> None:
+    compile_cache.enable()
     parser = argparse.ArgumentParser()
     parser.add_argument("--d-model", type=int, default=1024)
     parser.add_argument("--n-layers", type=int, default=8)

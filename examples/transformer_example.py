@@ -22,23 +22,19 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tpusnap.test_utils import apply_platform_env
-
-apply_platform_env()
-
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from tpusnap import PytreeState, Snapshot, StateDict
+from tpusnap import PytreeState, Snapshot, StateDict, compile_cache
 from tpusnap.models import Transformer, TransformerConfig, make_mesh, make_train_step
-from tpusnap.models.transformer import init_train_state
+from tpusnap.models.transformer import init_train_state, random_tokens
 
 NUM_EPOCHS = 3
 STEPS_PER_EPOCH = 4
 
 
 def main() -> None:
+    compile_cache.enable()
     parser = argparse.ArgumentParser()
     parser.add_argument("--work-dir", default=None)
     parser.add_argument("--resume-from", default=None)
@@ -70,11 +66,6 @@ def main() -> None:
     else:
         pending_restore = None
 
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    token_sharding = NamedSharding(
-        mesh, P("data", "fsdp") if use_ring else P(("data", "fsdp"), None)
-    )
     rng = np.random.default_rng(0)
     if pending_restore is not None:
         pending_restore.wait()  # reads overlapped the setup above
@@ -83,12 +74,7 @@ def main() -> None:
     while progress["epoch"] < NUM_EPOCHS:
         state = train.tree
         for _ in range(STEPS_PER_EPOCH):
-            tokens = jax.device_put(
-                jnp.asarray(
-                    rng.integers(0, cfg.vocab_size, (4, 32)), dtype=jnp.int32
-                ),
-                token_sharding,
-            )
+            tokens = random_tokens(cfg, mesh, rng, batch=4, seq_len=32)
             state, loss = train_step(state, tokens)
         train.tree = state
         progress["epoch"] += 1

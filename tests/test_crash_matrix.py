@@ -220,8 +220,7 @@ def _run_window(tmp_path, window: str, seed: int, extra_env=None) -> None:
     try:
         # Wait for the child to enter the window — via select, so a
         # wedged-silent child hits the deadline instead of blocking
-        # readline() forever (the pipe-wedge class _subproc.py exists
-        # for).
+        # readline() forever.
         buf = ""
         deadline = time.monotonic() + 120
         marked = eof = False

@@ -6,6 +6,7 @@ breaks them must fail the suite, not a user.
 import os
 import subprocess
 import sys
+import tempfile
 
 import pytest
 
@@ -18,14 +19,18 @@ def _run_example(name, *args, timeout=300):
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     ).strip()
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "examples", name), *args],
-        capture_output=True,
-        text=True,
-        timeout=timeout,
-        cwd=REPO,
-        env=env,
-    )
+    # The examples turn the persistent compile cache on; keep it out of
+    # the checkout so one test run cannot change the next.
+    with tempfile.TemporaryDirectory() as cache_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+        proc = subprocess.run(
+            [sys.executable, os.path.join(REPO, "examples", name), *args],
+            capture_output=True,
+            text=True,
+            timeout=timeout,
+            cwd=REPO,
+            env=env,
+        )
     assert proc.returncode == 0, (
         f"{name} failed (rc={proc.returncode})\n"
         f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"

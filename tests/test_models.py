@@ -44,10 +44,8 @@ class TestRingAttention:
         )
         ref = _dense_causal_attention(q, k, v)
         spec = P("data", "fsdp", "tensor", None)
-        from tpusnap.models.transformer import _shard_map
-
         fn = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 functools.partial(ring_attention, axis_name="fsdp", causal=True),
                 mesh=mesh,
                 in_specs=(spec, spec, spec),
@@ -188,7 +186,9 @@ class TestFlashAttention:
             jax.random.normal(kk, shape, jnp.float32)
             for kk in jax.random.split(jax.random.PRNGKey(1), 3)
         )
-        out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+        out = flash_attention(
+            q, k, v, causal=causal, block_q=64, block_k=64, interpret=True
+        )
         ref = _attention_reference(q, k, v, causal)
         np.testing.assert_allclose(out, ref, atol=2e-5)
 
@@ -200,21 +200,45 @@ class TestFlashAttention:
             jax.random.normal(kk, (1, 32, 2, 16), jnp.float32)
             for kk in jax.random.split(jax.random.PRNGKey(2), 3)
         )
-        g = jax.grad(lambda *a: flash_attention(*a).sum(), argnums=(0, 1, 2))(
-            q, k, v
-        )
+        g = jax.grad(
+            lambda *a: flash_attention(*a, interpret=True).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
         gr = jax.grad(
             lambda *a: _attention_reference(*a, True).sum(), argnums=(0, 1, 2)
         )(q, k, v)
         for got, want in zip(g, gr):
             np.testing.assert_allclose(got, want, atol=2e-5)
 
+    def test_nothing_selects_the_interpreter_implicitly(self, monkeypatch):
+        """Without an explicit interpret request the kernel goes to
+        Mosaic — which the CPU backend refuses — and "auto" never maps a
+        backend it does not know to some fallback."""
+        from tpusnap.ops import flash_attention
+
+        q = jnp.ones((1, 16, 2, 8), jnp.float32)
+        with pytest.raises(ValueError, match="interpret mode"):
+            flash_attention(q, q, q)
+        tokens = jnp.zeros((1, 16), jnp.int32)
+        tiny = dict(
+            vocab_size=128, d_model=32, n_heads=2, n_layers=1, d_ff=64, max_seq_len=16
+        )
+        model = Transformer(TransformerConfig(**tiny, attention_impl="flash"))
+        params = model.init(jax.random.PRNGKey(0))
+        with pytest.raises(ValueError, match="interpret mode"):
+            model.apply(params, tokens)
+        auto = Transformer(TransformerConfig(**tiny))  # "auto": XLA path on cpu
+        assert auto.apply(params, tokens).shape == (1, 16, 128)
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="does not know backend 'gpu'"):
+            auto.apply(params, tokens)
+
     def test_model_forward_flash_vs_reference(self):
         tokens = jnp.asarray(
             np.random.default_rng(0).integers(0, 128, (2, 16)), jnp.int32
         )
         logits = {}
-        for impl in ("flash", "reference"):
+        for impl in ("flash_interpret", "reference"):
             cfg = TransformerConfig(
                 vocab_size=128,
                 d_model=32,
@@ -229,5 +253,5 @@ class TestFlashAttention:
             params = model.init(jax.random.PRNGKey(0))
             logits[impl] = model.apply(params, tokens)
         np.testing.assert_allclose(
-            logits["flash"], logits["reference"], atol=1e-4
+            logits["flash_interpret"], logits["reference"], atol=1e-4
         )

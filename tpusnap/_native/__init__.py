@@ -1,9 +1,15 @@
 """ctypes bindings for tpusnap's native C++ helpers, compiled on demand.
 
 The .so is built from src/tpusnap_native.cpp with g++ the first time it is
-needed (or when the source is newer than the binary). Every entry point has
-a pure-Python fallback, and ``TPUSNAP_DISABLE_NATIVE=1`` forces the
-fallbacks — so the library works (slower) without a toolchain.
+needed on a host. Its file name carries a key — a hash of the source, the
+compiler flags and this host's CPU feature set — and only the file with
+this host's key is ever ``dlopen``ed: the build uses ``-march=native``, so a
+binary copied in from another machine (a checkout synced to a different
+host, a stale build of older source) must never be loaded — it can fault
+on its first instruction. A binary under any other name is ignored and
+the library is rebuilt here. Every entry point has a pure-Python fallback,
+and ``TPUSNAP_DISABLE_NATIVE=1`` forces the fallbacks — so the library
+works (slower) without a toolchain; a failed build logs at WARNING.
 
 ctypes releases the GIL around foreign calls, which is the whole point:
 file writes, ranged reads, and large memcpys run concurrently with Python
@@ -12,8 +18,10 @@ threads, the role torch's native ops play in the reference
 """
 
 import ctypes
+import hashlib
 import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional, Tuple
@@ -23,39 +31,62 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_DIR, "src", "tpusnap_native.cpp")
-_SO = os.path.join(_DIR, "libtpusnap_native.so")
+_CXXFLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-pthread", "-std=c++17")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
+# True when THIS process compiled the library it loaded (as opposed to
+# finding a binary with this host's key already in place).
+_built_in_process = False
 _lock = threading.Lock()
 
 
-def _build() -> bool:
-    # Link to a temp path, then rename into place: the final .so may
-    # already be dlopen-mapped (by this or another process), and letting
-    # the linker truncate a live mapping corrupts it. os.replace gives the
-    # new build a fresh inode, so a subsequent CDLL(_SO) maps the new
-    # library instead of returning glibc's cached handle for the old one.
-    tmp = f"{_SO}.tmp.{os.getpid()}"
-    cmd = [
-        "g++",
-        "-O3",
-        "-march=native",
-        "-shared",
-        "-fPIC",
-        "-pthread",
-        "-std=c++17",
-        "-o",
-        tmp,
-        _SRC,
-    ]
+def _host_cpu_features() -> str:
+    """What ``-march=native`` resolves against: the architecture plus the
+    kernel's feature list for the first CPU (``flags`` on x86,
+    ``Features`` on arm). Empty feature list where /proc is absent."""
+    features = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    features = " ".join(sorted(line.split(":", 1)[1].split()))
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {features}"
+
+
+def library_path(native_dir: str = _DIR) -> str:
+    """Path of the one binary this host may load from ``native_dir``:
+    keyed by source bytes, compiler flags and host CPU features."""
+    h = hashlib.sha256()
+    with open(os.path.join(native_dir, "src", "tpusnap_native.cpp"), "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_host_cpu_features().encode())
+    return os.path.join(native_dir, f"libtpusnap_native.{h.hexdigest()[:16]}.so")
+
+
+def _build(so_path: str) -> bool:
+    # Link to a temp path, then rename into place: another process may be
+    # building the same key concurrently, and a half-written .so must
+    # never be visible under the final name.
+    src = os.path.join(os.path.dirname(so_path), "src", "tpusnap_native.cpp")
+    tmp = f"{so_path}.tmp.{os.getpid()}"
+    cmd = ["g++", *_CXXFLAGS, "-o", tmp, src]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
+        os.replace(tmp, so_path)
         return True
-    except Exception as e:  # toolchain missing/failed: fall back to Python
-        logger.warning("tpusnap native build failed (%s); using Python fallbacks", e)
+    except (OSError, subprocess.SubprocessError) as e:
+        # Toolchain missing or failed: fall back to Python.
+        detail = getattr(e, "stderr", b"") or b""
+        logger.warning(
+            "tpusnap native build failed (%s %s); using Python fallbacks",
+            e,
+            detail.decode(errors="replace")[-500:],
+        )
         try:
             os.unlink(tmp)
         except OSError:
@@ -64,7 +95,7 @@ def _build() -> bool:
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _load_attempted
+    global _lib, _load_attempted, _built_in_process
     with _lock:
         if _load_attempted:
             return _lib
@@ -73,46 +104,35 @@ def _load() -> Optional[ctypes.CDLL]:
 
         if is_native_disabled():
             return None
-        stale = not os.path.exists(_SO) or (
-            os.path.exists(_SRC) and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        )
+        so_path = library_path(_DIR)
         built = False
-        if stale:
-            if not _build():
+        if not os.path.exists(so_path):
+            if not _build(so_path):
                 return None
             built = True
-        # A cached .so from an older source revision can pass the mtime
-        # check (cp/checkout preserve equal mtimes) yet lack newer symbols.
-        # On missing symbols, rebuild once and retry — unless this .so was
-        # just built from current source, where a second identical build
-        # cannot help and the Python fallbacks are the only option.
-        for _ in range(2):
-            try:
-                lib = ctypes.CDLL(_SO)
-            except OSError as e:
-                logger.warning("tpusnap native load failed (%s)", e)
-                return None
-            try:
-                _bind(lib)
-            except AttributeError as e:
-                if built:
-                    logger.warning(
-                        "tpusnap native .so is missing expected symbols "
-                        "(%s); using Python fallbacks",
-                        e,
-                    )
-                    return None
-                logger.warning(
-                    "tpusnap native .so is missing expected symbols; "
-                    "rebuilding"
-                )
-                if not _build():
-                    return None
-                built = True
-                continue
-            _lib = lib
-            return _lib
-        return None
+        try:
+            lib = ctypes.CDLL(so_path)
+            _bind(lib)
+        except (OSError, AttributeError) as e:
+            logger.warning(
+                "tpusnap native load failed (%s); using Python fallbacks", e
+            )
+            return None
+        _lib = lib
+        _built_in_process = built
+        return _lib
+
+
+def build_info() -> dict:
+    """How the native engine came to be loaded in this process:
+    ``loaded``, the ``path`` that was opened, and ``built_in_process``
+    (False when a binary with this host's key was already in place)."""
+    lib = _load()
+    return {
+        "loaded": lib is not None,
+        "path": lib._name if lib is not None else None,
+        "built_in_process": _built_in_process,
+    }
 
 
 def _bind(lib: ctypes.CDLL) -> None:

@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from . import telemetry
 from .io_types import (
     BufferConsumer,
     BufferStager,
@@ -187,6 +188,9 @@ class DeviceBatchedBufferStager(BufferStager):
                 return await loop.run_in_executor(executor, self._stage_blocking)
             return self._stage_blocking()
         except Exception as e:
+            # Counted as well as logged, so a run that expects the
+            # device path (chip_smoke.py) can assert it saw none.
+            telemetry.incr("batcher.device_pack_fallbacks")
             logger.warning(
                 "device slab packing failed (%s); falling back to host packing", e
             )
@@ -195,12 +199,11 @@ class DeviceBatchedBufferStager(BufferStager):
             )
 
     def _stage_blocking(self) -> BufferType:
-        import numpy as np
-
         from .knobs import is_checksum_disabled
 
         packed = _pack_on_device(tuple(s.arr for _, _, s in self.members))
-        host = np.asarray(packed)  # the single DtoH DMA
+        with telemetry.span("dtoh", bytes=self.total, slab_members=len(self.members)):
+            host = np.asarray(packed)  # the single DtoH DMA
         if host.nbytes != self.total:
             raise RuntimeError(
                 f"device-packed slab is {host.nbytes} bytes, expected {self.total}"

@@ -69,18 +69,11 @@ class KVStore(abc.ABC):
             time.sleep(_POLL_INTERVAL_SEC)
 
 
-def _client_try_get(client, full_key: str, probe_timeout_ms: int = 50):
-    """Non-blocking-ish single-key get against the coordination client.
-
-    Newer JAX exposes ``key_value_try_get``; older clients (including
-    jaxlib 0.4.x) only have ``blocking_key_value_get``, which raises on
-    timeout — probe with a short deadline there. Returns None when the
-    key is absent (or the service errored)."""
-    getter = getattr(client, "key_value_try_get", None)
+def _client_try_get(client, full_key: str):
+    """Non-blocking single-key get against the coordination client.
+    Returns None when the key is absent (or the service errored)."""
     try:
-        if getter is not None:
-            return getter(full_key)
-        return client.blocking_key_value_get(full_key, probe_timeout_ms)
+        return client.key_value_try_get(full_key)
     except Exception:
         return None
 
@@ -104,20 +97,11 @@ class CoordinationKVStore(KVStore):
         import base64
 
         payload = base64.b64encode(value).decode()
-        try:
-            # Overwrite semantics: lease/heartbeat republishes and
-            # elastic-stream membership transitions rewrite the SAME
-            # key — the coordination service's default insert-only
-            # key_value_set rejects the second write (ALREADY_EXISTS).
-            self._client.key_value_set(
-                self._k(key), payload, allow_overwrite=True
-            )
-        except TypeError:
-            # Older clients lack the kwarg: emulate with delete+insert
-            # (non-atomic, but every overwriting caller here tolerates
-            # a reader seeing the brief gap as "absent").
-            self._client.key_value_delete(self._k(key))
-            self._client.key_value_set(self._k(key), payload)
+        # Overwrite semantics: lease/heartbeat republishes and
+        # elastic-stream membership transitions rewrite the SAME key —
+        # the coordination service's default insert-only key_value_set
+        # rejects the second write (ALREADY_EXISTS).
+        self._client.key_value_set(self._k(key), payload, allow_overwrite=True)
 
     def try_get(self, key: str) -> Optional[bytes]:
         import base64

@@ -21,6 +21,7 @@ Differences by design:
 from __future__ import annotations
 
 import asyncio
+import logging
 import math
 from concurrent.futures import Executor
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -44,6 +45,8 @@ from ..serialization import (
     dtype_to_string,
     tensor_nbytes,
 )
+
+logger = logging.getLogger(__name__)
 
 ArrayLike = object  # jax.Array | np.ndarray
 
@@ -69,11 +72,27 @@ def enqueue_dtoh(arr: ArrayLike) -> None:
     (io_preparers/tensor.py:257-259)."""
     from ..host_offload import is_host_resident
 
-    if isinstance(arr, jax.Array) and not is_host_resident(arr):
-        try:
-            arr.copy_to_host_async()
-        except Exception:
-            pass  # some platforms/arrays don't support it; asarray will block
+    if not isinstance(arr, jax.Array) or is_host_resident(arr):
+        return
+    try:
+        arr.copy_to_host_async()
+    except Exception as e:
+        # Staging still works (np.asarray blocks on the transfer), but
+        # the prefetch the design relies on did not happen.
+        logger.warning(
+            "copy_to_host_async failed on %s (%s: %s); device→host "
+            "transfer will not be prefetched",
+            next(iter(arr.devices())).platform,
+            type(e).__name__,
+            e,
+        )
+        return
+    from .. import telemetry
+
+    # Counted at enqueue: a member the batcher later packs on device is
+    # transferred a second time inside the slab, and only the two counts
+    # together show that.
+    telemetry.incr("dtoh.enqueued_bytes", array_nbytes(arr))
 
 
 class ArrayBufferStager(BufferStager):
@@ -532,8 +551,8 @@ class ArrayBufferStager(BufferStager):
 
 # platform name -> does np.asarray of a device array ALIAS the XLA
 # buffer (vs materializing a fresh host copy)? Probed empirically once
-# per backend (VERDICT r4: a hardcoded platform assumption here decides
-# whether every async take pays a full clone pass).
+# per backend: a hardcoded platform assumption here would decide
+# whether every async take pays a full clone pass.
 _ASARRAY_ALIASES_BY_PLATFORM: dict = {}
 
 
@@ -542,9 +561,9 @@ def _asarray_aliases_device_buffer(device) -> bool:
     VIEW of the XLA buffer (CPU backends: zero-copy, so donation could
     overwrite it) or a fresh host copy (real TPU/GPU: DtoH materializes
     new host memory donation never touches). Compares the host array's
-    data pointer against the device buffer's; platforms whose runtime
-    can't report a buffer pointer (e.g. remote/proxied PJRT) fall back
-    to the platform heuristic — only local "cpu" aliases."""
+    data pointer against the device buffer's. A runtime that cannot
+    answer the probe gets the safe result, "may alias" (async takes
+    clone), logged once — never a guess from the platform's name."""
     platform = getattr(device, "platform", "unknown")
     cached = _ASARRAY_ALIASES_BY_PLATFORM.get(platform)
     if cached is not None:
@@ -556,8 +575,15 @@ def _asarray_aliases_device_buffer(device) -> bool:
             host.__array_interface__["data"][0]
             == probe.unsafe_buffer_pointer()
         )
-    except Exception:
-        aliases = platform == "cpu"
+    except Exception as e:
+        logger.warning(
+            "cannot probe whether np.asarray aliases %s device buffers "
+            "(%s: %s); async takes will clone",
+            platform,
+            type(e).__name__,
+            e,
+        )
+        aliases = True
     _ASARRAY_ALIASES_BY_PLATFORM[platform] = aliases
     return aliases
 

@@ -39,16 +39,15 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention
-
-# jax.shard_map was promoted to the top-level namespace in newer JAX;
-# older versions expose it under jax.experimental.shard_map.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
 from ..ops.ring_attention import ring_attention
 
 Params = Dict[str, Any]
+
+_ATTENTION_IMPLS = ("auto", "flash", "flash_interpret", "reference")
+# What "auto" means per backend. This table is the only place in the
+# model that decides from the backend; a backend it does not list is an
+# error, never a quiet fallback.
+_AUTO_ATTENTION_IMPL = {"tpu": "flash", "cpu": "reference"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,15 +62,16 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16  # compute dtype (MXU-friendly)
     param_dtype: Any = jnp.float32
     use_ring_attention: bool = False  # shard the sequence over "fsdp" (CP)
-    # Non-ring attention implementation: "auto" → Pallas flash kernel on
-    # TPU backends, plain-XLA online softmax elsewhere; "flash" forces
-    # the Pallas kernel (interpreter mode off-TPU); "reference" forces
-    # the XLA path.
+    # Non-ring attention implementation: "auto" → compiled Pallas flash
+    # kernel on the "tpu" backend, plain-XLA online softmax on "cpu", an
+    # error on any other backend; "flash" is the compiled kernel (fails
+    # to lower off-TPU); "flash_interpret" runs the kernel in the Pallas
+    # interpreter (CPU tests); "reference" forces the XLA path.
     attention_impl: str = "auto"
     rope_theta: float = 10000.0
 
     def __post_init__(self) -> None:
-        if self.attention_impl not in ("auto", "flash", "reference"):
+        if self.attention_impl not in _ATTENTION_IMPLS:
             raise ValueError(f"unknown attention_impl: {self.attention_impl!r}")
 
     @property
@@ -198,7 +198,7 @@ class Transformer:
             # Heads are independent (no collective on "tensor"); K/V blocks
             # rotate over the "fsdp" ring.
             spec = P("data", "fsdp", "tensor", None)
-            out = _shard_map(
+            out = jax.shard_map(
                 functools.partial(ring_attention, axis_name="fsdp", causal=True),
                 mesh=mesh,
                 in_specs=(spec, spec, spec),
@@ -207,11 +207,19 @@ class Transformer:
         else:
             impl = cfg.attention_impl
             if impl == "auto":
-                impl = "flash" if jax.default_backend() == "tpu" else "reference"
-            if impl == "flash":
-                out = flash_attention(q, k, v, causal=True)
-            else:
+                backend = jax.default_backend()
+                if backend not in _AUTO_ATTENTION_IMPL:
+                    raise RuntimeError(
+                        f'attention_impl="auto" does not know backend '
+                        f"{backend!r}; choose one of {_ATTENTION_IMPLS[1:]}"
+                    )
+                impl = _AUTO_ATTENTION_IMPL[backend]
+            if impl == "reference":
                 out = ring_attention(q, k, v, axis_name=None, causal=True)
+            else:
+                out = flash_attention(
+                    q, k, v, causal=True, interpret=impl == "flash_interpret"
+                )
         out = out.reshape(b, s, cfg.d_model)
         return jnp.einsum("bsd,dz->bsz", out, lp["wo"].astype(cfg.dtype))
 
@@ -295,17 +303,10 @@ def make_train_step(model: Transformer, mesh: Mesh, learning_rate: float = 1e-3)
     """Jitted SPMD train step ``(state, tokens) -> (state, loss)``.
 
     ``state = {"params": ..., "opt": {"mu": ..., "nu": ..., "step": ...}}``
-    (Adam; f32 moments sharded like their params). Token sharding:
-    ``P("data", "fsdp")`` under ring attention — the sequence rides the
-    "fsdp" axis as context parallelism — else ``P(("data", "fsdp"), None)``
-    (batch sharded over both axes).
+    (Adam; f32 moments sharded like their params). Tokens are sharded
+    by :func:`token_sharding`.
     """
-    cfg = model.config
-    specs = model.param_specs()
-    state_specs = train_state_specs(model)
-    token_spec = (
-        P("data", "fsdp") if cfg.use_ring_attention else P(("data", "fsdp"), None)
-    )
+    state_shardings = train_state_shardings(model, mesh)
     b1, b2, eps = 0.9, 0.999, 1e-8
 
     def train_step(state, tokens):
@@ -335,18 +336,35 @@ def make_train_step(model: Transformer, mesh: Mesh, learning_rate: float = 1e-3)
         new_state = {"params": params, "opt": {"mu": mu, "nu": nu, "step": step}}
         return new_state, loss
 
-    def to_named(tree_specs):
-        return jax.tree.map(
-            lambda s: NamedSharding(mesh, s),
-            tree_specs,
-            is_leaf=lambda s: isinstance(s, P),
-        )
-
     return jax.jit(
         train_step,
-        in_shardings=(to_named(state_specs), NamedSharding(mesh, token_spec)),
-        out_shardings=(to_named(state_specs), NamedSharding(mesh, P())),
+        in_shardings=(state_shardings, token_sharding(model.config, mesh)),
+        out_shardings=(state_shardings, NamedSharding(mesh, P())),
     )
+
+
+def token_sharding(cfg: TransformerConfig, mesh: Mesh) -> NamedSharding:
+    """Sharding of a ``[batch, seq]`` token batch: ``P("data", "fsdp")``
+    under ring attention — the sequence rides the "fsdp" axis as context
+    parallelism — else ``P(("data", "fsdp"), None)`` (batch over both)."""
+    spec = (
+        P("data", "fsdp") if cfg.use_ring_attention else P(("data", "fsdp"), None)
+    )
+    return NamedSharding(mesh, spec)
+
+
+def random_tokens(
+    cfg: TransformerConfig,
+    mesh: Mesh,
+    rng: np.random.Generator,
+    batch: int,
+    seq_len: Optional[int] = None,
+) -> jax.Array:
+    """A ``[batch, seq_len]`` int32 batch drawn from ``rng``, placed with
+    :func:`token_sharding`. ``seq_len`` defaults to ``cfg.max_seq_len``."""
+    shape = (batch, seq_len if seq_len is not None else cfg.max_seq_len)
+    tokens = rng.integers(0, cfg.vocab_size, shape).astype(np.int32)
+    return jax.device_put(tokens, token_sharding(cfg, mesh))
 
 
 def train_state_specs(model: Transformer) -> Params:
@@ -354,17 +372,32 @@ def train_state_specs(model: Transformer) -> Params:
     return {"params": specs, "opt": {"mu": specs, "nu": specs, "step": P()}}
 
 
+def train_state_shardings(model: Transformer, mesh: Mesh) -> Params:
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, s),
+        train_state_specs(model),
+        is_leaf=lambda s: isinstance(s, P),
+    )
+
+
 def init_train_state(model: Transformer, mesh: Mesh, key: jax.Array) -> Params:
-    """Sharded params + zero-initialized Adam state."""
-    specs = model.param_specs()
-    params = model.shard_params(model.init(key), mesh)
+    """Sharded params + zero-initialized Adam state.
 
-    def zeros_f32(p, s):
-        return jax.device_put(
-            jnp.zeros(p.shape, jnp.float32), NamedSharding(mesh, s)
-        )
+    Built under ``jit`` with ``out_shardings``, so every device
+    materializes only its own shards: building the arrays unsharded
+    first would put the whole state on the default device before the
+    ``device_put``."""
 
-    mu = jax.tree.map(zeros_f32, params, specs)
-    nu = jax.tree.map(zeros_f32, params, specs)
-    step = jax.device_put(jnp.zeros((), jnp.int32), NamedSharding(mesh, P()))
-    return {"params": params, "opt": {"mu": mu, "nu": nu, "step": step}}
+    def init(key):
+        params = model.init(key)
+        zeros = lambda p: jnp.zeros(p.shape, jnp.float32)  # noqa: E731
+        return {
+            "params": params,
+            "opt": {
+                "mu": jax.tree.map(zeros, params),
+                "nu": jax.tree.map(zeros, params),
+                "step": jnp.zeros((), jnp.int32),
+            },
+        }
+
+    return jax.jit(init, out_shardings=train_state_shardings(model, mesh))(key)

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -238,13 +237,13 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Flash attention over ``[batch, seq, heads, head_dim]`` inputs.
 
-    ``interpret=None`` auto-selects: compiled on TPU backends, Pallas
-    interpreter elsewhere (CPU test meshes).
+    Compiles the kernel through Mosaic, which only a TPU backend can do.
+    The Pallas interpreter runs only on an explicit ``interpret=True``
+    (CPU tests); nothing here looks at the backend, so a run that was
+    meant for the chip can never quietly take the interpreter.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _flash_attention(q, k, v, causal, block_q, block_k, interpret)
