@@ -1,0 +1,261 @@
+"""The benchmark's harness off the chip: the manifest and the files it
+names, the reducers on hand-made observations, and ``perf/run.py
+--rehearsal`` end to end at the tiny configuration: sound, with the
+program's bf16 store switched on (the control), and with the train step
+broken underneath. CPU only; nothing here describes a TPU topology."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+PERF = os.path.join(ROOT, "perf")
+sys.path.insert(0, ROOT)
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    MANIFEST = json.load(_f)
+CELLS = {w["name"]: w for w in MANIFEST["workloads"]}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def _perf_json(*parts):
+    with open(os.path.join(PERF, *parts)) as f:
+        return json.load(f)
+
+
+def _one_cell_per_traffic_kind():
+    seen = {}
+    for cell in MANIFEST["workloads"]:
+        kind = _perf_json("traffic", f"{cell['traffic']}.json")["kind"]
+        seen.setdefault(kind, cell["name"])
+    return sorted(seen.items())
+
+
+@pytest.fixture(scope="module")
+def cache_dir(tmp_path_factory):
+    return str(tmp_path_factory.mktemp("perf_jax_cache"))
+
+
+def _run(cache_dir, *args, code=None):
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=1",
+        JAX_COMPILATION_CACHE_DIR=cache_dir,
+    )
+    cmd = [sys.executable, "-c", code] if code else [sys.executable, os.path.join(PERF, "run.py")]
+    return subprocess.run(
+        [*cmd, *args], capture_output=True, text=True, timeout=300, cwd=ROOT, env=env
+    )
+
+
+def _result(proc):
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_manifest_names_files_that_exist():
+    for config in MANIFEST["configs"]:
+        path = os.path.join(ROOT, config["file"])
+        assert os.path.isfile(path), path
+        held = _perf_json("configs", f"{config['name']}.json")
+        assert sorted(held["reduced"]) == sorted(config["reduced"])
+        assert os.path.isfile(os.path.join(PERF, "configs", f"{held['rehearsal_config']}.json"))
+    for cell in MANIFEST["workloads"]:
+        assert cell["config"] in {c["name"] for c in MANIFEST["configs"]}
+        traffic = _perf_json("traffic", f"{cell['traffic']}.json")
+        assert os.path.isfile(os.path.join(PERF, "traffic", f"{traffic['kind']}.py"))
+    from perf import harness
+
+    for metric in MANIFEST["per_layer"]:
+        spec = harness.layer_metric_spec(metric["name"])
+        assert os.path.isfile(os.path.join(PERF, "reducers", f"{spec['reducer']}.py"))
+    # One file a reading: a quantity split by what it moves shares its file.
+    held = {f[:-5] for f in os.listdir(os.path.join(PERF, "layer_metrics"))}
+    named = {m["name"] for m in MANIFEST["per_layer"]}
+    assert held <= named | {n.rsplit(".", 1)[0] for n in named}
+    assert _perf_json("peaks.json")["devices"]
+
+
+def test_every_moves_is_an_end_to_end_metric_its_cells_report():
+    reported = {
+        m["name"]: set(m.get("workloads", CELLS)) for m in MANIFEST["end_to_end"]
+    }
+    for metric in MANIFEST["per_layer"]:
+        assert metric["moves"] in reported, metric
+        for cell in metric.get("workloads", CELLS):
+            assert cell in CELLS
+            assert cell in reported[metric["moves"]], (metric["name"], cell)
+    for cell in CELLS:  # setup_s, one more end-to-end metric, one per-layer metric
+        assert cell in reported["setup_s"]
+        assert any(cell in cells for name, cells in reported.items() if name != "setup_s")
+        assert any(cell in m.get("workloads", CELLS) for m in MANIFEST["per_layer"])
+
+
+def test_names_and_units_hold_only_the_allowed_characters():
+    metrics = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
+    names = [m["name"] for m in metrics]
+    assert len(set(names)) == len(names)
+    for m in metrics:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+    for cell in MANIFEST["workloads"]:
+        assert all(NAME.match(cell[k]) for k in ("name", "config", "traffic")), cell
+        assert len(cell["why"]) <= 200 and cell["chips"] in (1, 4)
+    for config in MANIFEST["configs"]:
+        assert all(NAME.match(k) for k in [config["name"], *config["reduced"]])
+
+
+def test_no_cell_or_configuration_is_named_in_code():
+    names = set(CELLS) | {c["name"] for c in MANIFEST["configs"]}
+    for folder, _, files in os.walk(PERF):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(folder, name)) as f:
+                    text = f.read()
+                assert not [n for n in names if n in text], os.path.join(folder, name)
+
+
+def test_reducers_on_hand_made_observations():
+    from perf.reducers import (
+        bytes_per_state_byte, counter_per_op, memory_share, percentile, span_per_op,
+    )
+
+    span = lambda name, start, end, nbytes=0: {  # noqa: E731
+        "name": name, "start": start, "end": end, "bytes": nbytes}
+    obs = {
+        "ops": [{"t_call": 0.0, "t_done": 10.0}, {"t_call": 20.0, "t_done": 30.0}],
+        "spans": [
+            span("dtoh", 1.0, 2.0, 600), span("dtoh", 1.5, 3.0, 400),  # two threads
+            span("dtoh", 21.0, 24.0, 1000), span("storage_write", 2.0, 2.5),
+            span("dtoh", 12.0, 13.0, 9999),  # between operations: nobody's
+        ],
+        "counters": [
+            {"name": "dtoh.enqueued_bytes", "t": 0.5, "delta": 1000},
+            {"name": "dtoh.enqueued_bytes", "t": 20.5, "delta": 1000},
+        ],
+        "series": {"step_ms": list(range(1, 201))},
+        "state_bytes": 1000,
+        "memory": [{"peak_bytes_in_use": 60, "bytes_limit": 100},
+                   {"peak_bytes_in_use": 80, "bytes_limit": 100}],
+    }
+    assert span_per_op.per_op(obs, {"dtoh"}) == [2.5, 3.0]
+    assert span_per_op.reduce(obs, ["dtoh"]) == pytest.approx(2750.0)  # ms, busy not wall
+    assert span_per_op.reduce(obs, ["commit_prep"]) is None  # nothing to read
+    assert counter_per_op.reduce(obs, ["dtoh.enqueued_bytes"]) == 1000
+    assert bytes_per_state_byte.reduce(
+        obs, counters=["dtoh.enqueued_bytes"], spans=["dtoh"]) == pytest.approx(2.0)
+    assert percentile.reduce(obs, "step_ms", 99) == 198
+    assert percentile.reduce(obs, "step_ms", 50) == 100
+    assert percentile.reduce(obs, "no_such_series", 50) is None
+    assert memory_share.reduce(obs) == pytest.approx(80.0)
+    assert memory_share.reduce({"memory": None}) is None
+
+
+def test_trace_reduction_on_synthetic_intervals():
+    from perf.reducers import _trace, trace_idle_share
+
+    busy = _trace.merge([(0.0, 1.0), (0.5, 2.0), (5.0, 6.0), (9.0, 9.5)])
+    assert busy == [[0.0, 2.0], [5.0, 6.0], [9.0, 9.5]]
+    spans = [{"name": "async_blocked", "start": 2.0, "end": 4.5},
+             {"name": "dtoh", "start": 4.0, "end": 5.0}]
+    gaps = _trace.label_gaps(busy, (0.0, 10.0), spans)
+    assert gaps == [["host-other", 3.5], ["async_blocked", 3.0]]  # longest label first
+    events = [(0, 10, "while"), (1, 3, "fusion.1"), (3, 6, "fusion.2"), (12, 13, "fusion.1")]
+    assert _trace.self_seconds(events) == {"fusion.1": 3.0, "fusion.2": 3.0, "while": 5.0}
+    assert _trace.short_name("%fusion.7 = f32[8]{0} fusion(...)") == "fusion.7"
+    obs = {"trace": {"busy_s": 3.5, "window_s": 10.0}}
+    assert trace_idle_share.reduce(obs) == pytest.approx(65.0)
+    assert trace_idle_share.reduce({"trace": None}) is None
+
+
+def test_without_a_tpu_nothing_is_printed(cache_dir):
+    cell = next(iter(CELLS))
+    proc = _run(cache_dir, "--workload", cell, "--seed", "1", "--seconds", "1", "--trace", "0")
+    assert proc.returncode not in (0, None)
+    assert not [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+    assert "needs" in proc.stderr
+
+
+@pytest.mark.parametrize("kind,cell", _one_cell_per_traffic_kind())
+@pytest.mark.parametrize("trace", [0, 1])
+def test_rehearsal_runs_each_traffic_kind_end_to_end(cache_dir, kind, cell, trace):
+    from perf import harness
+
+    proc = _run(cache_dir, "--workload", cell, "--seed", "2147483653", "--seconds", "1",
+                "--trace", str(trace), "--rehearsal")
+    result = _result(proc)
+    assert set(result) - {"breakdown"} == RESULT_KEYS
+    assert result["correct"] is True and result["failed"] == 0 and result["attempted"] >= 1
+    assert result["device"]["platform"] == "cpu"
+    wanted = MANIFEST["per_layer"] if trace else MANIFEST["end_to_end"]
+    units = {m["name"]: m["unit"] for m in wanted if cell in m.get("workloads", CELLS)}
+    assert result["metrics"] and set(result["metrics"]) <= set(units)
+    for name, metric in result["metrics"].items():
+        assert metric["unit"] == units[name]
+        # A time from the CPU is never printed under a metric's name; a
+        # count (bytes per byte, fallbacks) may be.
+        counted = harness.layer_metric_spec(name).get("count") if trace else False
+        assert metric["value"] is None or counted, (name, metric)
+    assert "busy_s" not in result["device"]
+    assert '"compile_events_in_window", "ok": true' in proc.stdout
+
+
+@pytest.mark.parametrize("kind,cell", _one_cell_per_traffic_kind())
+def test_storing_the_state_in_bf16_is_not_correct(cache_dir, kind, cell):
+    """The control: the program's own lower-precision store switched on."""
+    proc = _run(cache_dir, "--workload", cell, "--seed", "7", "--seconds", "1",
+                "--trace", "0", "--rehearsal", "--control", "store_bf16")
+    assert _result(proc)["correct"] is False
+    assert '"name": "restored_bits_differ", "ok": false' in proc.stdout
+
+
+def test_eight_bit_arithmetic_fails_the_first_order_number(cache_dir):
+    """The control of the train step's check: the reference with its linear
+    layers rounded to fp8, the step below the configuration's bf16, reads
+    over the limit of ``grad_diff`` on every seed, and the bf16 program
+    under it."""
+    config = _perf_json("configs", f"{MANIFEST['configs'][0]['name']}.json")
+    limit = _perf_json("configs", f"{config['rehearsal_config']}.json")["limits"]["grad_diff"]
+    proc = subprocess.run(
+        [sys.executable, os.path.join(PERF, "readings.py"), "--config",
+         MANIFEST["configs"][0]["name"], "--seeds", "3", "--controls", "fp8", "--rehearsal"],
+        capture_output=True, text=True, timeout=300, cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=cache_dir),
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    rows = [json.loads(ln.split(": ", 1)[1]) for ln in proc.stdout.splitlines()
+            if ln.startswith("perf reading:")]
+    assert len(rows) == 3
+    for row in rows:
+        assert row["sound"]["grad_diff"] <= limit < row["control_fp8"]["grad_diff"] / 2, row
+
+
+BROKEN_STEP = """
+import sys
+sys.path.insert(0, {root!r})
+import tpusnap.models as models
+sound = models.make_train_step
+def broken(model, mesh, *a, **k):
+    step = sound(model, mesh, *a, **k)
+    return lambda state, tokens: (state, step(state, tokens)[1])  # the state never moves
+models.make_train_step = broken
+sys.argv = ["perf/run.py"] + sys.argv[1:]
+from perf import run
+sys.exit(run.main())
+"""
+
+
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(cache_dir):
+    """The rest of a run, chip look-up skipped, with the timed path broken."""
+    cell = _one_cell_per_traffic_kind()[0][1]
+    proc = _run(cache_dir, "--workload", cell, "--seed", "11", "--seconds", "1",
+                "--trace", "0", "--rehearsal", code=BROKEN_STEP.format(root=ROOT))
+    assert _result(proc)["correct"] is False
+    assert '"name": "delta_norm_gap", "ok": false' in proc.stdout
