@@ -141,7 +141,9 @@ def test_chrome_trace_events_shape():
     ev = complete[0]
     assert ev["name"] == "work" and ev["pid"] == 1
     assert isinstance(ev["ts"], float) and isinstance(ev["dur"], float)
-    assert ev["args"] == {"bytes": 10}
+    assert ev["args"]["bytes"] == 10
+    assert ev["args"]["kind"] == "phase" and ev["args"]["op"] == rec.op
+    assert ev["args"]["parent"] is None and ev["args"]["id"] > 0
     # Serializes as valid JSON end to end.
     doc = json.loads(rec.to_json())
     assert isinstance(doc["traceEvents"], list)

@@ -185,7 +185,9 @@ class DeviceBatchedBufferStager(BufferStager):
         loop = asyncio.get_running_loop()
         try:
             if executor is not None:
-                return await loop.run_in_executor(executor, self._stage_blocking)
+                return await loop.run_in_executor(
+                    executor, telemetry.handoff("stage", self._stage_blocking)
+                )
             return self._stage_blocking()
         except Exception as e:
             # Counted as well as logged, so a run that expects the
@@ -202,7 +204,12 @@ class DeviceBatchedBufferStager(BufferStager):
         from .knobs import is_checksum_disabled
 
         packed = _pack_on_device(tuple(s.arr for _, _, s in self.members))
-        with telemetry.span("dtoh", bytes=self.total, slab_members=len(self.members)):
+        # A slab is packed and fetched in this one blocking call, so here
+        # `dtoh` IS the transfer (with the pack program before it), and
+        # `dtoh.transfer` is the same interval under the name that the
+        # prefetched leaves' real transfers go by.
+        attrs = {"bytes": self.total, "slab_members": len(self.members)}
+        with telemetry.span("dtoh.transfer", **attrs), telemetry.span("dtoh", **attrs):
             host = np.asarray(packed)  # the single DtoH DMA
         if host.nbytes != self.total:
             raise RuntimeError(

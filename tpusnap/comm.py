@@ -235,7 +235,7 @@ class JaxCoordinationComm(Communicator):
     def barrier(self) -> None:
         from . import telemetry
 
-        with telemetry.span("comm.barrier"):
+        with telemetry.span("comm.barrier", kind=telemetry.WAIT):
             self._barrier_impl()
 
     def _barrier_impl(self) -> None:
@@ -367,7 +367,7 @@ class JaxCoordinationComm(Communicator):
         port serialized take/restore at scale)."""
         from . import telemetry
 
-        with telemetry.span("comm.all_gather"):
+        with telemetry.span("comm.all_gather", kind=telemetry.WAIT):
             return self._all_gather_object_impl(obj)
 
     def _all_gather_object_impl(self, obj: Any) -> List[Any]:
@@ -395,7 +395,7 @@ class JaxCoordinationComm(Communicator):
         is GC'd after a later barrier proves global consumption."""
         from . import telemetry
 
-        with telemetry.span("comm.broadcast"):
+        with telemetry.span("comm.broadcast", kind=telemetry.WAIT):
             return self._broadcast_object_impl(obj, src)
 
     def _broadcast_object_impl(self, obj: Any, src: int = 0) -> Any:

@@ -471,7 +471,7 @@ class LinearBarrier:
         from . import flight, telemetry
 
         flight.record("barrier_enter", op=self.prefix)
-        with telemetry.span("kv.barrier_arrive"):
+        with telemetry.span("kv.barrier_arrive", kind=telemetry.WAIT):
             self.store.set(self._key("arrive", str(self.rank)), b"1")
             if self.rank == self.leader_rank:
                 for r in self.ranks:
@@ -480,7 +480,7 @@ class LinearBarrier:
     def depart(self) -> None:
         from . import flight, telemetry
 
-        with telemetry.span("kv.barrier_depart"):
+        with telemetry.span("kv.barrier_depart", kind=telemetry.WAIT):
             if self.rank == self.leader_rank:
                 self.store.set(self._key("depart"), b"1")
             else:

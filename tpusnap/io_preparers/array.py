@@ -23,12 +23,14 @@ from __future__ import annotations
 import asyncio
 import logging
 import math
+import time
 from concurrent.futures import Executor
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
+from .. import telemetry
 from ..io_types import (
     BufferConsumer,
     BufferStager,
@@ -63,8 +65,10 @@ def is_supported_array_dtype(arr: ArrayLike) -> bool:
         return False
 
 
-def enqueue_dtoh(arr: ArrayLike) -> None:
-    """Start the device→host DMA early (overlaps with scheduling).
+def enqueue_dtoh(arr: ArrayLike) -> Optional[float]:
+    """Start the device→host DMA early (overlaps with scheduling), and
+    return the ``time.monotonic()`` reading of the call (None where no
+    transfer was started): the start of the leaf's ``dtoh.transfer``.
 
     Host-offloaded arrays (host_offload.py, the UVM analog) skip the
     enqueue: their buffers already live in host memory, so staging is a
@@ -73,7 +77,8 @@ def enqueue_dtoh(arr: ArrayLike) -> None:
     from ..host_offload import is_host_resident
 
     if not isinstance(arr, jax.Array) or is_host_resident(arr):
-        return
+        return None
+    started = time.monotonic()
     try:
         arr.copy_to_host_async()
     except Exception as e:
@@ -86,13 +91,12 @@ def enqueue_dtoh(arr: ArrayLike) -> None:
             type(e).__name__,
             e,
         )
-        return
-    from .. import telemetry
-
+        return None
     # Counted at enqueue: a member the batcher later packs on device is
     # transferred a second time inside the slab, and only the two counts
     # together show that.
     telemetry.incr("dtoh.enqueued_bytes", array_nbytes(arr))
+    return started
 
 
 class ArrayBufferStager(BufferStager):
@@ -157,15 +161,19 @@ class ArrayBufferStager(BufferStager):
         # applied to the ORIGINAL array at stage time with tracing=False
         # (reference io_preparers/tensor.py:231-241).
         self.array_prepare_func = array_prepare_func
+        # When the prefetch of this leaf's host copy was started, if it was.
+        self.dtoh_started: Optional[float] = None
         if array_prepare_func is None:
             # A transform usually changes the bytes; prefetching the
             # untransformed array's DtoH would be wasted DMA.
-            enqueue_dtoh(arr)
+            self.dtoh_started = enqueue_dtoh(arr)
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         loop = asyncio.get_running_loop()
         if executor is not None:
-            return await loop.run_in_executor(executor, self._stage_blocking)
+            return await loop.run_in_executor(
+                executor, telemetry.handoff("stage", self._stage_blocking)
+            )
         return self._stage_blocking()
 
     def _stage_blocking(self) -> BufferType:
@@ -185,8 +193,6 @@ class ArrayBufferStager(BufferStager):
                     "recorded at prepare time — the transform must be "
                     "deterministic"
                 )
-        from .. import telemetry
-
         rec = telemetry.current()
         dtoh_t0 = (
             rec.now()
@@ -195,7 +201,23 @@ class ArrayBufferStager(BufferStager):
         )
         host = np.asarray(arr)  # DtoH (no-op if DMA already done)
         if dtoh_t0 is not None:
-            rec.record_span("dtoh", dtoh_t0, rec.now() - dtoh_t0, bytes=host.nbytes)
+            # `dtoh` is this call alone. For a prefetched leaf that is
+            # the residual wait for a copy started at prepare time, not
+            # the transfer; `dtoh.transfer` runs from the start of the
+            # copy to here, where the host copy is SEEN ready: an upper
+            # bound on the transfer (ready is observed, not signalled,
+            # and a leaf staged late was ready long before).
+            done = rec.now()
+            prefetched = arr is self.arr and self.dtoh_started is not None
+            started = self.dtoh_started - rec.t0 if prefetched else dtoh_t0
+            rec.record_span(
+                "dtoh", dtoh_t0, done - dtoh_t0, bytes=host.nbytes,
+                kind=telemetry.WAIT if prefetched else telemetry.WORK,
+            )
+            rec.record_span(
+                "dtoh.transfer", started, done - started, bytes=host.nbytes,
+                kind=telemetry.WORK,
+            )
         mv = array_as_memoryview(host)
         want_crc = self.entry is not None and not is_checksum_disabled()
         if self.compress_codec is not None and want_crc:
@@ -477,13 +499,13 @@ class ArrayBufferStager(BufferStager):
                 "compress",
                 t0,
                 rec.now() - t0,
+                kind=telemetry.WORK,
                 bytes=mv.nbytes,
                 out_bytes=out.nbytes,
                 codec=codec,
             )
         telemetry.incr("compress.bytes_in", mv.nbytes, rec=rec)
         telemetry.incr("compress.bytes_out", out.nbytes, rec=rec)
-        telemetry.incr("compress.blobs", rec=rec)
         _annotate_compressed(
             entry, codec, mv.nbytes, comp_sizes, crcs, tile_rows, xxhs
         )
@@ -903,8 +925,6 @@ def _record_checksums(
     pass — so the next increment's dedup decisions carry more than 32
     bits of evidence per skipped unit. Small tile-less blobs record
     theirs on every take (see _DEDUP_HASH_EAGER_MAX)."""
-    from .. import telemetry
-
     with telemetry.span("checksum", bytes=mv.nbytes):
         _record_checksums_impl(entry, mv, record_dedup_hashes)
 
@@ -1033,17 +1053,28 @@ class ArrayBufferConsumer(BufferConsumer):
     ) -> None:
         loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(executor, self._consume_blocking, buf)
+            await loop.run_in_executor(
+                executor, _consume_handoff(self._consume_blocking), buf
+            )
         else:
             self._consume_blocking(buf)
 
     def _consume_blocking(self, buf: BufferType) -> None:
-        _maybe_verify(buf, self.entry.checksum, self.verify_location)
+        with telemetry.span("decode", bytes=memoryview(buf).nbytes):
+            _maybe_verify(buf, self.entry.checksum, self.verify_location)
         value = materialize_array(self.entry, buf, self.obj_out)
         self.fut.obj = value
 
     def get_consuming_cost_bytes(self) -> int:
         return tensor_nbytes(self.entry.dtype, self.entry.shape)
+
+
+def _consume_handoff(fn: Callable) -> Callable:
+    """A consumer's blocking body, wrapped for the consume executor:
+    the wait for a consume thread is ``consume.queued``; the body records
+    its own ``decode`` (checksum verify, decompress, copy into a host
+    target) and ``htod`` (``device_put`` into a jax target) spans."""
+    return telemetry.handoff("consume", fn, work=False)
 
 
 def _maybe_verify(buf: BufferType, checksum: Optional[str], location: str) -> None:
@@ -1090,21 +1121,26 @@ def finalize_into_target(
     - otherwise: a host array owning its memory (``owns_memory`` says
       whether ``host`` already does, or aliases a transient read
       buffer)."""
-    if isinstance(obj_out, np.ndarray):
-        if obj_out.shape == host.shape and obj_out.flags.writeable:
-            np.copyto(obj_out, host, casting="unsafe")
-            return obj_out
-        return host if owns_memory else _owning_copy(host)
     if isinstance(obj_out, jax.Array):
         # device_put is async; XLA overlaps the HtoD DMA with further
         # reads. The dtype cast (if any) runs on the accelerator with
         # the sharding preserved — not as a host pass that would double
-        # the transfer volume.
-        dev = jax.device_put(host, obj_out.sharding)
-        if obj_out.dtype != dev.dtype and obj_out.shape == dev.shape:
-            dev = dev.astype(obj_out.dtype)
+        # the transfer volume. `htod` is the call and whatever it
+        # dispatches, not the DMA's own time on the bus.
+        with telemetry.span("htod", bytes=host.nbytes):
+            dev = jax.device_put(host, obj_out.sharding)
+            if obj_out.dtype != dev.dtype and obj_out.shape == dev.shape:
+                dev = dev.astype(obj_out.dtype)
         return dev
-    return host if owns_memory else _owning_copy(host)
+    with telemetry.span("decode", bytes=host.nbytes):
+        if (
+            isinstance(obj_out, np.ndarray)
+            and obj_out.shape == host.shape
+            and obj_out.flags.writeable
+        ):
+            np.copyto(obj_out, host, casting="unsafe")
+            return obj_out
+        return host if owns_memory else _owning_copy(host)
 
 
 def materialize_array(
@@ -1500,7 +1536,7 @@ class _CompressedConsumer(BufferConsumer):
         if executor is not None:
             await loop.run_in_executor(
                 executor,
-                self._consume_blocking,
+                _consume_handoff(self._consume_blocking),
                 buf,
                 read_io.crc32c,
                 read_io.crc_algo,
@@ -1515,13 +1551,17 @@ class _CompressedConsumer(BufferConsumer):
         loop = asyncio.get_running_loop()
         if executor is not None:
             await loop.run_in_executor(
-                executor, self._consume_blocking, buf, None, None
+                executor, _consume_handoff(self._consume_blocking), buf, None, None
             )
         else:
             self._consume_blocking(buf, None, None)
         await self._after_consume(executor)
 
     def _consume_blocking(self, buf: BufferType, crc, crc_algo) -> None:
+        with telemetry.span("decode", rec=self._tele, bytes=self.comp_nbytes):
+            self._verify_and_decompress(buf, crc, crc_algo)
+
+    def _verify_and_decompress(self, buf: BufferType, crc, crc_algo) -> None:
         from .. import _native
         from ..knobs import get_native_copy_threads
 
@@ -1566,6 +1606,7 @@ class _CompressedConsumer(BufferConsumer):
                     "restore.decode",
                     start,
                     tele.now() - start,
+                    kind="work",
                     path=self.location,
                     bytes=self.comp_nbytes,
                     raw_bytes=self.raw_len,
@@ -1587,7 +1628,7 @@ class _CompressedConsumer(BufferConsumer):
             loop = asyncio.get_running_loop()
             self.fut.obj = await loop.run_in_executor(
                 executor,
-                finalize_into_target,
+                _consume_handoff(finalize_into_target),
                 self.host_out,
                 self.obj_out,
                 True,
@@ -1661,7 +1702,9 @@ class _TileConsumer(BufferConsumer):
     ) -> None:
         loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(executor, self._consume_blocking, buf)
+            await loop.run_in_executor(
+                executor, _consume_handoff(self._consume_blocking), buf
+            )
         else:
             self._consume_blocking(buf)
         await self._after_consume(executor)
@@ -1684,7 +1727,7 @@ class _TileConsumer(BufferConsumer):
             loop = asyncio.get_running_loop()
             self.fut.obj = await loop.run_in_executor(
                 executor,
-                finalize_into_target,
+                _consume_handoff(finalize_into_target),
                 self.host_out,
                 self.obj_out,
                 True,
@@ -1695,10 +1738,13 @@ class _TileConsumer(BufferConsumer):
             )
 
     def _consume_blocking(self, buf: BufferType) -> None:
-        _maybe_verify(buf, self.blob_checksum, self.blob_location)
-        tile_shape = [self.r1 - self.r0] + list(self.entry.shape[1:])
-        src = array_from_memoryview(memoryview(buf), self.entry.dtype, tile_shape)
-        np.copyto(self.host_out[self.r0 : self.r1], src)
+        with telemetry.span("decode", bytes=memoryview(buf).nbytes):
+            _maybe_verify(buf, self.blob_checksum, self.blob_location)
+            tile_shape = [self.r1 - self.r0] + list(self.entry.shape[1:])
+            src = array_from_memoryview(
+                memoryview(buf), self.entry.dtype, tile_shape
+            )
+            np.copyto(self.host_out[self.r0 : self.r1], src)
 
     def get_consuming_cost_bytes(self) -> int:
         return tensor_nbytes(

@@ -15,6 +15,7 @@ import asyncio
 from concurrent.futures import Executor
 from typing import Any, List, Optional, Tuple
 
+from .. import telemetry
 from ..io_types import (
     BufferConsumer,
     BufferStager,
@@ -60,7 +61,9 @@ class ObjectBufferConsumer(BufferConsumer):
         if executor is not None:
             loop = asyncio.get_running_loop()
             self.fut.obj = await loop.run_in_executor(
-                executor, pickle_from_bytes, bytes(buf)
+                executor,
+                telemetry.handoff("consume", pickle_from_bytes, work="decode"),
+                bytes(buf),
             )
         else:
             self.fut.obj = pickle_from_bytes(bytes(buf))

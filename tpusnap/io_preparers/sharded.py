@@ -34,6 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from .. import telemetry
 from ..io_types import (
     BufferConsumer,
     BufferStager,
@@ -376,14 +377,18 @@ class _Assembler:
                     else shard.device
                 )
             # One batched transfer for all of this array's shards (a
-            # per-shard loop pays jax dispatch overhead per piece).
-            per_device = jax.device_put(bufs, dsts)
-            if obj_out.dtype != per_device[0].dtype:
-                # Reduced-precision save restoring into a full-precision
-                # target: transfer at the STORED width (half the HtoD
-                # bytes), cast on device per single-device piece — the
-                # sharded analog of finalize_into_target's device cast.
-                per_device = [a.astype(obj_out.dtype) for a in per_device]
+            # per-shard loop pays jax dispatch overhead per piece). It
+            # runs on the event-loop thread, when the array's last read
+            # has landed.
+            with telemetry.span("htod", bytes=sum(b.nbytes for b in bufs)):
+                per_device = jax.device_put(bufs, dsts)
+                if obj_out.dtype != per_device[0].dtype:
+                    # Reduced-precision save restoring into a
+                    # full-precision target: transfer at the STORED
+                    # width (half the HtoD bytes), cast on device per
+                    # single-device piece — the sharded analog of
+                    # finalize_into_target's device cast.
+                    per_device = [a.astype(obj_out.dtype) for a in per_device]
             self.fut.obj = jax.make_array_from_single_device_arrays(
                 global_shape, obj_out.sharding, per_device
             )
@@ -440,13 +445,21 @@ class _ScatterConsumer(BufferConsumer):
     ) -> None:
         loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(executor, self._scatter, buf)
+            from .array import _consume_handoff
+
+            await loop.run_in_executor(
+                executor, _consume_handoff(self._scatter), buf
+            )
         else:
             self._scatter(buf)
         # Assembly bookkeeping stays on the event-loop thread: no races.
         self.assembler.read_landed()
 
     def _scatter(self, buf: BufferType) -> None:
+        with telemetry.span("decode", bytes=memoryview(buf).nbytes):
+            self._verify_and_scatter(buf)
+
+    def _verify_and_scatter(self, buf: BufferType) -> None:
         from .array import _maybe_verify
 
         if not self._verified:

@@ -451,21 +451,18 @@ class _WritePipeline:
     async def stage(self, executor: ThreadPoolExecutor) -> "_WritePipeline":
         from .io_types import SKIP_WRITE
 
-        start = self.tele.now() if self.tele is not None else 0.0
-        token = self.tele.op_enter("stage_buffer") if self.tele is not None else None
-        try:
+        # The request's await, timed on the event-loop thread (kind wait):
+        # it holds the wait for a staging thread, the work and the loop's
+        # own latency; the hand-off's `stage.queued` / `stage.work` spans,
+        # recorded on the worker as children of this one, tell them apart.
+        with telemetry.span(
+            "stage_buffer",
+            kind=telemetry.WAIT,
+            rec=self.tele,
+            path=self.write_req.path,
+            bytes=self.staging_cost,
+        ):
             buf = await self.write_req.buffer_stager.stage_buffer(executor)
-        finally:
-            if self.tele is not None:
-                self.tele.op_exit(token)
-        if self.tele is not None:
-            self.tele.record_span(
-                "stage_buffer",
-                start,
-                self.tele.now() - start,
-                path=self.write_req.path,
-                bytes=self.staging_cost,
-            )
         if buf is SKIP_WRITE:
             self.skipped = True
             telemetry.incr("scheduler.dedup_skipped", rec=self.tele)
@@ -511,24 +508,15 @@ class _WritePipeline:
                         self.tele.now() - hash_start,
                         bytes=self.buf_size,
                     )
-        write_start = self.tele.now() if self.tele is not None else 0.0
-        token = (
-            self.tele.op_enter("storage_write") if self.tele is not None else None
-        )
-        try:
+        with telemetry.span(
+            "storage_write",
+            kind=telemetry.WAIT,
+            rec=self.tele,
+            path=self.write_req.path,
+            bytes=self.buf_size,
+        ):
             await self.storage.write(
                 WriteIO(path=self.write_req.path, buf=self.buf)
-            )
-        finally:
-            if self.tele is not None:
-                self.tele.op_exit(token)
-        if self.tele is not None:
-            self.tele.record_span(
-                "storage_write",
-                write_start,
-                self.tele.now() - write_start,
-                path=self.write_req.path,
-                bytes=self.buf_size,
             )
         telemetry.incr("storage.bytes_written", self.buf_size, rec=self.tele)
         telemetry.incr("storage.writes", rec=self.tele)
@@ -811,7 +799,7 @@ class _WriteScheduler:
     # --- window / stall bookkeeping ------------------------------------
 
     def _note_stall(self) -> None:
-        # Budget-stall EPISODES, not wait iterations: one span + counter
+        # Budget-stall EPISODES, not wait iterations: one span
         # per contiguous window in which the head request cannot be
         # admitted, however many task completions the window spans.
         if self._staging_budget_starved():
@@ -819,7 +807,6 @@ class _WriteScheduler:
                 self._stall_start = (
                     self.tele.now() if self.tele is not None else 0.0
                 )
-                telemetry.incr("scheduler.budget_waits", rec=self.tele)
         elif self._stall_start is not None:
             if self.tele is not None:
                 self.tele.record_span(
@@ -1104,27 +1091,13 @@ class _ReadPipeline:
             into=self.read_req.into,
             want_crc=self.read_req.want_crc,
         )
-        start = self.tele.now() if self.tele is not None else 0.0
-        token = (
-            self.tele.op_enter("storage_read") if self.tele is not None else None
-        )
-        try:
+        with telemetry.span(
+            "storage_read", kind=telemetry.WAIT, rec=self.tele, path=self.read_req.path
+        ) as sp:
             await self.storage.read(self.read_io)
-        finally:
-            if self.tele is not None:
-                self.tele.op_exit(token)
-        nbytes = self._read_nbytes()
+            nbytes = sp.attrs["bytes"] = self._read_nbytes()
         self.read_nbytes = nbytes
-        if self.tele is not None:
-            self.tele.record_span(
-                "storage_read",
-                start,
-                self.tele.now() - start,
-                path=self.read_req.path,
-                bytes=nbytes,
-            )
         telemetry.incr("storage.bytes_read", nbytes, rec=self.tele)
-        telemetry.incr("storage.reads", rec=self.tele)
         self._record_access(nbytes)
         return self
 
@@ -1153,23 +1126,18 @@ class _ReadPipeline:
 
     async def consume(self, executor: ThreadPoolExecutor) -> "_ReadPipeline":
         # "consume" covers deserialize + the copy/`device_put` into the
-        # restore target (the HtoD leg for jax targets).
-        start = self.tele.now() if self.tele is not None else 0.0
-        token = self.tele.op_enter("consume") if self.tele is not None else None
-        try:
+        # restore target (the HtoD leg for jax targets), and the wait
+        # for a consume thread; the consumers' own `consume.queued`,
+        # `decode` and `htod` spans tell those apart.
+        with telemetry.span(
+            "consume",
+            kind=telemetry.WAIT,
+            rec=self.tele,
+            path=self.read_req.path,
+            bytes=self.consuming_cost,
+        ):
             await self.read_req.buffer_consumer.consume_read_io(
                 self.read_io, executor
-            )
-        finally:
-            if self.tele is not None:
-                self.tele.op_exit(token)
-        if self.tele is not None:
-            self.tele.record_span(
-                "consume",
-                start,
-                self.tele.now() - start,
-                path=self.read_req.path,
-                bytes=self.consuming_cost,
             )
         self.read_io = None  # release
         return self

@@ -643,6 +643,7 @@ class Snapshot:
         tele = telemetry.begin_restore(comm.rank)
         tele.meta.update(path=self.path, world_size=comm.world_size)
         mark = telemetry.PhaseMarker(rec=tele, from_start=True)
+        mark.begin("restore.plan")
         # Access-ledger scope around the whole read path: every ReadReq
         # the restore executes attributes (logical path, byte range,
         # source tier) to this reader's sidecar — the raw material for
@@ -732,7 +733,7 @@ class Snapshot:
         else:
             keys = sorted(app_state.keys())
         # Metadata read/decode + budget + (optional) key gather.
-        mark("restore.plan")
+        mark("restore.plan")  # each key begins its own phases
         # RNG state is restored last so that loading other statefuls
         # cannot perturb it (reference snapshot.py:473-481).
         rng_keys = [
@@ -1173,7 +1174,7 @@ def _take_impl(
     # manifest_gather → metadata) tiling the take's timeline from t0;
     # the trace CLI's coverage figure is their sum over the take
     # wall-clock.
-    mark = telemetry.phase_marker(from_start=True)
+    mark = telemetry.phase_marker(from_start=True, first="state_dict")
 
     # Capture RNG state on entry; other statefuls' state_dict() calls may
     # consume RNG, and take() must be invariant (reference :332-374).
@@ -1204,7 +1205,7 @@ def _take_impl(
     # Undo any RNG perturbation caused by gathering state dicts.
     for key, captured in rng_captured.items():
         app_state[key].load_state_dict(captured)
-    mark("state_dict", keys=len(keys))
+    mark("state_dict", then="plan", keys=len(keys))
 
     # Local replicated candidates: glob-matched host-side values. A
     # fully-replicated multi-process jax.Array needs no glob — it routes
@@ -1297,7 +1298,7 @@ def _take_impl(
     # The G1 gather + write-load partition plan (single-process: just
     # the glob intersection — cheap, but keeping the phases contiguous
     # is what makes coverage meaningful).
-    mark("plan")
+    mark("plan", then="prepare")
     if mark.rec is not None:
         # Identity context for the summary consumers (export sinks,
         # cross-run history): take_id and the coalesced path are final
@@ -1716,7 +1717,7 @@ def _take_impl(
     memory_budget = get_process_memory_budget_bytes(
         comm, local_world_size=local_world_size
     )
-    mark("prepare", write_reqs=len(write_reqs))
+    mark("prepare", then="stage", write_reqs=len(write_reqs))
     # Async-take scheduling mode. PIPELINED (the default async path):
     # the blocked window stages only a TPUSNAP_ASYNC_STAGE_WINDOW_BYTES
     # window of write requests before control returns; the remaining
@@ -1772,7 +1773,7 @@ def _take_impl(
     # (first-window-staged for pipelined takes, staging-complete
     # otherwise); the scheduler's "stage_blocked"/"stage_window" op
     # spans are the interior measurements.
-    mark("stage", write_reqs=len(write_reqs))
+    mark("stage", then="manifest_gather", write_reqs=len(write_reqs))
     from .knobs import get_rank_failure_policy
 
     if (
@@ -1826,7 +1827,7 @@ def _take_impl(
             pending_io_work=pending_io_work,
         )
     global_manifest = _gather_manifest(entries, comm)
-    mark("manifest_gather")
+    mark("manifest_gather", then="metadata")
     import time
 
     metadata = SnapshotMetadata(
@@ -2866,11 +2867,11 @@ def _read_and_inflate(
         futures[logical_path] = fut
     read_reqs = batch_read_requests(read_reqs)
     if mark is not None:
-        mark("restore.prepare", reqs=len(read_reqs))
+        mark("restore.prepare", then="restore.read", reqs=len(read_reqs))
     sync_execute_read_reqs(read_reqs, storage, memory_budget, rank, event_loop)
     if mark is not None:
         # Storage reads + consume (deserialize/HtoD) under the budget.
-        mark("restore.read", reqs=len(read_reqs))
+        mark("restore.read", then="restore.load", reqs=len(read_reqs))
     flattened = {p: fut.obj for p, fut in futures.items()}
     container_manifest = {
         p: e for p, e in key_manifest.items() if is_container_entry(e)
@@ -2900,10 +2901,12 @@ def _load_stateful(
 
     # The current state_dict provides restore targets (device placement,
     # shardings, in-place numpy buffers).
+    if mark is not None:
+        mark.begin("restore.targets")
     target_manifest, target_flattened = flatten(stateful.state_dict(), prefix=key)
     handle_sharded_elasticity(local_manifest, target_flattened)
     if mark is not None:
-        mark("restore.targets", key=key)
+        mark("restore.targets", then="restore.prepare", key=key)
 
     restored = _read_and_inflate(
         key,

@@ -20,6 +20,7 @@ except ModuleNotFoundError:  # gated dep: fall back to thread-pool I/O
     aiofiles = None
 import numpy as np
 
+from .. import telemetry
 from ..io_types import ReadIO, StoragePlugin, WriteIO
 from ..memoryview_stream import MemoryviewStream
 
@@ -73,9 +74,16 @@ class FSStoragePlugin(StoragePlugin):
         if len(buf) >= _NATIVE_WRITE_THRESHOLD or aiofiles is None:
             # One blocking write in a thread: releases the GIL for the whole
             # transfer and avoids aiofiles' per-chunk hop overhead. Also the
-            # small-write path when aiofiles is not installed.
+            # small-write path when aiofiles is not installed. The hand-off
+            # records `write.queued` (the wait for one of the 8 threads)
+            # and `write.work` (the write) on that thread.
             loop = asyncio.get_running_loop()
-            await loop.run_in_executor(self._get_executor(), _write_file, path, buf)
+            await loop.run_in_executor(
+                self._get_executor(),
+                telemetry.handoff("write", _write_file, bytes=len(buf)),
+                path,
+                buf,
+            )
         else:
             async with aiofiles.open(path, "wb") as f:
                 await f.write(buf)
@@ -87,9 +95,13 @@ class FSStoragePlugin(StoragePlugin):
             # engines go through the page cache). Dirent durability is
             # handled at commit time (write_atomic fsyncs every
             # directory this plugin created).
+            # A second trip through the same executor: its queue wait is
+            # one more `write.queued`, the fsync itself `write.fsync`.
             loop = asyncio.get_running_loop()
             await loop.run_in_executor(
-                self._get_executor(), _fsync_path, str(path)
+                self._get_executor(),
+                telemetry.handoff("write", _fsync_path, work="write.fsync"),
+                str(path),
             )
 
     async def write_atomic(self, write_io: WriteIO, durable: bool = False) -> None:
@@ -157,7 +169,9 @@ class FSStoragePlugin(StoragePlugin):
                     return f.read(n)
 
             loop = asyncio.get_running_loop()
-            data = await loop.run_in_executor(self._get_executor(), work)
+            data = await loop.run_in_executor(
+                self._get_executor(), telemetry.handoff("read", work, bytes=n)
+            )
             read_io.buf = io.BytesIO(data)
             return
         async with aiofiles.open(path, "rb") as f:
@@ -179,7 +193,9 @@ class FSStoragePlugin(StoragePlugin):
                 path, offset, n, dst, want_crc=read_io.want_crc
             )
 
-        got, crc, algo = await self._submit_tracked(self._get_executor(), work)
+        got, crc, algo = await self._submit_tracked(
+            self._get_executor(), telemetry.handoff("read", work, bytes=n)
+        )
         if got != n:
             raise IOError(
                 f"short read: got {got} of {n} bytes at offset {offset} "
@@ -219,7 +235,9 @@ class FSStoragePlugin(StoragePlugin):
             got = _read_range(path, offset, n, arr.data)
             return arr, got, None, None
 
-        arr, got, crc, algo = await self._submit_tracked(self._get_executor(), work)
+        arr, got, crc, algo = await self._submit_tracked(
+            self._get_executor(), telemetry.handoff("read", work, bytes=n)
+        )
         if want_crc and got == n:
             read_io.crc32c = crc
             read_io.crc_algo = algo

@@ -52,13 +52,25 @@ must never fail a take.
 
 from __future__ import annotations
 
+import contextvars
+import itertools
 import json
 import logging
 import math
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Callable, Dict, Generator, List, Optional, Tuple
+from contextlib import contextmanager, nullcontext
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    Generator,
+    List,
+    NamedTuple,
+    Optional,
+    Tuple,
+    Union,
+)
 
 from .knobs import is_telemetry_enabled
 
@@ -89,6 +101,88 @@ def telemetry_rank_path(rank: int) -> str:
     return f"{TELEMETRY_DIR}/rank_{rank}.json"
 
 
+# -------------------------------------------------------------- records
+
+# What a span says about the thread that recorded it.
+PHASE = "phase"  # one step of the linear pipeline; phases tile a thread
+WAIT = "wait"  # nothing ran on the recording thread's behalf: a queue, an
+#                await seen from the event loop, a barrier, a transfer
+#                that was started earlier
+WORK = "work"  # the body ran on the recording thread, start to end
+
+
+class SpanRecord(NamedTuple):
+    """One span as the seam keeps, persists and publishes it. ``start``
+    and ``end`` are absolute ``time.monotonic()`` readings, so records
+    of different takes, restores and the caller's own timestamps share
+    one clock. ``op`` names the take or restore the span belongs to;
+    ``parent`` is the ``id`` of the span that caused it (the enclosing
+    span on that thread, or the request's span for work handed to
+    another thread), None at the top."""
+
+    id: int
+    name: str
+    start: float
+    end: float
+    thread: str
+    kind: str
+    op: str
+    parent: Optional[int]
+    attrs: Dict[str, Any]
+
+    @property
+    def duration_s(self) -> float:
+        return self.end - self.start
+
+
+# Span ids and op numbers are unique in the process, so a parent id can
+# never resolve into another take's spans.
+_span_ids = itertools.count(1)
+_op_numbers = itertools.count(1)
+
+# The innermost open span of the running thread or asyncio task, as
+# (recorder, span id): what a new span takes as its parent. asyncio
+# tasks copy it at creation, so a request's task inherits the phase
+# that created it; a thread starts with none.
+_ambient: "contextvars.ContextVar[Optional[Tuple[TakeTelemetry, int]]]" = (
+    contextvars.ContextVar("tpusnap_ambient_span", default=None)
+)
+
+
+class OpenSpan:
+    """Handle of a span that is still open: its ``id`` (what children
+    name as their parent) and its ``attrs``, which the body may add to
+    before the span closes."""
+
+    __slots__ = ("id", "attrs")
+
+    def __init__(self, span_id: int, attrs: Dict[str, Any]) -> None:
+        self.id = span_id
+        self.attrs = attrs
+
+
+_trace_me: Any = None  # jax.profiler.TraceAnnotation, False if unavailable
+
+
+def _annotate(name: str, op: str) -> Any:
+    """Open ``tpusnap:<name>`` in the profiler's own trace (a TraceMe on
+    the calling thread) and return it, or None without jax. With no
+    profile running this is one atomic load inside TraceMe (~0.6 us)."""
+    global _trace_me
+    if _trace_me is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _trace_me = TraceAnnotation
+        except Exception:
+            _trace_me = False
+    if not _trace_me:
+        return None
+    ann = _trace_me(f"tpusnap:{name}", op=op)
+    ann.__enter__()
+    return ann
+
+
 # --------------------------------------------------------------- sinks
 
 
@@ -100,6 +194,12 @@ class MetricsSink:
 
     def on_span(self, name: str, duration_s: float, attrs: Dict[str, Any]) -> None:
         pass
+
+    def on_span_record(self, record: SpanRecord) -> None:
+        """The whole record of a span (start, end, thread, kind, op,
+        parent). A sink that defines only ``on_span`` gets every span
+        through it, as before."""
+        self.on_span(record.name, record.duration_s, record.attrs)
 
     def on_counter(self, name: str, delta: int, value: int) -> None:
         pass
@@ -165,27 +265,45 @@ def metrics_sink(sink: MetricsSink) -> Generator[MetricsSink, None, None]:
 
 def _notify(method: str, *args) -> None:
     for sink in _sinks:
-        try:
-            getattr(sink, method)(*args)
-        except Exception:
-            # Swallowed (telemetry never fails a take) but NOT silent: a
-            # broken exporter is diagnosable from one WARNING naming the
-            # sink class and callback, rate-limited to once per sink
-            # class per callback per take.
-            key = (type(sink).__name__, method)
-            with _sinks_lock:
-                first = key not in _sink_warned
-                _sink_warned.add(key)
-            if first:
-                logger.warning(
-                    "MetricsSink %s.%s raised; exception swallowed "
-                    "(telemetry never fails a take) — further failures "
-                    "from this sink/callback suppressed until the next "
-                    "take",
-                    key[0],
-                    method,
-                    exc_info=True,
-                )
+        _notify_one(sink, method, *args)
+
+
+def _notify_one(sink: MetricsSink, method: str, *args) -> None:
+    try:
+        getattr(sink, method)(*args)
+    except Exception:
+        # Swallowed (telemetry never fails a take) but NOT silent: a
+        # broken exporter is diagnosable from one WARNING naming the
+        # sink class and callback, rate-limited to once per sink
+        # class per callback per take.
+        key = (type(sink).__name__, method)
+        with _sinks_lock:
+            first = key not in _sink_warned
+            _sink_warned.add(key)
+        if first:
+            logger.warning(
+                "MetricsSink %s.%s raised; exception swallowed "
+                "(telemetry never fails a take) — further failures "
+                "from this sink/callback suppressed until the next "
+                "take",
+                key[0],
+                method,
+                exc_info=True,
+            )
+
+
+def _notify_span(record: SpanRecord) -> None:
+    """A sink whose class defines ``on_span_record`` gets the record;
+    any other (a ``MetricsSink`` that overrides only ``on_span``, or a
+    duck-typed sink written before records existed) gets ``on_span``."""
+    for sink in _sinks:
+        handler = getattr(type(sink), "on_span_record", None)
+        if handler is None or handler is MetricsSink.on_span_record:
+            _notify_one(
+                sink, "on_span", record.name, record.duration_s, record.attrs
+            )
+        else:
+            _notify_one(sink, "on_span_record", record)
 
 
 def notify_slo_update(state: Dict[str, Any]) -> None:
@@ -455,12 +573,18 @@ class TakeTelemetry:
 
     ``enabled`` gates SPAN capture only (the TPUSNAP_TELEMETRY knob,
     sampled once at construction so a take is internally consistent);
-    counters and gauges are always recorded. Timestamps are offsets
-    from the take's start on the monotonic clock."""
+    counters and gauges are always recorded. Spans are kept as
+    :class:`SpanRecord` on the absolute monotonic clock; ``now()`` and
+    the persisted trace's ``ts`` are offsets from ``t0``, the take's
+    start on that clock."""
 
-    def __init__(self, rank: int, enabled: Optional[bool] = None) -> None:
+    def __init__(
+        self, rank: int, enabled: Optional[bool] = None, kind: str = "take"
+    ) -> None:
         self.rank = rank
         self.enabled = is_telemetry_enabled() if enabled is None else enabled
+        # One identifier for every span of this take or restore.
+        self.op = f"{kind}-{next(_op_numbers)}"
         self.t0 = time.monotonic()
         self.wall0 = _wall()
         # Identity/outcome context merged into summary(): the take path
@@ -470,8 +594,10 @@ class TakeTelemetry:
         # become a throughput trend point).
         self.meta: Dict[str, Any] = {}
         self._lock = threading.Lock()
-        # (name, start_s, dur_s, thread_name, is_phase, attrs)
-        self._spans: List[Tuple[str, float, float, str, bool, Dict[str, Any]]] = []
+        self._spans: List[SpanRecord] = []
+        # The annotation a PhaseMarker holds open in the profiler's
+        # trace; finalize() closes one that a failed take left open.
+        self._phase_annotation: Any = None
         # (name, ts_s, thread_name, attrs) — instant events (faults, retries)
         self._events: List[Tuple[str, float, str, Dict[str, Any]]] = []
         self._counters: Dict[str, int] = {}
@@ -502,35 +628,131 @@ class TakeTelemetry:
     def now(self) -> float:
         return time.monotonic() - self.t0
 
+    def ambient_parent(self) -> Optional[int]:
+        """The id of the innermost open span of this recorder on the
+        running thread or task, or None."""
+        ambient = _ambient.get()
+        return ambient[1] if ambient is not None and ambient[0] is self else None
+
+    def _keep(self, record: SpanRecord) -> None:
+        with self._lock:
+            self._spans.append(record)
+        _notify_span(record)
+
     def record_span(
         self,
         name: str,
         start_s: float,
         dur_s: float,
         phase: bool = False,
+        *,
+        kind: Optional[str] = None,
+        span_id: Optional[int] = None,
         **attrs: Any,
     ) -> None:
+        """Record a span after the fact: ``start_s`` is an offset from
+        ``t0`` as :meth:`now` gives it. Its kind is ``wait`` unless
+        said otherwise: a span timed from outside (an await seen from
+        the event loop, a window between two marks) holds queueing and
+        other threads' work, not the recording thread's."""
         if not self.enabled:
             return
-        thread = threading.current_thread().name
-        with self._lock:
-            self._spans.append((name, start_s, dur_s, thread, phase, attrs))
-        _notify("on_span", name, dur_s, attrs)
+        start = self.t0 + start_s
+        self._keep(
+            SpanRecord(
+                span_id if span_id is not None else next(_span_ids),
+                name,
+                start,
+                start + dur_s,
+                threading.current_thread().name,
+                PHASE if phase else (kind or WAIT),
+                self.op,
+                self.ambient_parent(),
+                attrs,
+            )
+        )
 
     @contextmanager
     def span(
-        self, name: str, phase: bool = False, **attrs: Any
-    ) -> Generator[None, None, None]:
+        self,
+        name: str,
+        phase: bool = False,
+        *,
+        kind: Optional[str] = None,
+        **attrs: Any,
+    ) -> Generator[OpenSpan, None, None]:
+        """Record a span around the body, on the thread (or asyncio
+        task) that runs it; spans opened inside take it as their parent.
+        Its kind is ``work`` unless said otherwise. A ``phase`` or
+        ``work`` span is also a ``tpusnap:<name>`` annotation in the
+        profiler's own trace; a ``wait`` span is not, because the waits
+        of one event-loop thread interleave and a trace line nests."""
         if not self.enabled:
-            yield
+            yield OpenSpan(0, attrs)
             return
-        start = self.now()
+        kind = PHASE if phase else (kind or WORK)
+        parent = self.ambient_parent()
+        sp = OpenSpan(next(_span_ids), attrs)
+        ambient = _ambient.set((self, sp.id))
+        annotation = _annotate(name, self.op) if kind != WAIT else None
+        start = time.monotonic()
         token = self.op_enter(name)
         try:
-            yield
+            yield sp
         finally:
+            end = time.monotonic()
             self.op_exit(token)
-            self.record_span(name, start, self.now() - start, phase=phase, **attrs)
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+            try:
+                _ambient.reset(ambient)
+            except ValueError:  # closed in another context than it opened in
+                _ambient.set(None)
+            self._keep(
+                SpanRecord(
+                    sp.id, name, start, end, threading.current_thread().name,
+                    kind, self.op, parent, sp.attrs,
+                )
+            )
+
+    def handoff(
+        self,
+        name: str,
+        fn: Callable[..., Any],
+        work: Union[bool, str] = True,
+        **attrs: Any,
+    ) -> Callable[..., Any]:
+        """Wrap ``fn`` for an executor. Call this where the function is
+        submitted; the wrapper records on the worker thread
+        ``<name>.queued`` (kind ``wait``: from now until a worker picks
+        the function up) and, around the body, a span of kind ``work``:
+        ``<name>.work``, or the name given as ``work``, or none (False)
+        for a body that records its own spans. Both are children of the
+        span that is open at the submit (the request's span), and so is
+        whatever the body records: the recorder and that parent are
+        installed on the worker for the body's length."""
+        if not self.enabled:
+            return fn
+        parent = self.ambient_parent()
+        submitted = time.monotonic()
+        work_name = f"{name}.work" if work is True else work
+
+        def run(*args: Any, **kwargs: Any) -> Any:
+            self._keep(
+                SpanRecord(
+                    next(_span_ids), f"{name}.queued", submitted, time.monotonic(),
+                    threading.current_thread().name, WAIT, self.op, parent, {},
+                )
+            )
+            ambient = _ambient.set((self, parent) if parent is not None else None)
+            body = self.span(work_name, **attrs) if work_name else nullcontext()
+            try:
+                with use(self), body:
+                    return fn(*args, **kwargs)
+            finally:
+                _ambient.reset(ambient)
+
+        return run
 
     # --- live state (heartbeat/watchdog feed) ---------------------------
 
@@ -642,6 +864,12 @@ class TakeTelemetry:
         if self._finalized_wall_s is not None:
             return
         self._finalized_wall_s = self.now()
+        annotation, self._phase_annotation = self._phase_annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        ambient = _ambient.get()
+        if ambient is not None and ambient[0] is self:
+            _ambient.set(None)  # a phase that a failure left begun
         if self._rss_sampler is not None:
             try:
                 self._rss_sampler.stop()
@@ -674,10 +902,10 @@ class TakeTelemetry:
             probes = [dict(s) for s in self._probe_samples]
         by_name: Dict[str, List[float]] = {}
         phase_total: Dict[str, float] = {}
-        for name, _start, dur, _thread, phase, _attrs in spans:
-            by_name.setdefault(name, []).append(dur)
-            if phase:
-                phase_total[name] = phase_total.get(name, 0.0) + dur
+        for r in spans:
+            by_name.setdefault(r.name, []).append(r.duration_s)
+            if r.kind == PHASE:
+                phase_total[r.name] = phase_total.get(r.name, 0.0) + r.duration_s
         stages = {}
         for name, durs in sorted(by_name.items()):
             durs_sorted = sorted(durs)
@@ -692,6 +920,7 @@ class TakeTelemetry:
         out = {
             **self.meta,
             "rank": self.rank,
+            "op": self.op,
             "enabled": self.enabled,
             "started_at": self.wall0,
             "take_wall_s": round(take_wall, 6),
@@ -733,8 +962,11 @@ class TakeTelemetry:
 
     def chrome_trace_events(self) -> List[Dict[str, Any]]:
         """Chrome trace-event list: complete ("X") events for spans,
-        instant ("i") events for faults/retries, ts/dur in microseconds,
-        pid = rank, tid = recording thread name."""
+        instant ("i") events for faults/retries, ts/dur in microseconds
+        from ``t0`` (``t0_monotonic`` in the process_name event's args:
+        ``t0_monotonic + ts`` is the absolute monotonic reading), pid =
+        rank, tid = recording thread name. A span's ``args`` carry its
+        ``id``, ``kind``, ``op`` and ``parent`` beside its attributes."""
         with self._lock:
             spans = list(self._spans)
             events = list(self._events)
@@ -744,22 +976,31 @@ class TakeTelemetry:
                 "ph": "M",
                 "pid": self.rank,
                 "tid": 0,
-                "args": {"name": f"tpusnap rank {self.rank}"},
+                "args": {
+                    "name": f"tpusnap rank {self.rank}",
+                    "t0_monotonic": self.t0,
+                },
             }
         ]
-        for name, start, dur, thread, phase, attrs in spans:
-            ev: Dict[str, Any] = {
-                "name": name,
-                "ph": "X",
-                "cat": "phase" if phase else "op",
-                "ts": round(start * 1e6, 1),
-                "dur": round(dur * 1e6, 1),
-                "pid": self.rank,
-                "tid": thread,
-            }
-            if attrs:
-                ev["args"] = attrs
-            out.append(ev)
+        for r in spans:
+            out.append(
+                {
+                    "name": r.name,
+                    "ph": "X",
+                    "cat": "phase" if r.kind == PHASE else "op",
+                    "ts": round((r.start - self.t0) * 1e6, 1),
+                    "dur": round(r.duration_s * 1e6, 1),
+                    "pid": self.rank,
+                    "tid": r.thread,
+                    "args": {
+                        **r.attrs,
+                        "id": r.id,
+                        "kind": r.kind,
+                        "op": r.op,
+                        "parent": r.parent,
+                    },
+                }
+            )
         for name, ts, thread, attrs in events:
             ev = {
                 "name": name,
@@ -853,7 +1094,7 @@ def begin_restore(rank: int) -> TakeTelemetry:
     overlay it thread-locally via :func:`use` so an in-flight take's
     global recorder is never disturbed)."""
     _begin_common()
-    rec = TakeTelemetry(rank)
+    rec = TakeTelemetry(rank, kind="restore")
     rec.meta["kind"] = "restore"
     rec.meta["job_id"] = _job_id()
     return rec
@@ -925,15 +1166,40 @@ def use(rec: Optional[TakeTelemetry]) -> Generator[None, None, None]:
 
 
 @contextmanager
-def span(name: str, phase: bool = False, **attrs: Any) -> Generator[None, None, None]:
-    """Record a span into the ambient recorder; no-op (one lookup) when
-    no take is in flight or span capture is knob-disabled."""
-    rec = current()
+def span(
+    name: str,
+    phase: bool = False,
+    *,
+    kind: Optional[str] = None,
+    rec: Optional[TakeTelemetry] = None,
+    **attrs: Any,
+) -> Generator[OpenSpan, None, None]:
+    """Record a span into ``rec`` or the ambient recorder (see
+    :meth:`TakeTelemetry.span`); no-op (one lookup) when no take is in
+    flight or span capture is knob-disabled."""
+    rec = rec if rec is not None else current()
     if rec is None or not rec.enabled:
-        yield
+        yield OpenSpan(0, attrs)
         return
-    with rec.span(name, phase=phase, **attrs):
-        yield
+    with rec.span(name, phase=phase, kind=kind, **attrs) as sp:
+        yield sp
+
+
+def handoff(
+    name: str, fn: Callable[..., Any], work: Union[bool, str] = True, **attrs: Any
+) -> Callable[..., Any]:
+    """``fn`` wrapped for an executor, so that its wait for a worker and
+    its body are told apart (see :meth:`TakeTelemetry.handoff`). The
+    recorder is the one whose span is open where this is called (the
+    request's), else the ambient one; with neither, ``fn`` as it is."""
+    ambient = _ambient.get()
+    if ambient is not None and ambient[0]._finalized_wall_s is None:
+        rec = ambient[0]
+    else:
+        rec = current()
+    if rec is None:
+        return fn
+    return rec.handoff(name, fn, work=work, **attrs)
 
 
 def event(name: str, **attrs: Any) -> None:
@@ -967,7 +1233,13 @@ class PhaseMarker:
     """Sequential PHASE-span recorder for a linear pipeline: each call
     records a phase span from the previous mark (or construction) to
     now, so the recorded phases tile the timeline with no gaps — which
-    is what makes the trace CLI's wall-clock coverage meaningful."""
+    is what makes the trace CLI's wall-clock coverage meaningful.
+
+    A mark names the phase that just ended. Where the caller also names
+    the one that begins (``then=``, or :meth:`begin`), the marker holds
+    ``tpusnap:<name>`` open in the profiler's trace from mark to mark,
+    and spans opened meanwhile on this thread take the phase as their
+    parent. The phases of one marker run on one thread."""
 
     def __init__(
         self, rec: Optional[TakeTelemetry] = None, from_start: bool = False
@@ -981,17 +1253,53 @@ class PhaseMarker:
             if self.rec is not None and self.rec.enabled and not from_start
             else 0.0
         )
+        self._begun: Optional[Tuple[str, int]] = None  # (name, span id)
 
-    def __call__(self, name: str, **attrs: Any) -> None:
+    def begin(self, name: str) -> None:
+        """Say which phase runs from here to the next mark."""
+        rec = self.rec
+        if rec is None or not rec.enabled or rec._finalized_wall_s is not None:
+            return
+        self._end_begun()
+        self._begun = (name, next(_span_ids))
+        _ambient.set((rec, self._begun[1]))
+        rec._phase_annotation = _annotate(name, rec.op)
+
+    def _end_begun(self) -> None:
+        if self._begun is None:
+            return
+        self._begun = None
+        _ambient.set(None)
+        annotation, self.rec._phase_annotation = self.rec._phase_annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+
+    def __call__(self, name: str, then: Optional[str] = None, **attrs: Any) -> None:
         if self.rec is None or not self.rec.enabled:
             return
+        span_id = (
+            self._begun[1] if self._begun is not None and self._begun[0] == name else None
+        )
+        self._end_begun()
         now = self.rec.now()
-        self.rec.record_span(name, self.last, now - self.last, phase=True, **attrs)
+        self.rec.record_span(
+            name, self.last, now - self.last, phase=True, span_id=span_id, **attrs
+        )
         self.rec.note_phase(name)
         self.last = now
+        if then is not None:
+            self.begin(then)
 
-def phase_marker(from_start: bool = False) -> PhaseMarker:
-    return PhaseMarker(from_start=from_start)
+
+def phase_marker(
+    from_start: bool = False, first: Optional[str] = None
+) -> PhaseMarker:
+    """A marker on the ambient recorder; ``first`` names the phase that
+    begins here (see :meth:`PhaseMarker.begin`)."""
+    marker = PhaseMarker(from_start=from_start)
+    if first is not None:
+        marker.begin(first)
+    return marker
 
 
 # -------------------------------------------------------------- rollup
