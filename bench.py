@@ -701,7 +701,10 @@ def main() -> None:
             local_off_runs.append(el)
         shutil.rmtree(comp_root, ignore_errors=True)
         compress_section = {
-            "compress_codec_gbps": round(_comp_mod.codec_throughput_gbps(), 3),
+            # The codec's rate on the throttled auto take's own sample.
+            "compress_codec_gbps": (
+                round(comp_auto_dec.sample_gbps, 3) if comp_auto_dec else None
+            ),
             "compress_throttle_gbps": comp_bw_gbps,
             "compress_section_gb": round(comp_nbytes / 1024**3, 2),
             "compress_ratio": round(comp_nbytes / comp_stored, 3),

@@ -589,9 +589,9 @@ if not np.array_equal(tgt["app"]["w"], a):
 # (b) auto policy against a PINNED fast pipe: seed the ceiling
 # registry with a known-fast sample for this backend label, so the
 # gate asserts the policy's decision logic, not this runner's disk
-# weather (a cgroup-throttled CI disk measuring under codec/1.3
-# would legitimately compress — bench.py owns the measured-local
-# claim). Manifest stays codec-free on a bypassed take.
+# weather (a cgroup-throttled CI disk slower than what the codec
+# takes off it would legitimately compress — bench.py owns the
+# measured-local claim). Manifest stays codec-free on a bypassed take.
 from tpusnap.storage_plugin import url_to_storage_plugin
 
 compress._reset_ceilings()

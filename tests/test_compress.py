@@ -11,11 +11,14 @@ Covers the acceptance criteria:
   retake reuses the intact compressed blobs via the dual-hash rule;
 - the write-back tiering drain uploads compressed blobs, with the lag
   gauges counting COMPRESSED bytes;
-- the auto policy is measured: compress when the codec outruns the
-  recorded pipe ceiling, bypass when the pipe outruns the codec (or the
-  take is too small to amortize the decision).
+- the auto policy is measured on the take: the codec's ratio and rate on
+  a sample of the take's own bytes, weighed against the recorded pipe
+  ceiling (compress when rate x (1 - ratio) clearly outruns the pipe,
+  bypass when it does not, when a number is missing, or when the take
+  is too small to amortize the decision).
 """
 
+import contextlib
 import os
 import signal
 import subprocess
@@ -191,13 +194,14 @@ def test_codec_for_dtype_mapping():
         compress_mod.codec_elem("zstd19")  # future codec: loud refusal
 
 
-def _mk_reqs(nbytes=1 << 20, dtype=np.float32):
+def _mk_reqs(nbytes=1 << 20, dtype=np.float32, arr=None, **stager_kwargs):
     """One real ArrayBufferStager-backed write request, policy-eligible."""
     from tpusnap.io_preparers.array import ArrayBufferStager
     from tpusnap.io_types import WriteReq
     from tpusnap.serialization import dtype_to_string
 
-    arr = np.zeros(nbytes // np.dtype(dtype).itemsize, dtype=dtype)
+    if arr is None:
+        arr = np.zeros(nbytes // np.dtype(dtype).itemsize, dtype=dtype)
     entry = TensorEntry(
         location="0/w",
         serializer="buffer_protocol",
@@ -205,50 +209,268 @@ def _mk_reqs(nbytes=1 << 20, dtype=np.float32):
         shape=list(arr.shape),
         replicated=False,
     )
-    stager = ArrayBufferStager(arr, is_async_snapshot=False, entry=entry)
+    stager = ArrayBufferStager(
+        arr, is_async_snapshot=False, entry=entry, **stager_kwargs
+    )
     return [WriteReq(path="0/w", buffer_stager=stager)], stager
 
 
+def _full_entropy(shape, seed=0):
+    """f32 values with every mantissa bit in use: trained f32 state. The
+    codec removes ~7 % (the exponent plane), as on the chip's cells."""
+    return np.random.default_rng(seed).standard_normal(shape).astype(np.float32)
+
+
+def _pin_sample_rate(monkeypatch, gbps):
+    """The sample's ratio stays what the codec measured on the data; its
+    rate, which is this host's, is pinned so that a case means the same
+    on every machine."""
+    real = compress_mod._sample_codec
+
+    def pinned(eligible, rec):
+        sample = real(eligible, rec)
+        return None if sample is None else (sample[0], gbps, sample[2])
+
+    monkeypatch.setattr(compress_mod, "_sample_codec", pinned)
+
+
+_FULL, _BF16ISH = "full_entropy_f32", "bf16_precision_f32"
+
+# (data, the codec's rate on it in GB/s, pipe GB/s, compress?). The rates
+# are the chip host's: 0.65 GB/s on full-entropy f32 state, ~2 GB/s on
+# bf16-precision values, whose zeroed planes LZ4 skips over.
+_MATRIX = [
+    # 0.65 x (1 - 0.93) = 0.045 GB/s taken off the pipe: under 1.3 x any
+    # pipe a checkpoint is written to.
+    (_FULL, 0.65, 0.05, False),
+    (_FULL, 0.65, 0.2, False),
+    (_FULL, 0.65, 1.0, False),
+    (_FULL, 0.65, 10.0, False),
+    # 2.0 x (1 - 0.43) = 1.14 GB/s: a bucket loses, local NVMe wins.
+    (_BF16ISH, 2.0, 0.2, True),
+    (_BF16ISH, 2.0, 10.0, False),
+    # The margin: 1.14 against 1.3 x pipe turns between 0.8 and 0.95.
+    (_BF16ISH, 2.0, 0.8, True),
+    (_BF16ISH, 2.0, 0.95, False),
+    # A codec as slow as the pipe gains nothing whatever the ratio.
+    (_BF16ISH, 0.2, 0.2, False),
+]
+
+
 @needs_native
-def test_auto_policy_decision_matrix(monkeypatch):
-    monkeypatch.setattr(compress_mod, "codec_throughput_gbps", lambda: 2.0)
+@pytest.mark.parametrize(
+    "data,rate,pipe,want",
+    _MATRIX,
+    ids=[f"{d}-codec{r}-pipe{p}" for d, r, p, _ in _MATRIX],
+)
+def test_auto_policy_decision_matrix(monkeypatch, data, rate, pipe, want):
     monkeypatch.setattr(compress_mod, "AUTO_MIN_TAKE_BYTES", 1 << 18)
-
-    # Pipe faster than codec (local NVMe): bypass.
-    reqs, st = _mk_reqs()
-    compress_mod.note_pipe_ceiling("X", 10.0)
-    monkeypatch.setattr(compress_mod, "pipe_ceiling", lambda label: 10.0)
+    monkeypatch.setattr(compress_mod, "pipe_ceiling", lambda label: pipe)
+    _pin_sample_rate(monkeypatch, rate)
+    make = _full_entropy if data == _FULL else _bf16ish
+    reqs, st = _mk_reqs(arr=make(1 << 20))
     with override_compress(mode="auto", min_blob_bytes=65536):
         d = compress_mod.apply_take_policy(reqs, None, None)
-    assert (d.compress, d.reason) == (False, "pipe_outruns_codec")
-    assert st.compress_codec is None
+    assert (d.compress, d.reason) == (
+        (True, "codec_outruns_pipe") if want else (False, "pipe_outruns_codec")
+    )
+    assert st.compress_codec == ("shuf4+lz4" if want else None)
+    assert d.pipe_gbps == pipe and d.sample_gbps == rate
+    assert d.sample_bytes == 4 << 20  # the whole leaf: it is under the cap
+    if data == _FULL:
+        assert 0.9 < d.sample_ratio < 0.97
+    else:
+        assert 0.3 < d.sample_ratio < 0.5
+    # The rule, from the decision's own numbers.
+    assert want == (
+        d.sample_gbps * (1 - d.sample_ratio)
+        >= compress_mod.COMPRESS_MARGIN * d.pipe_gbps
+    )
 
-    # Pipe slower than codec (cloud): compress.
-    monkeypatch.setattr(compress_mod, "pipe_ceiling", lambda label: 0.2)
-    reqs, st = _mk_reqs()
-    with override_compress(mode="auto", min_blob_bytes=65536):
-        d = compress_mod.apply_take_policy(reqs, None, None)
-    assert (d.compress, d.reason) == (True, "codec_outruns_pipe")
-    assert st.compress_codec == "shuf4+lz4"
-    assert d.pipe_gbps == 0.2 and d.codec_gbps == 2.0
 
-    # At the margin (codec < pipe * 1.3): bypass — parity gains nothing.
-    monkeypatch.setattr(compress_mod, "pipe_ceiling", lambda label: 1.8)
-    reqs, st = _mk_reqs()
-    with override_compress(mode="auto", min_blob_bytes=65536):
-        d = compress_mod.apply_take_policy(reqs, None, None)
-    assert not d.compress
-
-    # Below the auto floor: bypass without consulting any ceiling.
+@needs_native
+def test_auto_policy_below_floor_takes_no_sample(monkeypatch):
+    monkeypatch.setattr(compress_mod, "AUTO_MIN_TAKE_BYTES", 1 << 18)
+    monkeypatch.setattr(compress_mod, "_sample_codec", _must_not_sample)
+    # Below the auto floor: bypass without consulting sample or ceiling.
     reqs, st = _mk_reqs(nbytes=1 << 17)
     with override_compress(mode="auto", min_blob_bytes=65536):
         d = compress_mod.apply_take_policy(reqs, None, None)
     assert (d.compress, d.reason) == (False, "below_auto_floor")
+    assert d.sample_bytes == 0
+
+
+def _must_not_sample(eligible, rec):
+    raise AssertionError("this decision takes no sample")
+
+
+@needs_native
+def test_sample_spreads_over_a_large_leaf_and_caps_its_bytes():
+    """A leaf over the cap is sampled in pieces spread from its head to
+    its tail: a leaf whose first half is zeros reads half compressible,
+    not all or nothing."""
+    n = (64 << 20) // 4
+    arr = np.random.default_rng(3).integers(0, 2**32, n, dtype=np.uint32).view(
+        np.float32
+    )
+    arr[: n // 2] = 0.0
+    _, st = _mk_reqs(arr=arr)
+    ratio, gbps, nbytes = compress_mod._sample_codec([st], None)
+    assert nbytes == compress_mod.SAMPLE_BYTES
+    assert 0.45 < ratio < 0.55 and gbps > 0
+
+
+def _ceiling_label(root):
+    """The registry key of a take under ``root``: noting a ceiling there
+    is what an earlier probe of that mount would have done."""
+    import asyncio
+
+    from tpusnap.storage_plugin import url_to_storage_plugin_in_event_loop
+
+    loop = asyncio.new_event_loop()
+    storage = url_to_storage_plugin_in_event_loop(str(root), loop)
+    try:
+        return compress_mod.pipe_ceiling_key(storage)
+    finally:
+        storage.sync_close(loop)
+        loop.close()
+
+
+@needs_native
+def test_two_takes_over_different_data_decide_differently(tmp_path):
+    """Nothing is carried from a synthetic buffer or from an earlier
+    take: under one pipe ceiling, a take of incompressible state stores
+    raw and the next take in the process, of bf16-precision state,
+    compresses; and back."""
+    label = _ceiling_label(tmp_path)
+    noise = np.random.default_rng(5).integers(
+        0, 2**32, (4096, 4096), dtype=np.uint32
+    ).view(np.float32)
+    smooth = _bf16ish((4096, 4096), seed=6)
+    seen = []
+    for i, arr in enumerate((noise, smooth, noise)):
+        # A slow bucket's ceiling, read by every take (no mini-probe).
+        compress_mod.note_pipe_ceiling(label, 0.01)
+        path = str(tmp_path / f"snap{i}")
+        with override_compress(mode="auto"), override_batching_disabled(True):
+            Snapshot.take(path, {"app": StateDict(w=arr.copy())})
+        d = compress_mod.LAST_DECISION
+        seen.append((d.compress, d.reason))
+        assert d.sample_bytes == compress_mod.SAMPLE_BYTES and d.pipe_gbps == 0.01
+        assert (_payload_bytes(path) < arr.nbytes) == d.compress
+        out = {"app": StateDict(w=np.zeros_like(arr))}
+        Snapshot(path).restore(out)
+        assert np.array_equal(out["app"]["w"].view(np.uint32), arr.view(np.uint32))
+    assert seen == [
+        (False, "pipe_outruns_codec"),
+        (True, "codec_outruns_pipe"),
+        (False, "pipe_outruns_codec"),
+    ]
+
+
+class _SpanSink(telemetry.MetricsSink):
+    def __init__(self):
+        self.records = []
+
+    def on_span_record(self, record):
+        self.records.append(record)
+
+
+@contextlib.contextmanager
+def _compile_events():
+    """Every lowering, compile or cache fetch JAX reports inside the
+    block (the events the benchmark allows none of inside its window)."""
+    import jax
+
+    events = []
+
+    def listener(event, duration, **kw):
+        if "compil" in event:
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield events
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
+@needs_native
+def test_sample_reads_a_jax_leaf_without_a_device_operation(monkeypatch):
+    """The sample's bytes are the host copy that ``prepare`` started:
+    between the stager's construction and the decision nothing is
+    lowered or compiled, and the stager's own ``dtoh`` span (with the
+    leaf's bytes) follows the sample's."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(compress_mod, "AUTO_MIN_TAKE_BYTES", 1 << 18)
+    monkeypatch.setattr(compress_mod, "pipe_ceiling_key", lambda storage: "X")
+    compress_mod.note_pipe_ceiling("X", 0.001)
+    leaf = jnp.asarray(_bf16ish((1024, 1024), seed=8))
+    with _compile_events() as heard:
+        (leaf * 2).block_until_ready()
+    assert heard  # the listener does hear this backend
+    rec = telemetry.TakeTelemetry(rank=0, enabled=True)
+    with telemetry.metrics_sink(_SpanSink()) as sink, _compile_events() as compiles:
+        reqs, st = _mk_reqs(arr=leaf)  # prepare: the copy to the host starts
+        assert st.dtoh_started is not None and st.host_bytes_are_free()
+        with override_compress(mode="auto", min_blob_bytes=65536):
+            d = compress_mod.apply_take_policy(reqs, None, None, rec=rec)
+        assert d.compress and d.sample_bytes == leaf.nbytes
+        with telemetry.use(rec):
+            staged = st._stage_blocking()
+    assert compiles == []
+    assert memoryview(staged).nbytes < leaf.nbytes  # the compressed blob
+    spans = [r for r in sink.records if r.name in ("dtoh", "compress.sample")]
+    assert [r.name for r in spans] == ["dtoh", "compress.sample", "dtoh"]
+    wait, sample, own = spans
+    assert wait.kind == telemetry.WAIT and "bytes" not in wait.attrs
+    assert sample.kind == telemetry.WORK and sample.attrs["bytes"] == leaf.nbytes
+    assert 0 < sample.attrs["out_bytes"] < leaf.nbytes
+    assert own.attrs["bytes"] == leaf.nbytes and own.start >= sample.end
+    meta = rec.meta["compress"]
+    assert meta["sample_bytes"] == leaf.nbytes and meta["sample_gbps"] > 0
+    assert 0.3 < meta["sample_ratio"] < 0.5 and meta["pipe_gbps"] == 0.001
+    rec.finalize()
+
+
+@needs_native
+def test_no_sample_source_bypasses_and_says_so(monkeypatch):
+    """A leaf behind an ``array_prepare_func`` stages another array's
+    bytes, and they are not to be had before staging: a take with only
+    such leaves decides bypass, reason ``no_sample``, and probes no pipe."""
+    monkeypatch.setattr(compress_mod, "AUTO_MIN_TAKE_BYTES", 1 << 18)
+    monkeypatch.setattr(compress_mod, "_policy_probe", _must_not_sample)
+    calls = []
+
+    def prepare(arr, tracing):
+        calls.append(tracing)
+        return arr
+
+    reqs, st = _mk_reqs(arr=_bf16ish(1 << 20), array_prepare_func=prepare)
+    assert not st.host_bytes_are_free()
+    rec = telemetry.TakeTelemetry(rank=0, enabled=False)
+    with override_compress(mode="auto", min_blob_bytes=65536):
+        d = compress_mod.apply_take_policy(reqs, None, None, rec=rec)
+    assert (d.compress, d.reason) == (False, "no_sample")
+    assert st.compress_codec is None and not calls
+    assert rec.meta["compress"]["reason"] == "no_sample"
+    assert rec.meta["compress"]["sample_bytes"] == 0
+    # One free leaf among them is enough, and it is the one sampled.
+    free_reqs, free = _mk_reqs(arr=np.zeros(1 << 18, np.float32))
+    compress_mod.note_pipe_ceiling("X", 0.001)
+    monkeypatch.setattr(compress_mod, "pipe_ceiling_key", lambda s: "X")
+    with override_compress(mode="auto", min_blob_bytes=65536):
+        d = compress_mod.apply_take_policy(reqs + free_reqs, None, None)
+    assert d.compress and d.sample_bytes == 1 << 20 and not calls
+    assert st.compress_codec == free.compress_codec == "shuf4+lz4"
 
 
 @needs_native
 def test_forced_modes_and_eligibility(monkeypatch):
-    monkeypatch.setattr(compress_mod, "codec_throughput_gbps", lambda: 2.0)
+    # Forced modes decide without a sample and without a ceiling.
+    monkeypatch.setattr(compress_mod, "_sample_codec", _must_not_sample)
+    monkeypatch.setattr(compress_mod, "_policy_probe", _must_not_sample)
     # off: never compresses.
     reqs, st = _mk_reqs()
     with override_compress(mode="off"):
@@ -259,6 +481,7 @@ def test_forced_modes_and_eligibility(monkeypatch):
     with override_compress(mode="on", min_blob_bytes=65536):
         d = compress_mod.apply_take_policy(reqs, None, None)
     assert (d.compress, d.reason) == (True, "mode_forced")
+    assert d.sample_bytes == 0 and d.to_meta()["sample_gbps"] == 0.0
     # Below the per-blob floor: not eligible even when forced.
     reqs, st = _mk_reqs(nbytes=1 << 17)
     with override_compress(mode="on", min_blob_bytes=1 << 20):
@@ -273,14 +496,38 @@ def test_forced_modes_and_eligibility(monkeypatch):
 
 
 @needs_native
-def test_policy_mini_probe_measures_and_cleans_up(tmp_path, monkeypatch):
+@pytest.mark.parametrize("mode", ["on", "lz4"])
+def test_forced_mode_take_is_bit_exact_without_a_sample(tmp_path, monkeypatch, mode):
+    """``on``/``lz4`` compress whatever the data and the pipe: even
+    full-entropy state on a fast local disk, which ``auto`` stores raw."""
+    monkeypatch.setattr(compress_mod, "_sample_codec", _must_not_sample)
+    a = _full_entropy((2048, 512), seed=9)
+    path = str(tmp_path / "snap")
+    with override_compress(mode=mode, min_blob_bytes=65536), \
+            override_batching_disabled(True):
+        Snapshot.take(path, {"app": StateDict(w=a.copy())})
+    d = compress_mod.LAST_DECISION
+    assert (d.mode, d.compress, d.reason, d.sample_bytes) == (mode, True, "mode_forced", 0)
+    assert _payload_bytes(path) < a.nbytes
+    out = {"app": StateDict(w=np.zeros_like(a))}
+    Snapshot(path).restore(out)
+    assert np.array_equal(out["app"]["w"].view(np.uint32), a.view(np.uint32))
+
+
+@needs_native
+@pytest.mark.parametrize("durable", ["0", "1"], ids=["plain", "durable_commit"])
+def test_policy_mini_probe_measures_and_cleans_up(tmp_path, monkeypatch, durable):
     """auto with no recorded ceiling: the one-shot mini-probe measures
-    through the take's own plugin stack, caches the ceiling, and leaves
-    no probe files behind."""
+    through the take's own plugin stack (under ``TPUSNAP_DURABLE_COMMIT``
+    that is a second executor trip and an fsync a blob), caches the
+    ceiling, and leaves no probe files behind. The decision weighs this
+    take's sample against it, and the next take in the process samples
+    again but probes no more."""
     import asyncio
 
     from tpusnap.storage_plugin import url_to_storage_plugin_in_event_loop
 
+    monkeypatch.setenv("TPUSNAP_DURABLE_COMMIT", durable)
     monkeypatch.setattr(compress_mod, "AUTO_MIN_TAKE_BYTES", 1 << 18)
     loop = asyncio.new_event_loop()
     storage = url_to_storage_plugin_in_event_loop(str(tmp_path), loop)
@@ -291,7 +538,8 @@ def test_policy_mini_probe_measures_and_cleans_up(tmp_path, monkeypatch):
         assert "@" in label
         compress_mod._reset_ceilings()
         assert compress_mod.pipe_ceiling(label) is None
-        reqs, _ = _mk_reqs()
+        probes = telemetry.counter_value("compress.policy_probes")
+        reqs, _ = _mk_reqs(arr=_full_entropy(1 << 20))
         with override_compress(mode="auto", min_blob_bytes=65536):
             d = compress_mod.apply_take_policy(reqs, storage, loop)
         assert d.reason in ("codec_outruns_pipe", "pipe_outruns_codec")
@@ -299,9 +547,22 @@ def test_policy_mini_probe_measures_and_cleans_up(tmp_path, monkeypatch):
         assert compress_mod.pipe_ceiling(label) == pytest.approx(
             d.pipe_gbps, rel=1e-3
         )
+        assert d.sample_bytes == 4 << 20 and 0.9 < d.sample_ratio < 0.97
+        assert d.compress == (
+            d.sample_gbps * (1 - d.sample_ratio)
+            >= compress_mod.COMPRESS_MARGIN * d.pipe_gbps
+        )
+        assert telemetry.counter_value("compress.policy_probes") == probes + 1
         assert not os.path.exists(str(tmp_path / ".tpusnap" / "probe")) or (
             os.listdir(str(tmp_path / ".tpusnap" / "probe")) == []
         )
+        # The second take: the registry's ceiling, a sample of its own.
+        reqs, _ = _mk_reqs(arr=_bf16ish(1 << 20))
+        with override_compress(mode="auto", min_blob_bytes=65536):
+            d2 = compress_mod.apply_take_policy(reqs, storage, loop)
+        assert telemetry.counter_value("compress.policy_probes") == probes + 1
+        assert d2.pipe_gbps == pytest.approx(d.pipe_gbps, rel=1e-3)
+        assert 0.3 < d2.sample_ratio < 0.5
     finally:
         storage.sync_close(loop)
         loop.close()
@@ -729,10 +990,14 @@ def test_tiering_drain_counts_compressed_bytes(tmp_path):
 
 
 @needs_native
-def test_decision_and_ratio_ride_summary_history_and_prom(tmp_path):
+@pytest.mark.parametrize("mode", ["on", "auto"])
+def test_decision_and_ratio_ride_summary_history_and_prom(
+    tmp_path, monkeypatch, mode
+):
     """The resolved policy decision + codec counters land in the take
     summary, flow into the history event (flat gateable scalars) and
-    the Prometheus textfile export."""
+    the Prometheus textfile export. An ``auto`` decision carries what
+    its sample read; a forced one took none."""
     from tpusnap.history import event_from_summary
     from tpusnap.metrics_export import (
         PrometheusTextfileSink,
@@ -741,14 +1006,21 @@ def test_decision_and_ratio_ride_summary_history_and_prom(tmp_path):
 
     a = _bf16ish((2048, 256), seed=14)
     path = str(tmp_path / "snap")
+    monkeypatch.setattr(compress_mod, "AUTO_MIN_TAKE_BYTES", 1 << 18)
+    compress_mod.note_pipe_ceiling(_ceiling_label(tmp_path), 0.001)
     with override_compress(
-        mode="on", min_blob_bytes=65536
+        mode=mode, min_blob_bytes=65536
     ), override_batching_disabled(True):
         Snapshot.take(path, {"app": StateDict(w=a.copy())})
     summary = telemetry.LAST_TAKE_SUMMARY
     comp = summary.get("compress")
     assert comp and comp["decision"] == "compress"
-    assert comp["codec_gbps"] > 0
+    sampled = mode == "auto"
+    assert comp["reason"] == ("codec_outruns_pipe" if sampled else "mode_forced")
+    assert comp["sample_bytes"] == (a.nbytes if sampled else 0)
+    assert (comp["sample_gbps"] > 0) == sampled
+    assert (0.3 < comp["sample_ratio"] < 0.5) == sampled
+    assert summary["stages"].get("compress.sample", {}).get("count", 0) == sampled
     counters = summary["counters"]
     assert counters["compress.bytes_in"] == a.nbytes
     assert 0 < counters["compress.bytes_out"] < a.nbytes
@@ -757,7 +1029,8 @@ def test_decision_and_ratio_ride_summary_history_and_prom(tmp_path):
     ev = event_from_summary("take", summary)
     assert ev["compress_decision"] == "compress"
     assert ev["compress_ratio"] > 1.2
-    assert ev["compress_codec_gbps"] > 0
+    assert (ev.get("compress_codec_gbps", 0) > 0) == sampled
+    assert ev.get("compress_sample_ratio") == (comp["sample_ratio"] if sampled else None)
     assert ev["compress_bytes_out"] == counters["compress.bytes_out"]
 
     sink = PrometheusTextfileSink(directory=str(tmp_path / "prom"))
@@ -781,6 +1054,7 @@ def test_analyze_attributes_compress_as_own_resource():
     from tpusnap.analyze import ADVICE, WORK_PRIORITY, classify_span
 
     assert classify_span("compress") == "compress"
+    assert classify_span("compress.sample") == "compress"
     assert "compress" in WORK_PRIORITY
     assert "TPUSNAP_COMPRESS" in ADVICE["compress"]
     # The write-bound advice recommends the policy flip the other way.

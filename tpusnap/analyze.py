@@ -69,6 +69,7 @@ _CATEGORY_EXACT = {
     "checksum_late": "checksum",
     "cow_verify": "checksum",
     "compress": "compress",
+    "compress.sample": "compress",
     "consume": "consume",
     "restore.decode": "decode",
     "budget_wait": "budget_wait",

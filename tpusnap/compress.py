@@ -1,4 +1,4 @@
-"""Dtype-aware fused tile compression: codec model + probe-driven auto policy.
+"""Dtype-aware fused tile compression: codec model + sampled auto policy.
 
 The native engine's staging hot path already makes one fused pass per
 tile (clone + CRC32C + XXH64); on network-bound destinations (cloud,
@@ -9,17 +9,39 @@ bytes group into near-constant planes; fp8/int8 skip the filter)
 followed by LZ4 block compression, per checksum tile, preserving
 tile-grain random access on the restore path.
 
-The policy is MEASURED, not configured (``TPUSNAP_COMPRESS=auto``, the
-default): compress when the pipe's probe-reported write ceiling is
-clearly slower than the codec's measured throughput, bypass when local
-disk outruns it. Ceilings come from the in-take roofline probes
-(``TPUSNAP_PROBE=1``, scheduler._ProbeRunner feeds every sample here)
-or — when no sample exists yet and the take is large enough to amortize
-it — from a one-shot policy mini-probe through the take's own plugin
-stack. Codec throughput is measured once per process on a synthetic
-bf16-precision buffer. All checksums/dedup hashes of a compressed blob
-are recorded over the STORED (compressed) bytes, so the journal/salvage/
-upload-journal dual-hash evidence rule, scrub and fsck hold unchanged.
+The policy is MEASURED ON THE TAKE, not configured
+(``TPUSNAP_COMPRESS=auto``, the default). Before anything is staged,
+the real codec pass runs over ``SAMPLE_BYTES`` of the host bytes of the
+eligible leaf staging reaches first (the largest; its copy to the host
+was started at prepare time, so the sample waits out only what is left
+of that copy and runs no device operation), and reads two numbers:
+``sample_ratio`` r (bytes out / bytes in) and ``sample_gbps`` c (bytes
+in / second, on the threads staging will use). The pipe's write ceiling
+p comes from the in-take roofline probes (``TPUSNAP_PROBE=1``,
+scheduler._ProbeRunner feeds every sample here) or, when no sample
+exists yet and the take is large enough to amortize it, from a one-shot
+policy mini-probe through the take's own plugin stack.
+
+The rule weighs what the codec removes against what it costs. Staged
+serially, as in the window ``async_take`` blocks the caller on, B raw
+bytes cost B/p on the pipe; compressed they cost B/c in the codec and
+r*B/p on the pipe. The codec pays when B/c + r*B/p < B/p, that is when
+it takes bytes off the pipe faster than the pipe would have carried
+them:
+
+    c * (1 - r) >= COMPRESS_MARGIN * p
+
+With staging and writes overlapped the codec can only pay when c > p,
+which the rule implies (r >= 0), so the serial form is the stricter
+one. The gain is bounded by 1 - r and the cost is not: full-entropy
+f32 state (r ~0.93, c ~0.65 GB/s: 0.05 GB/s taken off the pipe)
+bypasses on any pipe a checkpoint is written to, bf16-precision values
+held in f32 (r ~0.5, c ~2 GB/s) compress under a 0.2 GB/s bucket.
+Doubt means bypass: no leaf whose bytes are free to read
+(``no_sample``), no ceiling, a failed sample. All checksums/dedup
+hashes of a compressed blob are recorded over the STORED (compressed)
+bytes, so the journal/salvage/upload-journal dual-hash evidence rule,
+scrub and fsck hold unchanged.
 """
 
 from __future__ import annotations
@@ -27,6 +49,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -37,10 +60,18 @@ logger = logging.getLogger(__name__)
 # the codec bookkeeping, and bypass is within noise there anyway.
 AUTO_MIN_TAKE_BYTES = 64 << 20
 
-# Compress only when the codec clearly outruns the pipe: at parity the
-# codec would serialize the take behind the CPU for ~zero effective
-# gain, and the probe ceiling itself carries measurement noise.
+# Compress only when what the codec takes off the pipe clearly outruns
+# the pipe: at parity the codec would serialize the take behind the CPU
+# for ~zero effective gain, and both the probe ceiling and an 8 MiB
+# sample carry measurement noise.
 COMPRESS_MARGIN = 1.3
+
+# The policy's codec sample: this many bytes of the take's own state,
+# gathered as equal pieces spread evenly over one leaf (a padded tail
+# or a zero head must not speak for the whole), each piece one tile of
+# the sampling pass so that it runs on as many threads as staging's.
+SAMPLE_BYTES = 8 << 20
+_SAMPLE_PIECES = 4
 
 # Policy mini-probe: streams x bytes written through the take's own
 # plugin stack (PROBE_DIR namespace: journal-exempt sidecar space, a
@@ -161,52 +192,6 @@ def _reset_ceilings() -> None:
         _ceilings.clear()
 
 
-# ------------------------------------------------------- codec throughput
-
-_codec_gbps: Optional[float] = None
-_codec_lock = threading.Lock()
-
-
-def codec_throughput_gbps() -> float:
-    """Measured compression throughput of this host (GB/s of input
-    consumed), cached per process. The sample is an 8 MiB f32 buffer
-    holding bf16-precision values — the mixed-precision-export shape
-    the policy most often judges — compressed through the same fused
-    native pass takes use. 0.0 when the native codec is unavailable
-    (the policy then always bypasses)."""
-    global _codec_gbps
-    with _codec_lock:
-        if _codec_gbps is not None:
-            return _codec_gbps
-        from . import _native
-        from .knobs import get_native_copy_threads
-
-        if not _native.compression_available():
-            _codec_gbps = 0.0
-            return _codec_gbps
-        import numpy as np
-
-        rng = np.random.default_rng(0x7C0)
-        arr = rng.standard_normal(2 << 20).astype(np.float32)
-        arr = (arr.view(np.uint32) & np.uint32(0xFFFF0000)).view(np.float32)
-        buf = arr.tobytes()
-        t0 = time.monotonic()
-        _native.compress_tiles(
-            buf, 4 << 20, 4, False, nthreads=get_native_copy_threads()
-        )
-        elapsed = max(time.monotonic() - t0, 1e-9)
-        _codec_gbps = round(len(buf) / elapsed / 1e9, 4)
-        logger.info("measured codec throughput: %.3f GB/s", _codec_gbps)
-        return _codec_gbps
-
-
-def _reset_codec_throughput() -> None:
-    """Test seam."""
-    global _codec_gbps
-    with _codec_lock:
-        _codec_gbps = None
-
-
 # ---------------------------------------------------------------- decision
 
 
@@ -214,12 +199,17 @@ def _reset_codec_throughput() -> None:
 class CompressDecision:
     """One take's resolved compression policy, recorded in the take's
     telemetry meta (→ summary → history event) and readable after the
-    fact via ``LAST_DECISION`` (ci_gate's smoke asserts on it)."""
+    fact via ``LAST_DECISION`` (ci_gate's smoke asserts on it). The
+    ``sample_*`` fields are what the codec did to ``sample_bytes`` of
+    this take's own state; they stay 0 where no sample was taken
+    (forced modes, and every bypass decided before the sample)."""
 
     mode: str
     compress: bool
     reason: str
-    codec_gbps: float = 0.0
+    sample_ratio: float = 0.0
+    sample_gbps: float = 0.0
+    sample_bytes: int = 0
     pipe_gbps: Optional[float] = None
     eligible_bytes: int = 0
 
@@ -228,7 +218,9 @@ class CompressDecision:
             "mode": self.mode,
             "decision": "compress" if self.compress else "bypass",
             "reason": self.reason,
-            "codec_gbps": self.codec_gbps,
+            "sample_ratio": self.sample_ratio,
+            "sample_gbps": self.sample_gbps,
+            "sample_bytes": self.sample_bytes,
             "eligible_bytes": self.eligible_bytes,
         }
         if self.pipe_gbps is not None:
@@ -314,13 +306,64 @@ def _eligible_stagers(write_reqs) -> List[object]:
     return out
 
 
+def _sample_codec(eligible, rec) -> Optional[Tuple[float, float, int]]:
+    """``(ratio, gbps, bytes)`` of the real codec pass over a sample of
+    this take's own bytes, or None when no eligible stager gives its
+    host bytes away for free. The source is the eligible stager staging
+    reaches first: the scheduler stages largest first and keeps request
+    order among equals, and so does ``max``."""
+    import numpy as np
+
+    from . import _native, telemetry
+    from .knobs import get_native_copy_threads
+    from .serialization import array_as_memoryview
+
+    sources = [st for st in eligible if st.host_bytes_are_free()]
+    if not sources:
+        return None
+    st = max(sources, key=lambda s: s.get_planned_bytes())
+    spans = rec is not None and rec.enabled
+    t0 = rec.now() if spans else 0.0
+    host = np.asarray(st.arr)
+    if spans and not isinstance(st.arr, np.ndarray):
+        # What is left of the copy started at prepare time: the wait the
+        # staging thread would have paid for this leaf. It keeps that
+        # wait's name; the leaf's bytes stay with the stager's own span.
+        rec.record_span(
+            "dtoh", t0, rec.now() - t0, kind=telemetry.WAIT, sample=True
+        )
+    flat = np.frombuffer(array_as_memoryview(host), np.uint8)
+    elem = codec_elem(codec_for_dtype(st.entry.dtype))
+    with rec.span("compress.sample") if spans else nullcontext() as sp:
+        if flat.nbytes > SAMPLE_BYTES:
+            piece = SAMPLE_BYTES // _SAMPLE_PIECES
+            stride = (flat.nbytes - piece) // (_SAMPLE_PIECES - 1) // elem * elem
+            flat = np.concatenate(
+                [flat[i * stride : i * stride + piece] for i in range(_SAMPLE_PIECES)]
+            )
+        else:
+            piece = max(elem, flat.nbytes // _SAMPLE_PIECES // elem * elem)
+        t_codec = time.monotonic()
+        out = _native.compress_tiles(
+            flat, piece, elem, False, nthreads=get_native_copy_threads()
+        )[0]
+        elapsed = max(time.monotonic() - t_codec, 1e-9)
+        if sp is not None:
+            sp.attrs.update(bytes=flat.nbytes, out_bytes=out.nbytes)
+    return (
+        round(out.nbytes / flat.nbytes, 4),
+        round(flat.nbytes / elapsed / 1e9, 4),
+        flat.nbytes,
+    )
+
+
 def apply_take_policy(write_reqs, storage, event_loop, rec=None):
     """Resolve this take's compress-or-bypass decision and arm the
     eligible stagers. Called once per take, after batching and before
     scheduling; never raises (a policy failure must not fail a take)."""
     global LAST_DECISION
     try:
-        decision = _apply_take_policy_impl(write_reqs, storage, event_loop)
+        decision = _apply_take_policy_impl(write_reqs, storage, event_loop, rec)
     except Exception:
         logger.warning("compression policy failed (bypassing)", exc_info=True)
         decision = CompressDecision(
@@ -341,7 +384,8 @@ def apply_take_policy(write_reqs, storage, event_loop, rec=None):
                 "compress_policy",
                 op=decision.reason,
                 decision="compress" if decision.compress else "bypass",
-                codec_gbps=decision.codec_gbps,
+                sample_ratio=decision.sample_ratio,
+                sample_gbps=decision.sample_gbps,
                 pipe_gbps=decision.pipe_gbps,
             )
     except Exception:
@@ -349,74 +393,51 @@ def apply_take_policy(write_reqs, storage, event_loop, rec=None):
     return decision
 
 
-def _apply_take_policy_impl(write_reqs, storage, event_loop):
+def _apply_take_policy_impl(write_reqs, storage, event_loop, rec=None):
     from . import _native
     from .knobs import get_compress_mode, is_checksum_disabled
 
     mode = get_compress_mode()
+
+    def bypass(reason: str, **fields) -> CompressDecision:
+        return CompressDecision(mode=mode, compress=False, reason=reason, **fields)
+
     if mode == "off":
-        return CompressDecision(mode=mode, compress=False, reason="mode_off")
+        return bypass("mode_off")
     if is_checksum_disabled():
         # Compressed restores verify the stored bytes by checksum; with
         # checksums off there is no integrity evidence to record.
-        return CompressDecision(
-            mode=mode, compress=False, reason="checksums_disabled"
-        )
+        return bypass("checksums_disabled")
     if not _native.compression_available():
-        return CompressDecision(
-            mode=mode, compress=False, reason="native_unavailable"
-        )
+        return bypass("native_unavailable")
     eligible = _eligible_stagers(write_reqs)
     if not eligible:
-        return CompressDecision(
-            mode=mode, compress=False, reason="no_eligible_blobs"
-        )
+        return bypass("no_eligible_blobs")
     eligible_bytes = sum(st.get_planned_bytes() for st in eligible)
-    codec_gbps = codec_throughput_gbps()
-    pipe = None
+    measured = {"eligible_bytes": eligible_bytes}
     if mode == "auto":
         if eligible_bytes < AUTO_MIN_TAKE_BYTES:
-            return CompressDecision(
-                mode=mode,
-                compress=False,
-                reason="below_auto_floor",
-                codec_gbps=codec_gbps,
-                eligible_bytes=eligible_bytes,
-            )
+            return bypass("below_auto_floor", **measured)
+        sample = _sample_codec(eligible, rec)
+        if sample is None:
+            return bypass("no_sample", **measured)
+        ratio, gbps, nbytes = sample
+        measured.update(sample_ratio=ratio, sample_gbps=gbps, sample_bytes=nbytes)
         label = pipe_ceiling_key(storage)
         pipe = pipe_ceiling(label)
         if pipe is None:
             pipe = _policy_probe(storage, event_loop, label)
         if pipe is None:
-            return CompressDecision(
-                mode=mode,
-                compress=False,
-                reason="no_pipe_ceiling",
-                codec_gbps=codec_gbps,
-                eligible_bytes=eligible_bytes,
-            )
-        if codec_gbps < pipe * COMPRESS_MARGIN:
-            return CompressDecision(
-                mode=mode,
-                compress=False,
-                reason="pipe_outruns_codec",
-                codec_gbps=codec_gbps,
-                pipe_gbps=pipe,
-                eligible_bytes=eligible_bytes,
-            )
+            return bypass("no_pipe_ceiling", **measured)
+        measured["pipe_gbps"] = pipe
+        if gbps * (1.0 - ratio) < pipe * COMPRESS_MARGIN:
+            return bypass("pipe_outruns_codec", **measured)
         reason = "codec_outruns_pipe"
     else:
         reason = "mode_forced"
     for st in eligible:
         st.compress_codec = codec_for_dtype(st.entry.dtype)
-    return CompressDecision(
-        mode=mode,
-        compress=True,
-        reason=reason,
-        codec_gbps=codec_gbps,
-        pipe_gbps=pipe,
-        eligible_bytes=eligible_bytes,
-    )
+    return CompressDecision(mode=mode, compress=True, reason=reason, **measured)
 
 
 # ------------------------------------------------------- restore helpers

@@ -155,8 +155,9 @@ def event_from_summary(kind: str, summary: Dict[str, Any]) -> Dict[str, Any]:
     if isinstance(comp, dict):
         ev["compress_decision"] = comp.get("decision")
         ev["compress_reason"] = comp.get("reason")
-        if comp.get("codec_gbps"):
-            ev["compress_codec_gbps"] = comp["codec_gbps"]
+        if comp.get("sample_gbps"):
+            ev["compress_codec_gbps"] = comp["sample_gbps"]
+            ev["compress_sample_ratio"] = comp.get("sample_ratio")
         if comp.get("pipe_gbps") is not None:
             ev["compress_pipe_gbps"] = comp["pipe_gbps"]
     c_in = counters.get("compress.bytes_in", 0)

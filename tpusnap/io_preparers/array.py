@@ -168,6 +168,18 @@ class ArrayBufferStager(BufferStager):
             # untransformed array's DtoH would be wasted DMA.
             self.dtoh_started = enqueue_dtoh(arr)
 
+    def host_bytes_are_free(self) -> bool:
+        """Whether ``np.asarray(self.arr)`` runs no device operation
+        before staging does: a numpy leaf, or one whose copy to the host
+        was started above (the call then waits out what is left of that
+        copy, and JAX keeps the host value for ``_stage_blocking``'s own
+        call). Never for a leaf behind an ``array_prepare_func``, whose
+        staged bytes are another array's. The compress policy samples
+        only such a leaf."""
+        if self.array_prepare_func is not None:
+            return False
+        return isinstance(self.arr, np.ndarray) or self.dtoh_started is not None
+
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         loop = asyncio.get_running_loop()
         if executor is not None:

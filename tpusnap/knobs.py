@@ -686,13 +686,18 @@ _warned_compress_modes: set = set()
 def get_compress_mode() -> str:
     """Per-take fused tile compression (:mod:`tpusnap.compress`):
 
-    - ``auto`` (default) — a MEASURED per-take decision: compress when
-      the storage pipe's probe-reported ceiling is clearly slower than
-      the codec's measured throughput (cloud, virtio, the write-back
-      tier's remote drain), bypass when local disk outruns it. Takes
-      whose eligible payload is below the auto floor always bypass
-      (small takes are not worth the codec bookkeeping or a probe).
-    - ``on`` — compress every eligible blob regardless of the pipe.
+    - ``auto`` (default) — a per-take decision MEASURED ON THE TAKE: the
+      codec runs over an 8 MiB sample of the state's own host bytes, and
+      the take compresses when rate x (1 - ratio) of that sample, the
+      bytes the codec takes off the pipe per second, clearly outruns the
+      pipe's probe-reported ceiling (compressible state to cloud,
+      virtio, the write-back tier's remote drain); it bypasses when the
+      state barely compresses or local disk outruns the codec, and
+      whenever a number is missing. Takes whose eligible payload is
+      below the auto floor always bypass (small takes are not worth
+      the codec bookkeeping, a sample or a probe).
+    - ``on`` — compress every eligible blob regardless of the pipe;
+      takes no sample.
     - ``off`` — bypass entirely.
     - ``lz4`` — force the named codec family (same as ``on`` today;
       the name exists so a future codec can be pinned explicitly).

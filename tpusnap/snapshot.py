@@ -1620,10 +1620,13 @@ def _take_impl(
     entries = dict(zip(entries.keys(), entries_list))
 
     # Fused tile compression (tpusnap.compress): ONE measured
-    # compress-or-bypass decision per take — the codec's measured
-    # throughput against the probe-reported pipe ceiling — armed on the
-    # eligible stagers (standalone dense blobs after batching; slab
-    # members and shards bypass by construction). Never fails a take.
+    # compress-or-bypass decision per take — what the codec takes off
+    # the pipe, by its ratio and rate on a sample of this take's own
+    # host bytes, against the probe-reported pipe ceiling — armed on
+    # the eligible stagers (standalone dense blobs after batching; slab
+    # members and shards bypass by construction). Made before
+    # scheduling, which reads the stagers' staging cost. Never fails a
+    # take.
     from . import compress as _compress
 
     _compress.apply_take_policy(write_reqs, storage, event_loop, rec=mark.rec)

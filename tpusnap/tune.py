@@ -192,9 +192,9 @@ def build_plan(
     """The planner. ``events`` is the full history (oldest first);
     ``ceilings`` a :func:`compress.pipe_ceilings_snapshot`; ``verdict``
     the analyze bound category when the caller computed one;
-    ``codec_gbps`` the measured codec throughput (None → read it from
-    :func:`compress.codec_throughput_gbps`). Current knob values come
-    from :mod:`tpusnap.knobs` (env + any applied plan)."""
+    ``codec_gbps`` the codec's throughput (None → the ``sample_gbps`` of
+    this process's last take decision, 0 without one). Current knob
+    values come from :mod:`tpusnap.knobs` (env + any applied plan)."""
     from . import knobs
 
     cell = select_events(
@@ -230,12 +230,10 @@ def build_plan(
         return plan
 
     if codec_gbps is None:
-        from .compress import codec_throughput_gbps
+        from . import compress
 
-        try:
-            codec_gbps = codec_throughput_gbps()
-        except Exception:
-            codec_gbps = 0.0
+        last = compress.LAST_DECISION
+        codec_gbps = last.sample_gbps if last is not None else 0.0
 
     med_bytes = _metric_median(cell, "bytes")
     med_wall = _metric_median(cell, "wall_s")
