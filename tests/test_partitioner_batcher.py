@@ -191,6 +191,34 @@ class TestDeviceBatching:
             start, end = entries[name].byte_range
             assert bytes(mv[start:end]) == np.asarray(arr).tobytes()
 
+    def test_four_byte_members_are_packed_as_words_byte_exact(self):
+        """A slab whose members are all 4-byte elements is concatenated as
+        ``uint32`` on the device (a TPU pads a minor dimension of 4 bytes
+        to 128) and read as the same bytes on the host."""
+        import jax
+        import jax.numpy as jnp
+
+        from tpusnap.batcher import DeviceBatchedBufferStager, _pack_members
+
+        arrays = {
+            "f32": jax.random.normal(jax.random.PRNGKey(0), (3, 5, 7), jnp.float32),
+            "i32": jnp.arange(-6, 7, dtype=jnp.int32),
+            "u32": jnp.asarray([0, 1, 0xFFFFFFFF, 0x01020304], jnp.uint32),
+            "scalar": jnp.asarray(7, jnp.int32),
+        }
+        assert _pack_members(tuple(arrays.values())).dtype == jnp.uint32
+        assert _pack_members((arrays["f32"], jnp.ones(3, jnp.bfloat16))).dtype == jnp.uint8
+        entries, write_reqs = self._prepare(arrays)
+        _, reqs = batch_write_requests(list(entries.values()), write_reqs)
+        assert len(reqs) == 1
+        assert isinstance(reqs[0].buffer_stager, DeviceBatchedBufferStager)
+        buf = asyncio.run(reqs[0].buffer_stager.stage_buffer())
+        mv = memoryview(buf).cast("B")
+        assert len(mv) == sum(a.nbytes for a in arrays.values())
+        for name, arr in arrays.items():
+            start, end = entries[name].byte_range
+            assert bytes(mv[start:end]) == np.asarray(arr).tobytes()
+
     def test_mixed_host_device_members_split_slabs(self):
         import jax.numpy as jnp
 

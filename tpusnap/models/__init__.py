@@ -2,13 +2,16 @@
 
 The reference library ships example *training scripts* (DDP / FSDP /
 torchrec DLRM, SURVEY.md §2 #23-24) but no model code of its own. tpusnap
-ships two model families: a flagship decoder transformer whose parameter
+ships three model families: a flagship decoder transformer whose parameter
 pytree exercises every sharding family the checkpoint preparers must
 handle — DP (replicated), FSDP (param-sharded), TP (tensor-parallel),
 SP/CP (ring attention over a sequence axis) and EP (expert-sharded MoE
-weights) — and a sharded embedding-table collection (the torchrec DMP
+weights) —, a sharded embedding-table collection (the torchrec DMP
 analog: row/col/table-wise layouts, host-offloaded tables, row-wise
-Adagrad state).
+Adagrad state), and one chip's share of a sparse-expert decoder with
+window and global attention (``smallthinker``: top-k token dispatch over
+the experts held here, one subtree a layer, so a state of many leaves of
+a few tens of MiB).
 """
 
 from .embedding import (  # noqa: F401
@@ -16,6 +19,7 @@ from .embedding import (  # noqa: F401
     TableConfig,
     make_embedding_train_step,
 )
+from .smallthinker import SmallThinker, SmallThinkerConfig  # noqa: F401
 from .transformer import (  # noqa: F401
     Transformer,
     TransformerConfig,
@@ -25,6 +29,8 @@ from .transformer import (  # noqa: F401
 
 __all__ = [
     "EmbeddingCollection",
+    "SmallThinker",
+    "SmallThinkerConfig",
     "TableConfig",
     "Transformer",
     "TransformerConfig",

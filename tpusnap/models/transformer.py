@@ -231,8 +231,10 @@ class Transformer:
             return jnp.einsum("bsf,fd->bsd", h, lp["w2"].astype(cfg.dtype))
         # MoE with dense soft routing (every token weighted over all
         # experts). The *weights* are EP-sharded; XLA inserts the gathers.
-        # Top-k token dispatch (all-to-all) is future work — the
-        # checkpoint framework only needs the expert-sharded layout.
+        # This branch exists for the expert-sharded layout alone. Top-k
+        # token dispatch over the experts a chip holds (sorted pairs,
+        # grouped products, no token dropped) is models/smallthinker.py;
+        # its all-to-all between shares is still not here.
         gates = jax.nn.softmax(
             jnp.einsum("bsd,de->bse", x, lp["router"].astype(cfg.dtype)), axis=-1
         )
@@ -300,7 +302,9 @@ def _default_mesh_shape(n: int) -> Tuple[int, int, int]:
 
 
 def make_train_step(model: Transformer, mesh: Mesh, learning_rate: float = 1e-3):
-    """Jitted SPMD train step ``(state, tokens) -> (state, loss)``.
+    """Jitted SPMD train step ``(state, tokens) -> (state, loss)``, for
+    any model with ``loss(params, tokens, mesh=...)``, ``param_specs()``
+    and ``config`` (:class:`Transformer`, :class:`~.smallthinker.SmallThinker`).
 
     ``state = {"params": ..., "opt": {"mu": ..., "nu": ..., "step": ...}}``
     (Adam; f32 moments sharded like their params). Tokens are sharded
