@@ -16,6 +16,7 @@ from tpusnap.partitioner import (
     _greedy_assign,
     consolidate_replicated_entries,
 )
+from tpusnap.telemetry import MetricsSink
 
 
 def _tensor_entry(path, nbytes=100, replicated=True, location=None):
@@ -350,3 +351,228 @@ def test_estimate_matches_prepared_entries():
                 )
             else:  # ObjectEntry: getsizeof approximation, just present
                 assert path in unit_ids
+
+
+# ------------------------------------------------- who may be a slab member
+
+_KIB, _MIB = 1 << 10, 1 << 20
+# One state around the member size (16 MiB): two leaves under it, one at
+# it, two over it and under the slab threshold (128 MiB).
+_MIXED_BYTES = {"k1": _KIB, "m15": 15 * _MIB, "m16": 16 * _MIB, "m17": 17 * _MIB, "m60": 60 * _MIB}
+_MIXED_MEMBERS = ("k1", "m15")
+_MIXED_WHOLE = ("m16", "m17", "m60")
+
+
+class _CounterSink(MetricsSink):
+    """Sums each counter's deltas; every other event is the base's no-op."""
+
+    def __init__(self):
+        self.counters = {}
+
+    def on_counter(self, name, delta, value):
+        self.counters[name] = self.counters.get(name, 0) + delta
+
+
+def _mixed_state(kind):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(29)
+    arrays = {
+        name: rng.standard_normal(nbytes // 4, dtype=np.float32)
+        for name, nbytes in _MIXED_BYTES.items()
+    }
+    return {n: jnp.asarray(a) for n, a in arrays.items()} if kind == "jax" else arrays
+
+
+def _bits(arr):
+    return np.asarray(arr).reshape(-1).view(np.uint8)
+
+
+def _batch(arrays):
+    """The batcher over one state's write requests: entries by name, the
+    requests it returns, and the counters it bumped."""
+    from tpusnap import metrics_sink
+
+    entries, write_reqs = {}, []
+    for name, arr in arrays.items():
+        entry, reqs = ArrayIOPreparer.prepare_write(f"0/{name}", arr)
+        entries[name] = entry
+        write_reqs += reqs
+    with metrics_sink(_CounterSink()) as sink:
+        _, reqs = batch_write_requests(list(entries.values()), write_reqs)
+    return entries, reqs, sink.counters
+
+
+@pytest.fixture(scope="module", params=["jax", "numpy"])
+def batched_mixed(request):
+    return request.param, _batch(_mixed_state(request.param))
+
+
+@pytest.mark.parametrize("name", list(_MIXED_BYTES))
+def test_member_size_decides_who_is_a_slab_member(batched_mixed, name):
+    """Under 16 MiB a leaf is a member (``location`` + ``byte_range`` of
+    the one slab); at 16 MiB or more it is its own request and its own
+    blob, as a leaf over the slab threshold is. Device and host leaves
+    alike: the rule comes before the choice of stager."""
+    from tpusnap.batcher import BatchedBufferStager, DeviceBatchedBufferStager
+
+    kind, (entries, reqs, _) = batched_mixed
+    slabs = [r for r in reqs if r.path.startswith("batched/")]
+    assert len(slabs) == 1 and len(reqs) == 1 + len(_MIXED_WHOLE)
+    assert isinstance(
+        slabs[0].buffer_stager,
+        DeviceBatchedBufferStager if kind == "jax" else BatchedBufferStager,
+    )
+    entry = entries[name]
+    if name in _MIXED_MEMBERS:
+        assert entry.location == slabs[0].path
+        start, end = entry.byte_range
+        assert end - start == _MIXED_BYTES[name]
+        assert slabs[0].buffer_stager.total == sum(_MIXED_BYTES[n] for n in _MIXED_MEMBERS)
+    else:
+        assert entry.location == f"0/{name}" and entry.byte_range is None
+        (own,) = [r for r in reqs if r.path == entry.location]
+        assert isinstance(own.buffer_stager, ArrayBufferStager)
+
+
+def test_whole_leaf_counters_say_what_the_member_size_kept_out(batched_mixed):
+    _, (_, _, counters) = batched_mixed
+    assert counters["batcher.whole_leaves"] == len(_MIXED_WHOLE)
+    assert counters["batcher.whole_leaf_bytes"] == sum(_MIXED_BYTES[n] for n in _MIXED_WHOLE)
+
+
+@pytest.mark.parametrize(
+    "threshold, members, whole",
+    [
+        # Below the member size the threshold bounds members as before,
+        # and nothing is "kept out by the member size".
+        (8 * _MIB, ("k1", "m4"), ()),
+        (_MIB, (), ()),
+        # At or above it the member size does: the capacity is the
+        # threshold's, the largest member is not. (At 16 MiB the 12 MiB
+        # leaf is a candidate, but with k1 and m4 it passes the capacity:
+        # flushed as a slab of one, it is left as its own request.)
+        (16 * _MIB, ("k1", "m4"), ()),
+        (32 * _MIB, ("k1", "m4", "m12"), ("m16", "m20")),
+        (128 * _MIB, ("k1", "m4", "m12"), ("m16", "m20")),
+    ],
+)
+def test_threshold_below_member_size_still_bounds_members(threshold, members, whole):
+    sizes = {"k1": _KIB, "m4": 4 * _MIB, "m12": 12 * _MIB, "m16": 16 * _MIB, "m20": 20 * _MIB}
+    arrays = {n: np.full(b, i + 1, np.uint8) for i, (n, b) in enumerate(sizes.items())}
+    with override_slab_size_threshold_bytes(threshold):
+        entries, reqs, counters = _batch(arrays)
+    got = tuple(n for n in sizes if entries[n].byte_range is not None)
+    assert got == members
+    assert len({entries[n].location for n in members}) == (1 if members else 0)
+    assert counters.get("batcher.whole_leaves", 0) == len(whole)
+    assert counters.get("batcher.whole_leaf_bytes", 0) == sum(sizes[n] for n in whole)
+    assert len(reqs) == len(sizes) - len(members) + (1 if members else 0)
+
+
+@pytest.mark.parametrize(
+    "sizes",
+    [
+        {"k1": _KIB, "m16": 16 * _MIB, "m20": 20 * _MIB},  # one candidate
+        {"m16": 16 * _MIB, "m20": 20 * _MIB},  # none
+    ],
+    ids=["one_candidate", "no_candidate"],
+)
+def test_fewer_than_two_candidates_leave_every_request_as_it_was(sizes):
+    """The early return: with under two leaves below the member size no
+    slab is made, every leaf keeps its own request and location, and the
+    counters still say which leaves the member size kept whole."""
+    arrays = {n: np.full(b, i + 1, np.uint8) for i, (n, b) in enumerate(sizes.items())}
+    entries, reqs, counters = _batch(arrays)
+    assert [r.path for r in reqs] == [f"0/{n}" for n in sizes]
+    assert all(isinstance(r.buffer_stager, ArrayBufferStager) for r in reqs)
+    assert all(e.location == f"0/{n}" and e.byte_range is None for n, e in entries.items())
+    whole = [n for n, b in sizes.items() if b >= 16 * _MIB]
+    assert counters["batcher.whole_leaves"] == len(whole)
+    assert counters["batcher.whole_leaf_bytes"] == sum(sizes[n] for n in whole)
+
+
+def _restore_bits(path, state):
+    import jax
+    import jax.numpy as jnp
+
+    from tpusnap import PytreeState, Snapshot
+
+    targets = {"m": PytreeState(jax.tree.map(jnp.zeros_like, state))}
+    Snapshot(path).restore(targets)
+    for name, want in state.items():
+        got = targets["m"].tree[name]
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.array_equal(_bits(got), _bits(want)), name
+
+
+@pytest.mark.parametrize("how", ["take", "async_take"])
+def test_mixed_state_round_trips_with_whole_leaves_beside_a_slab(tmp_path, monkeypatch, how):
+    """The five leaves through a real take: two in one slab packed on the
+    device, three as blobs of their own; every checksum recorded, ``verify``
+    clean, restored bit for bit; ``batcher.device_slab_bytes`` holds the
+    members' bytes only and ``storage.writes`` one blob a whole leaf."""
+    from tpusnap import PytreeState, Snapshot, metrics_sink, telemetry
+
+    monkeypatch.setenv("TPUSNAP_TELEMETRY", "1")
+    state = _mixed_state("jax")
+    path = str(tmp_path / "snap")
+    telemetry.reset_global_counters()
+    with metrics_sink(_CounterSink()) as sink:
+        if how == "take":
+            Snapshot.take(path, {"m": PytreeState(state)})
+        else:
+            Snapshot.async_take(path, {"m": PytreeState(state)}).wait()
+    manifest = Snapshot(path).get_manifest()
+    by_name = {n: manifest[f"0/m/{n}"] for n in state}
+    slab = {by_name[n].location for n in _MIXED_MEMBERS}
+    assert len(slab) == 1 and next(iter(slab)).startswith("batched/")
+    for name, entry in by_name.items():
+        assert entry.checksum is not None, name
+        assert (entry.byte_range is not None) == (name in _MIXED_MEMBERS)
+        if name in _MIXED_WHOLE:
+            assert not entry.location.startswith("batched/")
+            assert os.path.getsize(os.path.join(path, entry.location)) == _MIXED_BYTES[name]
+    members_bytes = sum(_MIXED_BYTES[n] for n in _MIXED_MEMBERS)
+    assert sink.counters["batcher.device_slabs"] == 1
+    assert sink.counters["batcher.device_slab_bytes"] == members_bytes
+    assert sink.counters["batcher.whole_leaves"] == len(_MIXED_WHOLE)
+    assert sink.counters["batcher.whole_leaf_bytes"] == sum(_MIXED_BYTES[n] for n in _MIXED_WHOLE)
+    assert telemetry.counter_value("batcher.device_pack_fallbacks") == 0
+    # One blob a whole leaf and one for the slab, besides the take's own
+    # small objects (which no tensor entry points at).
+    tensor_blobs = {e.location for e in by_name.values()}
+    assert len(tensor_blobs) == 1 + len(_MIXED_WHOLE)
+    assert sink.counters["storage.writes"] >= len(tensor_blobs)
+    report = Snapshot(path).verify()
+    assert report.clean and report.unverified == 0
+    _restore_bits(path, state)
+
+
+@pytest.mark.parametrize("written_under", ["old", "new"])
+def test_slab_layouts_restore_across_the_member_size(tmp_path, monkeypatch, written_under):
+    """The format did not change. A snapshot written in the old layout
+    (the member size at the slab threshold: 60 MiB members inside slabs)
+    restores bit for bit under the default, and one written under the
+    default restores under the old member size."""
+    import tpusnap.batcher as batcher
+    from tpusnap import PytreeState, Snapshot
+
+    default = batcher._MAX_SLAB_MEMBER_BYTES
+    assert default == 16 * _MIB
+    old = 128 * _MIB
+    state = _mixed_state("jax")
+    path = str(tmp_path / "snap")
+    monkeypatch.setattr(batcher, "_MAX_SLAB_MEMBER_BYTES", old if written_under == "old" else default)
+    Snapshot.take(path, {"m": PytreeState(state)})
+    manifest = Snapshot(path).get_manifest()
+    in_slabs = {n for n in state if manifest[f"0/m/{n}"].byte_range is not None}
+    if written_under == "old":
+        # 109 MiB in all, under the 128 MiB capacity: one slab holds the five.
+        assert in_slabs == set(state)
+        assert len({manifest[f"0/m/{n}"].location for n in state}) == 1
+    else:
+        assert in_slabs == set(_MIXED_MEMBERS)
+    monkeypatch.setattr(batcher, "_MAX_SLAB_MEMBER_BYTES", default if written_under == "old" else old)
+    assert Snapshot(path).verify().clean
+    _restore_bits(path, state)

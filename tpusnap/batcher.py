@@ -42,6 +42,19 @@ logger = logging.getLogger(__name__)
 # Bounds XLA compile time of the per-composition device pack program.
 _MAX_DEVICE_SLAB_MEMBERS = 256
 
+# A leaf whose staging cost reaches this is no slab member. A slab pays
+# when it replaces many objects and transfers; at the default capacity
+# members of 16 MiB or more make a slab of at most eight, which saves at
+# most seven blobs' fixed cost on the parallel I/O threads for one pack,
+# a second crossing of the bus and one hash of the whole slab on the one
+# staging thread. Such a leaf's own DMA and its own blob are already at
+# bandwidth: its prefetched host copy is the staged buffer and its hash
+# is deferred to the write path, as for a leaf over the threshold.
+# Why 16 MiB: no leaf of any benchmark cell lies between 9.2 MB
+# (attention matrices) and 60 MiB (expert banks), and 8, 16 and 32 MiB
+# read the same layout and the same time to durable on the chip (PR 29).
+_MAX_SLAB_MEMBER_BYTES = 16 * 1024 * 1024
+
 
 def _batchable_tensor_entries(entries: List[Entry]) -> Dict[str, TensorEntry]:
     """location → TensorEntry for every dense tensor blob (incl. chunks)."""
@@ -375,23 +388,30 @@ def batch_write_requests(
 ) -> Tuple[List[Entry], List[WriteReq]]:
     """Pack small array writes into slabs, rewriting entries in place
     (reference batch_write_requests, batcher.py:201-352)."""
+    # The threshold is a slab's capacity; a member is bounded by the
+    # smaller of it and the member size.
     threshold = get_slab_size_threshold_bytes()
     if is_batching_disabled():
         return entries, write_reqs
+    member_limit = min(threshold, _MAX_SLAB_MEMBER_BYTES)
 
     entry_by_location = _batchable_tensor_entries(entries)
     candidates: List[WriteReq] = []
     passthrough: List[WriteReq] = []
     for wr in write_reqs:
         stager = wr.buffer_stager
-        if (
-            isinstance(stager, ArrayBufferStager)
-            and wr.path in entry_by_location
-            and stager.get_staging_cost_bytes() < threshold
-        ):
-            candidates.append(wr)
-        else:
+        if not (isinstance(stager, ArrayBufferStager) and wr.path in entry_by_location):
             passthrough.append(wr)
+            continue
+        cost = stager.get_staging_cost_bytes()
+        if cost < member_limit:
+            candidates.append(wr)
+            continue
+        passthrough.append(wr)
+        if cost < threshold:
+            # Under a slab's capacity: kept whole by the member size alone.
+            telemetry.incr("batcher.whole_leaves")
+            telemetry.incr("batcher.whole_leaf_bytes", stager.get_planned_bytes())
     if len(candidates) < 2:
         return entries, write_reqs
 

@@ -191,6 +191,15 @@ def get_max_shard_size_bytes() -> int:
 
 
 def get_slab_size_threshold_bytes() -> int:
+    """A slab's capacity (``TPUSNAP_SLAB_SIZE_THRESHOLD_BYTES``, 128 MiB):
+    the batcher closes a slab before its members' bytes pass it. It is
+    not the largest member: a dense leaf is a slab member only while its
+    staging cost is under the smaller of this and the batcher's fixed
+    member size (16 MiB, ``batcher._MAX_SLAB_MEMBER_BYTES``). A slab pays
+    when it replaces many small objects and transfers; a leaf of 16 MiB
+    or more already fills its own DMA and its own blob, and inside a slab
+    it would cross the bus a second time and be hashed on the one staging
+    thread. Set below 16 MiB, the threshold bounds members too."""
     return _get_int_env(
         _SLAB_SIZE_THRESHOLD_ENV_VAR, _DEFAULT_SLAB_SIZE_THRESHOLD_BYTES
     )
