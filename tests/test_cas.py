@@ -737,6 +737,53 @@ def test_cli_gc_store_dry_run_default(tmp_path):
     assert not os.path.exists(os.path.join(store, blob_path(key)))
 
 
+def test_cli_store_gc_killed_mid_sweep_then_fsck_clean(tmp_path, monkeypatch):
+    """The commands an operator types, in order: two jobs share a store
+    (`fsck --store` 0), one retires, `gc --store --force` is SIGKILLed
+    at its first delete, the re-run steals the dead sweeper's lease and
+    exits 0, and both the store and the surviving job fsck clean."""
+    import shutil
+
+    from tpusnap.__main__ import main as cli_main
+
+    store = str(tmp_path / "store")
+    s = _state(11)
+    with knobs.override_cas(store):
+        Snapshot.take(str(tmp_path / "jobA"), s)
+        Snapshot.take(str(tmp_path / "jobB"), s)
+    assert len(os.listdir(os.path.join(store, BLOBS_DIR))) == _N
+    assert cli_main(["fsck", "--store", store]) == 0
+    shutil.rmtree(str(tmp_path / "jobA"))
+    for sub in (ROOTS_DIR, BLOBS_DIR):
+        for name in os.listdir(os.path.join(store, sub)):
+            _backdate(os.path.join(store, sub, name), 3600.0)
+    proc = subprocess.run(
+        [sys.executable, "-m", "tpusnap", "gc", "--store",
+         f"chaos+fs://{store}", "--force"],
+        env=dict(
+            os.environ,
+            JAX_PLATFORMS="cpu",
+            TPUSNAP_FAULT_SPEC="crash_after_op=delete:1",
+        ),
+        capture_output=True,
+        text=True,
+        timeout=120,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert proc.returncode == -signal.SIGKILL, (proc.returncode, proc.stderr)
+    # Past its TTL the dead sweeper's lease is stolen (the clock is
+    # moved forward, not slept out).
+    monkeypatch.setattr(cas, "_wall", lambda: time.time() + 120.0)
+    assert cli_main(["gc", "--store", store, "--force"]) == 0
+    assert cli_main(["fsck", "--store", store]) == 0
+    assert len(os.listdir(os.path.join(store, BLOBS_DIR))) == _N
+    assert cli_main(["fsck", str(tmp_path / "jobB")]) == 0
+    out = _zeros()
+    with knobs.override_cas(store):
+        Snapshot(str(tmp_path / "jobB")).restore(out)
+    _assert_eq(out, s)
+
+
 def test_cli_info_prints_cas_summary(tmp_path, capsys):
     from tpusnap.__main__ import main as cli_main
 

@@ -6,7 +6,7 @@ its author remembered. This module runs the same plugin + snapshot
 round trips against the real ``fake-gcs-server`` and ``minio`` SERVER
 BINARIES when they are on PATH (opt-in evidence: each suite skips
 cleanly when its binary — or its client package — is missing, so no CI
-lane ever fails for lacking them). ``scripts/ci_gate.sh`` runs the
+lane ever fails for lacking them). The CI gate script runs the
 ``cloud_real`` marker as an optional step whenever a binary is found.
 
 Server processes are spawned per module, on ephemeral ports, with

@@ -338,6 +338,31 @@ def test_stream_anchors_slo_tracker(tmp_path):
     assert st["stream_cadence_s"] is None
 
 
+def test_closed_stream_leaves_slo_check_green(tmp_path):
+    """With the RPO objective armed from the knob, a stream of three
+    micro-commits that closes cleanly leaves records `slo --check`
+    passes, and its own stats carry the widest commit interval."""
+    from tpusnap import slo
+    from tpusnap.__main__ import main
+    from tpusnap.knobs import override_slo_thresholds, override_telemetry_dir
+
+    slo.reset_tracker()
+    state = _state(9)
+    with override_telemetry_dir(str(tmp_path / "tele")), override_slo_thresholds(
+        rpo_s=3600.0
+    ):
+        s = Snapshot.stream(str(tmp_path / "stream"), state, cadence_s=3600.0)
+        for k in range(3):
+            state["app"]["w"][k, :] = float(k + 1)
+            s.commit_now()
+        s.close(final_commit=False)
+        s.raise_if_failed()
+        assert s.stats["commits"] >= 3, s.stats
+        assert s.stats["max_commit_interval_s"] is not None
+        assert main(["slo", "--check"]) == 0
+    slo.reset_tracker()
+
+
 def test_stream_multiprocess_needs_coordination(tmp_path):
     """A multi-process stream runs its elastic control plane over the
     jax.distributed coordination KV — opening one without the service

@@ -688,9 +688,9 @@ def _world_multihost_budget(snap_dir):
     """4 ranks across 2 simulated hosts: the per-host memory-budget
     divisor must see local_world_size == 2 (ranks sharing MY node), and
     the write-load partitioner must keep spreading replicated entries
-    across ALL ranks regardless of host boundaries (reference
-    benchmarks/ddp/README.md scales 1x8 -> 4x8 across nodes; spread is
-    per-rank there too)."""
+    across ALL ranks regardless of host boundaries (the reference's DDP
+    benchmark scales 1x8 -> 4x8 across nodes; spread is per-rank there
+    too)."""
     import numpy as np
 
     from tpusnap import Snapshot, StateDict

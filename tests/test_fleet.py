@@ -527,6 +527,62 @@ def test_clean_exit_stamps_final_record(tmp_path):
     assert later["jobs"][0]["rpo_s"] < 60
 
 
+_KILLED_CHILD = r"""
+import sys, time
+import numpy as np
+from tpusnap import Snapshot, StateDict
+from tpusnap.fleet import read_fleet_records
+
+dest, fdir = sys.argv[1], sys.argv[2]
+state = {"m": StateDict(w=np.arange(1 << 16, dtype=np.float32))}
+Snapshot.take(dest + "/t0", state)
+deadline = time.monotonic() + 60.0
+while not read_fleet_records(fdir) and time.monotonic() < deadline:
+    time.sleep(0.02)
+print("PUBLISHED" if read_fleet_records(fdir) else "NO-RECORD", flush=True)
+# TPUSNAP_FAULT_SPEC kills this process after the first chaos blob write.
+Snapshot.take("chaos+fs://" + dest + "/t1", state)
+print("SURVIVED", flush=True)
+"""
+
+
+def test_sigkilled_job_leaves_nonfinal_record(tmp_path):
+    """A job SIGKILLed inside a take never runs its atexit stamp: its
+    record stays non-final, so the fold keeps counting it as a writer
+    whose exposure grows, and the gate still reads it."""
+    import signal
+
+    fdir = str(tmp_path / "fleet")
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        TPUSNAP_FLEET_DIR=fdir,
+        TPUSNAP_JOB_ID="killed",
+        TPUSNAP_TELEMETRY_DIR=str(tmp_path / "tele"),
+        TPUSNAP_HISTORY="0",
+        TPUSNAP_FAULT_SPEC="transient_per_op=0,crash_after_op=write:1",
+    )
+    r = subprocess.run(
+        [sys.executable, "-c", _KILLED_CHILD, str(tmp_path / "dest"), fdir],
+        capture_output=True,
+        text=True,
+        env=env,
+        timeout=180,
+    )
+    assert r.returncode == -signal.SIGKILL, (r.returncode, r.stderr[-800:])
+    assert "PUBLISHED" in r.stdout and "SURVIVED" not in r.stdout
+    recs = read_fleet_records(fdir)
+    assert [rec["job_id"] for rec in recs] == ["killed"]
+    assert not recs[0].get("final")
+    job = fold_fleet(recs)["jobs"][0]
+    assert not job["final"] and job["state"] != "finished"
+    # An hour later the dead job's exposure has grown by that hour.
+    later = fold_fleet(recs, now=recs[0]["ts"] + 3600)
+    assert later["jobs"][0]["rpo_s"] >= 3600
+    assert main(["fleet", "--dir", fdir, "--check", "--rpo", "3600"]) == 0
+    assert main(["fleet", "--dir", fdir, "--check", "--rpo", "0.001"]) == 2
+
+
 # ------------------------------------------------------ overhead guard
 
 

@@ -410,6 +410,9 @@ def test_sigkill_mid_take_timeline_names_inflight_op(tmp_path):
     # latency as the blob writes they witness).
     assert "journal" in r0, r0
     assert verdict["missing_ranks"] == []
+    # The table a person reads carries the same post-mortem, same exit.
+    tt = _timeline(path)
+    assert tt.returncode == 4 and "POST-MORTEM" in tt.stdout
     # analyze folds the same verdict on a torn path.
     a = subprocess.run(
         [sys.executable, "-m", "tpusnap", "analyze", path],

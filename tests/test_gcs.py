@@ -38,14 +38,37 @@ class FakeGCS:
         self.sessions = {}  # sid -> {"name":, "data": bytearray, "total": int}
         self.faults = []
         self.request_log = []
-        # Injected per-request latency (seconds) — simulates cloud RTT;
-        # ThreadingHTTPServer handles each request on its own thread, so
-        # concurrent plugin requests overlap their sleeps and the
-        # benchmarks/gcs_pipeline harness can measure pipeline
-        # concurrency as sum(latency)/wall.
-        self.latency_s = 0.0
+        # Injected delay on upload requests (the cloud's round trip): each
+        # is held until a second one is in flight, for at most
+        # ``upload_hold_s``. ThreadingHTTPServer serves every request on
+        # its own thread, so a client that keeps several uploads going
+        # is released at once and a serial one shows a high-water mark
+        # of 1, whatever the machine's speed. The journal and the
+        # metadata are written alone by design and are not held.
+        self.upload_hold_s = 0.0
+        self.uploads_in_flight = 0
+        self.uploads_high_water = 0
         self._next_sid = 0
         self._lock = threading.Lock()
+        self._uploads = threading.Condition(self._lock)
+
+    def upload_enter(self, name):
+        with self._uploads:
+            self.uploads_in_flight += 1
+            if self.uploads_in_flight > self.uploads_high_water:
+                self.uploads_high_water = self.uploads_in_flight
+                self._uploads.notify_all()
+            payload = ".tpusnap/" not in name and not name.endswith(
+                ".snapshot_metadata"
+            )
+            if self.upload_hold_s and payload:
+                self._uploads.wait_for(
+                    lambda: self.uploads_high_water >= 2, self.upload_hold_s
+                )
+
+    def upload_exit(self):
+        with self._uploads:
+            self.uploads_in_flight -= 1
 
     def pop_fault(self, kind):
         with self._lock:
@@ -63,10 +86,6 @@ def _make_handler(state: FakeGCS):
             pass
 
         def _reply(self, code, headers=None, body=b""):
-            if state.latency_s:
-                import time as _time
-
-                _time.sleep(state.latency_s)
             self.send_response(code)
             for k, v in (headers or {}).items():
                 self.send_header(k, v)
@@ -82,6 +101,15 @@ def _make_handler(state: FakeGCS):
         def do_POST(self):
             state.request_log.append(("POST", self.path))
             body = self._read_body()
+            from urllib.parse import unquote
+
+            state.upload_enter(unquote(self.path.rpartition("name=")[2]))
+            try:
+                self._post(body)
+            finally:
+                state.upload_exit()
+
+        def _post(self, body):
             m = re.match(r"/upload/storage/v1/b/([^/]+)/o\?uploadType=(\w+)&name=(.*)", self.path)
             if not m:
                 return self._reply(404)
@@ -108,6 +136,14 @@ def _make_handler(state: FakeGCS):
         def do_PUT(self):
             state.request_log.append(("PUT", self.path, self.headers.get("Content-Range")))
             body = self._read_body()
+            sess = state.sessions.get(self.path.rpartition("/")[2], {})
+            state.upload_enter(sess.get("name", ""))
+            try:
+                self._put(body)
+            finally:
+                state.upload_exit()
+
+        def _put(self, body):
             m = re.match(r"/upload-session/(\w+)", self.path)
             if not m:
                 return self._reply(404)
@@ -519,31 +555,36 @@ def test_materialize_through_gcs(fake_gcs, monkeypatch):
     assert np.array_equal(target["w"], state["w"]) and target["step"] == 1
 
 
-def test_gcs_pipeline_benchmark_smoke():
-    """The benchmarks/gcs_pipeline harness (cloud-path throughput via
-    the fake server with injected latency) runs end to end, verifies
-    its restore, and reports pipeline concurrency."""
-    import os
-    import subprocess
-    import sys
+def test_take_keeps_several_uploads_in_flight(fake_gcs, monkeypatch):
+    """Against a store that answers slowly, what the framework controls
+    is how many requests it keeps going: a take of 16 x 1 MiB through
+    ``gs://`` has more than one upload in flight at once (counted by the
+    fake, which holds each upload until a second arrives), in sessions
+    of several chunks, and restores bit for bit."""
+    import numpy as np
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [
-            sys.executable,
-            os.path.join(repo, "benchmarks", "gcs_pipeline", "main.py"),
-            "--total-mb", "16",
-            "--latency-ms", "5",
-            "--upload-chunk-mb", "1",
-        ],
-        capture_output=True,
-        text=True,
-        timeout=240,
-        env=env,
-        cwd=repo,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "restore verified: True" in proc.stdout
-    assert "concurrency" in proc.stdout
+    from tpusnap import PytreeState, Snapshot
+    from tpusnap.knobs import override_batching_disabled
+
+    monkeypatch.setattr(gcs_mod, "_UPLOAD_CHUNK_SIZE", 256 << 10)
+    monkeypatch.setattr(gcs_mod, "_DOWNLOAD_CHUNK_SIZE", 256 << 10)
+    monkeypatch.setenv("STORAGE_EMULATOR_HOST", fake_gcs.endpoint)
+    fake_gcs.upload_hold_s = 20.0
+    rng = np.random.default_rng(0)
+    state = {
+        f"w{i}": rng.integers(0, 255, 1 << 20, dtype=np.uint8)
+        for i in range(16)
+    }
+    with override_batching_disabled(True):
+        Snapshot.take("gs://bkt/snaps/pipe", {"m": PytreeState(state)})
+    assert fake_gcs.uploads_high_water > 1
+    assert fake_gcs.uploads_in_flight == 0
+    chunk_puts = [
+        r for r in fake_gcs.request_log
+        if r[0] == "PUT" and r[2] and not r[2].startswith("bytes */")
+    ]
+    assert len(chunk_puts) >= 16 * 4
+    target = PytreeState({k: np.zeros_like(v) for k, v in state.items()})
+    Snapshot("gs://bkt/snaps/pipe").restore({"m": target})
+    for k, v in state.items():
+        assert np.array_equal(target.tree[k], v), k

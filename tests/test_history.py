@@ -325,12 +325,15 @@ def test_history_cli_check_rejects_kind_all(history_env, capsys):
 
 def test_history_cli_kind_filter(history_env, capsys):
     record_event(_synth(0, 1.0))
-    record_event(_synth(1, 2.5, kind="bench", roofline_fraction=0.9))
-    assert main(["history", "--kind", "bench", "--json"]) == 0
+    record_event(_synth(1, 2.5, kind="restore", restore_roofline_fraction=0.9))
+    assert main(["history", "--kind", "restore", "--json"]) == 0
     doc = json.loads(capsys.readouterr().out)
-    assert [e["kind"] for e in doc["events"]] == ["bench"]
+    assert [e["kind"] for e in doc["events"]] == ["restore"]
     assert main(["history", "--kind", "all", "--json"]) == 0
     assert len(json.loads(capsys.readouterr().out)["events"]) == 2
+    # Only the library's own events are kinds: take and restore.
+    assert main(["history", "--kind", "bench"]) == 1
+    assert "invalid choice: 'bench'" in capsys.readouterr().err
 
 
 def test_history_cli_multi_metric_check(history_env, capsys):

@@ -207,7 +207,7 @@ def test_reporter_stats_and_log_split(tmp_path, caplog, monkeypatch):
     """Reporter parity (reference scheduler.py:96-175): the final summary
     logs the staging-time vs total-time split, periodic reports carry
     per-stage pipeline counts + remaining budget, and the split is
-    published via LAST_EXECUTION_STATS for benchmarks."""
+    published via LAST_EXECUTION_STATS."""
     import logging
 
     from tpusnap import scheduler as sched

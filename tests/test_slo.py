@@ -408,8 +408,8 @@ def test_rto_estimator_uses_own_rank(slo_env):
 
 
 def test_cli_exit_contract(slo_env, tmp_path):
-    """slo --check: 0 healthy / 2 breach / 3 insufficient — unit leg of
-    the contract ci_gate.sh exercises end-to-end."""
+    """slo --check: 0 healthy / 2 breach / 3 insufficient, from a real
+    take's record."""
     from tpusnap.__main__ import main
 
     # (3) empty dir.

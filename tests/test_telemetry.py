@@ -333,7 +333,7 @@ def test_last_take_summary_exposed(tmp_path):
 
 
 def test_clean_take_records_no_fatal_payload_retries(tmp_path):
-    """Regression (BENCH_r06 stray ``retry.fatal.read: 1``): the journal
+    """Regression (a stray ``retry.fatal.read: 1``): the journal
     probe at take start 404s on every fresh path, and other
     sidecar-namespace misses are expected probes, not payload failures —
     none of them may surface as ``retry.fatal.*`` payload counters in

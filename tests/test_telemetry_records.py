@@ -187,22 +187,33 @@ def solo(tmp_path_factory):
 
 @pytest.mark.parametrize("request_span", ["stage_buffer", "storage_write", "storage_read"])
 def test_queue_plus_work_adds_up_to_the_await(solo, request_span):
-    """Per request, what the worker recorded falls short of the await span
-    only by the event loop's latency: within 5 % or 10 ms. (Outside the
-    test suite it is within 1 ms here; the suite runs under the lock-order
-    watchdog, which walks the stack at every lock, and the plug-in's first
-    write makes its directory and its executor's first thread: 4-5 ms.)"""
+    """Per request, what the worker recorded accounts for the await span
+    in order: every part lies inside the request, no two overlap, each
+    wait for a thread ends before the work it waited for starts, and
+    their sum is no more than the await. (How far the sum falls short is
+    the event loop's latency, a reading of the machine and not of the
+    code: the benchmark reports it, no test bounds it.)"""
     seen = 0
     for req in (r for r in solo if r.name == request_span):
-        parts = [r for r in solo if r.parent == req.id and r.name in HANDOFFS[request_span]]
+        parts = sorted(
+            (r for r in solo if r.parent == req.id and r.name in HANDOFFS[request_span]),
+            key=lambda r: r.start,
+        )
         if not any(r.name.endswith(".work") for r in parts):
             continue  # a small blob on the aiofiles path: no executor hand-off
         seen += 1
-        total = sum(r.duration_s for r in parts)
-        assert total <= req.duration_s + 1e-3, (req, parts)
         layout = [(r.name, r.start - req.start, r.end - req.start) for r in parts]
-        assert req.duration_s - total <= max(0.05 * req.duration_s, 10e-3), (
-            req.duration_s, layout)
+        for r in parts:
+            assert req.start <= r.start <= r.end <= req.end, (req, layout)
+        for a, b in zip(parts, parts[1:]):
+            assert a.end <= b.start, layout
+        # wait, work, wait, work: a hand-off's `.queued` is followed by its body
+        assert len(parts) % 2 == 0, layout
+        for queued, work in zip(parts[::2], parts[1::2]):
+            assert queued.name.endswith(".queued") and queued.kind == telemetry.WAIT, layout
+            assert not work.name.endswith(".queued") and work.kind == telemetry.WORK, layout
+            assert queued.thread == work.thread, layout
+        assert sum(r.duration_s for r in parts) <= req.duration_s + 1e-9, (req, layout)
     assert seen == 1
 
 

@@ -1,6 +1,6 @@
 """Memory-budgeted (tiled) reads — the reference's signature
-memory-bounded load (io_preparers/tensor.py:126-179, validated by
-benchmarks/load_tensor/main.py:24-61): ``read_object`` of an array far
+memory-bounded load (io_preparers/tensor.py:126-179, validated by its
+load_tensor benchmark): ``read_object`` of an array far
 larger than the budget must stream byte-ranged tiles, keeping peak RSS
 near the budget instead of materializing a second full copy.
 """

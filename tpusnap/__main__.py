@@ -59,8 +59,8 @@ counterpart — torchsnapshot ships no CLI and no integrity checking):
                         frame; exit 3 = no heartbeat records found)
   history               cross-run take/restore performance history from
                         this host's TPUSNAP_TELEMETRY_DIR/history.jsonl
-                        (one event per completed take/restore; bench.py
-                        records its runs too): trend table or ``--json``;
+                        (one event per completed take/restore): trend
+                        table or ``--json``;
                         ``--check`` compares the latest run against the
                         trailing median (``--window``/``--threshold``,
                         cold-run-aware; ``--metric`` repeatable — e.g.
@@ -1688,7 +1688,7 @@ def cmd_history(args) -> int:
             # refuse instead of silently coercing to one kind.
             print(
                 "error: --check needs one event kind "
-                "(--kind take|restore|bench); run one check per kind",
+                "(--kind take|restore); run one check per kind",
                 file=sys.stderr,
             )
             return 1
@@ -2380,10 +2380,8 @@ def main(argv=None) -> int:
     )
     p.add_argument(
         "--kind", default="take",
-        choices=["take", "restore", "bench", "orbax", "fleet", "all"],
-        help="event kind to show/check (default take; orbax = the "
-        "orbax_compare benchmark's median/speedup events; fleet = "
-        "fleetsim soak events)",
+        choices=["take", "restore", "all"],
+        help="event kind to show/check (default take)",
     )
     p.add_argument(
         "-n", "--limit", type=int, default=20, metavar="N",

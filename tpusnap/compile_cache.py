@@ -1,6 +1,6 @@
 """Persistent XLA compilation cache for this repo's entry points.
 
-Called by scripts (``chip_smoke.py``, ``examples/``, ``benchmarks/``,
+Called by scripts (``chip_smoke.py``, ``examples/``, ``perf/``,
 ``__graft_entry__.py``) before their first compile — never at
 ``import tpusnap``: a library must not redirect its host program's
 cache. The directory is part of every cache key's lookup, so it must

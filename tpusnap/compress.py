@@ -199,10 +199,10 @@ def _reset_ceilings() -> None:
 class CompressDecision:
     """One take's resolved compression policy, recorded in the take's
     telemetry meta (→ summary → history event) and readable after the
-    fact via ``LAST_DECISION`` (ci_gate's smoke asserts on it). The
-    ``sample_*`` fields are what the codec did to ``sample_bytes`` of
-    this take's own state; they stay 0 where no sample was taken
-    (forced modes, and every bypass decided before the sample)."""
+    fact via ``LAST_DECISION`` (tests assert on it). The ``sample_*``
+    fields are what the codec did to ``sample_bytes`` of this take's own
+    state; they stay 0 where no sample was taken (forced modes, and
+    every bypass decided before the sample)."""
 
     mode: str
     compress: bool

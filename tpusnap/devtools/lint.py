@@ -23,8 +23,8 @@ deliberate exception, not an off switch.
 
 CLI: ``python -m tpusnap lint [--json] [--check] [--root DIR]
 [--select RULES]`` — ``--check`` exits 2 on any unwaived finding, 0 on
-a clean tree; the tier-1 suite and ``scripts/ci_gate.sh`` run it over
-the whole package.
+a clean tree; the tier-1 suite and the CI gate script run it over the
+whole package.
 """
 
 from __future__ import annotations

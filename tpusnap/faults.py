@@ -21,7 +21,7 @@ Usage — no code changes needed, just the URL (and optionally a spec)::
     Snapshot.take("chaos+fs:///tmp/snap", app_state,
                   storage_options={"fault_plan": FaultPlan(seed=3,
                                                            transient_per_op=1)})
-    # or via the environment, e.g. in an example/benchmark run:
+    # or via the environment, e.g. in a run of an example:
     #   TPUSNAP_FAULT_SPEC="seed=3,transient_per_op=1,latency_ms=2"
 
 Determinism: all randomness derives from ``FaultPlan.seed``; op indices
@@ -93,17 +93,13 @@ class FaultPlan:
       across all concurrent ops, so the plugin behaves like a slow
       network pipe rather than per-op latency (which would tax
       compressed and raw bytes identically). The deterministic
-      bandwidth-bound regime the compression auto policy exists for;
-      bench.py's compression section and ci_gate's compression smoke
-      run on it.
+      bandwidth-bound regime the compression auto policy exists for.
     - ``rank``: RANK FILTER — the whole plan applies only on the
       process whose distributed rank (jax.distributed process_id, 0
       when uninitialized) matches; every other rank's plugin behaves
       fault-free. One shared ``TPUSNAP_FAULT_SPEC`` can thus
       deterministically kill or wedge exactly one rank of a
-      multi-process world (``rank=1,crash_after_op=write:2``) — the
-      rank-failure crash matrix and ci_gate's rank-failure smoke run
-      on it.
+      multi-process world (``rank=1,crash_after_op=write:2``).
     - ``wedge``: ("write", 3) → the 3rd write ATTEMPT SIGSTOPs the
       whole process (index 0/``*`` = first attempt of the kind). Unlike
       ``stall_op`` — which hangs one op while heartbeat/lease threads

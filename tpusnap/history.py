@@ -28,8 +28,7 @@ Design constraints, in order:
   surface here.
 - **Cold-run aware.** The first recorded event of each kind in a
   process is tagged ``cold: true`` (it pays imports, native-library
-  load, allocator growth — BENCH_r05's 0.206 first-run outlier in
-  ``roofline_fraction_fullscale_runs`` is exactly this shape). The
+  load, allocator growth). The
   regression check matches the cold tag like-for-like: a lone cold
   run among warm ones passes (warmup never pages an operator), while
   an all-cold history — the one-take-per-process fleet — grades cold
@@ -148,8 +147,8 @@ def event_from_summary(kind: str, summary: Dict[str, Any]) -> Dict[str, Any]:
         ev["async_blocked_s"] = round(float(summary["async_blocked_s"]), 6)
     # Fused tile compression: the take's resolved policy decision plus
     # realized ratio/codec throughput. Flat scalars so `history --check
-    # --metric compress_ratio` (or the bench's effective-GB/s metrics)
-    # trend and gate like everything else; absent on bypassed takes
+    # --metric compress_ratio` trends and gates like everything else;
+    # absent on bypassed takes
     # keeps old/new event populations comparable.
     comp = summary.get("compress")
     if isinstance(comp, dict):
@@ -169,8 +168,8 @@ def event_from_summary(kind: str, summary: Dict[str, Any]) -> Dict[str, Any]:
     # Storage-boundary latency quantiles from the run's log2 histograms
     # (merged across plugin classes, per op): *_s metrics, so `history
     # --check --metric storage_write_p99_s` (and storage_read_p99_s on
-    # restores/benches) gates tail latency upward exactly like every
-    # other duration.
+    # restores) gates tail latency upward exactly like every other
+    # duration.
     for op in ("write", "read"):
         op_lat = None
         for key, st in (summary.get("io_histograms") or {}).items():

@@ -224,14 +224,14 @@ def is_native_disabled() -> bool:
 def is_direct_io_disabled() -> bool:
     """O_DIRECT file writes (fs plugin): on by default; the native layer
     falls back to buffered writes automatically on filesystems without
-    O_DIRECT support, so this knob exists for debugging/bench A-Bs."""
+    O_DIRECT support, so this knob exists for debugging."""
     return os.environ.get(_DISABLE_DIRECT_IO_ENV_VAR, "0") == "1"
 
 
 def is_checksum_disabled() -> bool:
     """Per-blob CRC32C integrity checksums: recorded at stage time and
-    verified on read, both on by default. Disable for A/B benchmarking or
-    when reading snapshots from untrusted-layout sources only."""
+    verified on read, both on by default. Disable only when reading
+    snapshots from untrusted-layout sources."""
     return os.environ.get(_DISABLE_CHECKSUM_ENV_VAR, "0") == "1"
 
 
@@ -298,8 +298,8 @@ def is_journal_disabled() -> bool:
     (the salvage-resume evidence; one fused CRC32C+XXH64 pass per
     non-slab blob on the write path, overlapped with storage I/O on a
     worker thread). ``TPUSNAP_DISABLE_JOURNAL=1`` turns the whole layer
-    off for maximum-throughput A/B benchmarking: crashed takes then
-    classify as foreign and retakes restart from byte zero."""
+    off: crashed takes then classify as foreign and retakes restart
+    from byte zero."""
     return os.environ.get(_DISABLE_JOURNAL_ENV_VAR, "0") == "1"
 
 
@@ -1044,12 +1044,6 @@ def override_journal_disabled(disabled: bool) -> Generator[None, None, None]:
 
 
 @contextlib.contextmanager
-def override_stall_deadline_s(seconds: float) -> Generator[None, None, None]:
-    with _override_env(_STALL_DEADLINE_ENV_VAR, str(seconds)):
-        yield
-
-
-@contextlib.contextmanager
 def override_heartbeat_interval_s(seconds: float) -> Generator[None, None, None]:
     with _override_env(_HEARTBEAT_INTERVAL_ENV_VAR, str(seconds)):
         yield
@@ -1123,12 +1117,6 @@ def override_flight_enabled(enabled: bool) -> Generator[None, None, None]:
 
 
 @contextlib.contextmanager
-def override_flight_ring_size(n: int) -> Generator[None, None, None]:
-    with _override_env(_FLIGHT_RING_ENV_VAR, str(n)):
-        yield
-
-
-@contextlib.contextmanager
 def override_flight_flush_interval_s(seconds: float) -> Generator[None, None, None]:
     with _override_env(_FLIGHT_FLUSH_ENV_VAR, str(seconds)):
         yield
@@ -1151,18 +1139,6 @@ def override_slo_thresholds(
 @contextlib.contextmanager
 def override_slo_stream_cadence_x(factor: float) -> Generator[None, None, None]:
     with _override_env(_SLO_STREAM_CADENCE_X_ENV_VAR, str(factor)):
-        yield
-
-
-@contextlib.contextmanager
-def override_delta_cadence_s(seconds: float) -> Generator[None, None, None]:
-    with _override_env(_DELTA_CADENCE_ENV_VAR, str(seconds)):
-        yield
-
-
-@contextlib.contextmanager
-def override_delta_max_chain(n: int) -> Generator[None, None, None]:
-    with _override_env(_DELTA_MAX_CHAIN_ENV_VAR, str(n)):
         yield
 
 

@@ -217,8 +217,8 @@ class RetryingStoragePlugin(StoragePlugin):
             # payload failures: the journal read at every take start
             # 404s on a fresh path, and a ``retry.fatal.read`` counter
             # for it reads as a payload-blob retry gone fatal in every
-            # stage_breakdown (the BENCH_r06 stray). Label them under
-            # their own family so the payload counters stay clean.
+            # stage_breakdown. Label them under their own family so the
+            # payload counters stay clean.
             sidecar = path.startswith(SIDECAR_PREFIX)
             if transient and not sidecar:
                 # Retry-budget EXHAUSTION is its own failure mode: the
