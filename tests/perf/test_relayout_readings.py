@@ -1,0 +1,84 @@
+"""What the program says of a relayout (s:``relayout``, c:``stage.relayouts``,
+c:``stage.relayout_bytes``; PR 32) as the reducers that are here read it:
+``span_per_op`` and ``counted_bytes_per_state_byte``, on a hand-made
+observation and on what a real take gives the harness's own sink. No
+metric file names them yet: ``test_smallthinker.py`` pins the end of the
+manifest's ``per_layer`` list (PERF.md 7). CPU only."""
+
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from perf import harness  # noqa: E402
+from perf.reducers import counted_bytes_per_state_byte, counter_per_op, span_per_op  # noqa: E402
+from tpusnap import Snapshot, StateDict, metrics_sink  # noqa: E402
+from tpusnap.serialization import RELAYOUT_MIN_BYTES  # noqa: E402
+
+SPANS, COUNTERS = ["relayout"], ["stage.relayout_bytes"]
+
+
+def _handmade(leaves, saves=2, state_bytes=1000):
+    """``saves`` saves ten seconds apart; in each, every leaf is turned
+    in 0.1 s inside one ``stage.work`` of its own."""
+    ops, spans, counters = [], [], []
+    for k in range(saves):
+        t0 = 10.0 * k
+        ops.append({"t_call": t0, "t_done": t0 + 10.0})
+        for i, nbytes in enumerate(leaves):
+            start = t0 + 1 + i
+            spans += [{"name": "stage.work", "start": start - 0.2, "end": start + 0.5, "bytes": 0, "kind": "work"},
+                      {"name": "relayout", "start": start, "end": start + 0.1, "bytes": nbytes, "kind": "work"}]
+            counters += [{"name": "stage.relayouts", "t": start + 0.1, "delta": 1},
+                         {"name": "stage.relayout_bytes", "t": start + 0.1, "delta": nbytes}]
+    return {"ops": ops, "spans": spans, "counters": counters, "state_bytes": state_bytes}
+
+
+@pytest.mark.parametrize("leaves, ms, share", [
+    ((50, 50, 58), 300.0, 0.158),   # three leaves a save, as the sparse cell's head and its moments
+    ((200,), 100.0, 0.2),
+], ids=["three_leaves", "one_leaf"])
+def test_the_reducers_give_the_sum_and_the_share(leaves, ms, share):
+    obs = _handmade(leaves)
+    assert span_per_op.reduce(obs, SPANS) == pytest.approx(ms)
+    assert counted_bytes_per_state_byte.reduce(obs, COUNTERS) == pytest.approx(share)
+    assert counter_per_op.reduce(obs, ["stage.relayouts"]) == len(leaves)
+    assert span_per_op.per_op(obs, set(SPANS), "bytes") == [sum(leaves)] * 2
+
+
+def test_a_program_that_turns_nothing_gives_nothing_to_read():
+    """The parent of PR 32, and every take of a state without a strided
+    leaf: no span and no counter, so no reading, not a zero."""
+    obs = _handmade(())
+    obs["spans"].append({"name": "stage.work", "start": 1.0, "end": 2.0, "bytes": 0, "kind": "work"})
+    assert span_per_op.reduce(obs, SPANS) is None
+    assert counted_bytes_per_state_byte.reduce(obs, COUNTERS) is None
+    assert counter_per_op.reduce(obs, ["stage.relayouts"]) == 0
+
+
+@pytest.mark.parametrize("how", ["take", "async_take"])
+def test_a_real_take_read_through_the_harness_sink(tmp_path, monkeypatch, how):
+    monkeypatch.setenv("TPUSNAP_TELEMETRY", "1")
+    rng = np.random.default_rng(32)
+    turned = np.asfortranarray(rng.standard_normal((1500, 1500), dtype=np.float32))
+    plain = rng.standard_normal((1500, 1500), dtype=np.float32)
+    assert turned.nbytes >= RELAYOUT_MIN_BYTES
+    app = {"m": StateDict(turned=turned, plain=plain)}
+    log = harness.SpanLog()
+    t_call = time.monotonic()
+    with metrics_sink(log):
+        if how == "take":
+            Snapshot.take(str(tmp_path / "snap"), app)
+        else:
+            Snapshot.async_take(str(tmp_path / "snap"), app).wait()
+    obs = {"ops": [{"t_call": t_call, "t_done": time.monotonic()}], "spans": log.spans,
+           "counters": log.counters, "state_bytes": turned.nbytes + plain.nbytes}
+    assert span_per_op.reduce(obs, SPANS) > 0
+    assert span_per_op.per_op(obs, set(SPANS), "bytes", "work") == [turned.nbytes]
+    assert counted_bytes_per_state_byte.reduce(obs, COUNTERS) == pytest.approx(0.5)
+    assert counter_per_op.reduce(obs, ["stage.relayouts"]) == 1

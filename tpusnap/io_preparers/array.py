@@ -41,9 +41,11 @@ from ..io_types import (
 )
 from ..manifest import TensorEntry
 from ..serialization import (
+    RELAYOUT_MIN_BYTES,
     Serializer,
     array_as_memoryview,
     array_from_memoryview,
+    c_order_copy_into,
     dtype_to_string,
     tensor_nbytes,
 )
@@ -230,7 +232,18 @@ class ArrayBufferStager(BufferStager):
                 "dtoh.transfer", started, done - started, bytes=host.nbytes,
                 kind=telemetry.WORK,
             )
-        mv = array_as_memoryview(host)
+        # A large host array in another order than C (a device array
+        # whose minor dimension is no multiple of the tile reaches the
+        # host with its dimensions swapped; a caller's Fortran-ordered
+        # or transposed numpy leaf) is turned here, in parallel pieces
+        # into a pooled buffer, and not by np.ascontiguousarray inside
+        # array_as_memoryview. The staged bytes are that buffer's: they
+        # alias nothing live, so nothing below clones or re-verifies,
+        # and where no other buffer is staged the pool's own array is
+        # returned, for the write pipeline to hand back.
+        relaid = _relayout(host)
+        mv = memoryview(relaid) if relaid is not None else array_as_memoryview(host)
+        staged = relaid if relaid is not None else mv
         want_crc = self.entry is not None and not is_checksum_disabled()
         if self.compress_codec is not None and want_crc:
             # Fused tile compression: the staged buffer is the
@@ -239,7 +252,9 @@ class ArrayBufferStager(BufferStager):
             # clone nor the COW write-time re-verify, and dedup (when
             # armed) compares hashes of the compressed bytes. Handles
             # its own dedup/skip decision.
-            return self._stage_compressed(mv)
+            compressed = self._stage_compressed(mv)
+            _release_clone_buffer(relaid)
+            return compressed
         if want_crc and self.dedup_entry is not None:
             # Incremental dedup: hash first (the expected outcome is
             # "unchanged", where no clone and no write happen at all).
@@ -262,9 +277,12 @@ class ArrayBufferStager(BufferStager):
                     if self.dedup_entry.byte_range is not None
                     else None
                 )
+                _release_clone_buffer(relaid)
                 return SKIP_WRITE
-            clone = self.is_async_snapshot and _may_alias_live_memory(
-                self.arr, host
+            clone = (
+                relaid is None
+                and self.is_async_snapshot
+                and _may_alias_live_memory(self.arr, host)
             )
             if clone:
                 from ..knobs import is_async_cow_enabled
@@ -281,8 +299,12 @@ class ArrayBufferStager(BufferStager):
                 # checksums already recorded
                 _native.memcpy(out, mv, nthreads=get_native_copy_threads())
                 return out
-            return mv
-        if self.is_async_snapshot and _may_alias_live_memory(self.arr, host):
+            return staged
+        if (
+            relaid is None
+            and self.is_async_snapshot
+            and _may_alias_live_memory(self.arr, host)
+        ):
             # Defensive clone: training resumes before I/O completes, and a
             # donated buffer could be overwritten under us. The native
             # memcpy releases the GIL (and parallelizes) for large clones
@@ -366,7 +388,7 @@ class ArrayBufferStager(BufferStager):
             return out
         if want_crc and not self.defer_checksums:
             _record_checksums(self.entry, mv, self.record_dedup_hashes)
-        return mv
+        return staged
 
     def late_checksum(self, buf) -> None:
         """Record checksums from the STAGED buffer — called by the write
@@ -652,6 +674,32 @@ def _may_alias_live_memory(arr: ArrayLike, host: np.ndarray) -> bool:
         except Exception:
             return True
     return True
+
+
+def _relayout(host: np.ndarray) -> Optional[np.ndarray]:
+    """``host``'s bytes in C order in a buffer of the staging pool (warm
+    pages from the second take on; the write pipeline returns it after
+    the write), copied in pieces on the native copy threads' budget; or
+    None for an array staging leaves as it is: C-contiguous, or under
+    ``RELAYOUT_MIN_BYTES``. Read off the array alone."""
+    if host.flags.c_contiguous or host.nbytes < RELAYOUT_MIN_BYTES:
+        return None
+    from ..knobs import get_native_copy_threads
+
+    with telemetry.span("relayout", bytes=host.nbytes):
+        out = _acquire_clone_buffer(host.nbytes)
+        c_order_copy_into(out, host, get_native_copy_threads())
+    telemetry.incr("stage.relayouts")
+    telemetry.incr("stage.relayout_bytes", host.nbytes)
+    return out
+
+
+def _release_clone_buffer(buf: Optional[np.ndarray]) -> None:
+    """Hand a pool buffer back where staging ended with other bytes
+    than its own (a compressed blob, a dedup skip); None is ignored."""
+    from .._staging_pool import release
+
+    release(buf)
 
 
 def _acquire_clone_buffer(nbytes: int):
