@@ -655,10 +655,12 @@ def _run(manifest, cell, args, t_process_start, devices, work_dir, trap) -> int:
             peaks[device["kind"]]["bf16_flops_per_s"] * len(devices))
         say("model_flop_share_of_quiet_step", share=share, flops_per_step=flops)
 
+    failed = int(result.get("failed", 0))
+    checks.add("failed", failed, 0)
     line: Dict[str, Any] = {
-        "correct": checks.correct and not result.get("failed", 0),
+        "correct": checks.correct,
         "attempted": int(result.get("attempted", 0)),
-        "failed": int(result.get("failed", 0)),
+        "failed": failed,
         "metrics": metrics,
         "device": device,
     }
@@ -666,5 +668,10 @@ def _run(manifest, cell, args, t_process_start, devices, work_dir, trap) -> int:
         line["breakdown"] = breakdown
     if args.control:
         say("control", name=args.control, correct=line["correct"])
+    # Each number compared beside its limit: the last lines of standard
+    # error, and the last key of the result's line.
+    line["checks"] = {r["name"]: {"value": r["value"], "limit": r["limit"]} for r in checks.rows}
+    for r in checks.rows:
+        print(f"perf compared: {r['name']} {r['value']} limit {r['limit']}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0

@@ -4,13 +4,17 @@ Parameters (the traffic mix's file): ``first_save_s`` and ``save_every_s``
 (the k-th save starts at the first step boundary at or after
 ``first_save_s + k * save_every_s`` into the window, so every window
 holds the same number of saves, however fast its steps are), and for the
-traced slice ``trace_lead_s`` and ``trace_max_s``.
+traced slice ``trace_lead_s`` and ``trace_max_s``. One take at a time: a
+save still draining when the next is due is waited for, and the wait is its
+stall. A save's time to durable ends when its take does (``_watch``), not
+at the step boundary where the loop learns of it.
 """
 
 from __future__ import annotations
 
 import os
 import statistics
+import threading
 import time
 
 import jax
@@ -33,6 +37,23 @@ def _dirty_kb():
 def _take(ctx, n: int):
     path = os.path.join(ctx.work_dir, f"save_{n}")
     return path, Snapshot.async_take(path, ctx.app_state(ctx.state), **ctx.take_kwargs())
+
+
+def _watch(save, pending) -> threading.Thread:
+    """Stamps ``save`` at the moment its take is durable, from a thread that
+    does nothing but wait for it: the loop itself looks once a step, and
+    would read every save up to one step late."""
+
+    def body():
+        try:
+            pending.wait()
+        except Exception:
+            return  # the loop's own wait() meets it and counts the save failed
+        save["t_durable"] = now()
+
+    watcher = threading.Thread(target=body, name="perf-durable-watch", daemon=True)
+    watcher.start()
+    return watcher
 
 
 def setup(ctx) -> None:
@@ -72,11 +93,14 @@ def run(ctx, seconds: float):
         if t_start >= deadline and pending is None:
             break
         busy = pending is not None
+        t_begin = t_start
         if pending is not None and t_start >= next_save_at:
-            # As a trainer does: one take at a time. The wait is stall.
+            # As a trainer does: one take at a time. The wait is stall, and
+            # the stall of the save waited for: the next one begins after it.
             try:
                 pending.wait()
-                _durable(ctx, save, pending, i, now())
+                t_begin = now()
+                _durable(ctx, save, i, t_begin)
             except Exception as e:
                 ctx.say("save_failed", step=save["step"], error=repr(e))
                 save["failed"] = True
@@ -86,11 +110,12 @@ def run(ctx, seconds: float):
             if ctx.held is not None:
                 ctx.held["state"] = None  # one saved state alive beside the loop's, not two
             n_saves += 1
-            save = fresh = {"step": i, "t_begin": t_start, "state": ctx.state,
+            save = fresh = {"step": i, "t_begin": t_begin, "state": ctx.state,
                             "dirty_kb": _dirty_kb()}
             save["t_call"] = now()
             try:
                 save["path"], pending = _take(ctx, n_saves)
+                save["watcher"] = _watch(save, pending)
             except Exception as e:  # counted, never hidden
                 ctx.say("save_failed", step=i, error=repr(e))
                 save["failed"] = True
@@ -110,7 +135,7 @@ def run(ctx, seconds: float):
         if pending is not None and pending.done():
             try:
                 pending.wait()
-                _durable(ctx, save, pending, i, t_end)
+                _durable(ctx, save, i, t_end)
             except Exception as e:
                 ctx.say("save_failed", step=save["step"], error=repr(e))
                 save["failed"] = True
@@ -139,6 +164,8 @@ def run(ctx, seconds: float):
         "saves_durable": len(durable),
         "stalls_ms": stalls,
         "durable_s": durable,
+        "durable_seen_late_ms": [(sv["t_seen"] - sv["t_durable"]) * 1e3 for sv in saves
+                                 if "t_seen" in sv],
         "dirty_kb_at_save": [sv.get("dirty_kb") for sv in saves],
         "end_to_end": {
             "train_tokens_per_s": len(in_window) * tokens_per_step / seconds,
@@ -153,10 +180,12 @@ def run(ctx, seconds: float):
     }
 
 
-def _durable(ctx, save, pending, step: int, t_end: float) -> None:
-    """The take of ``save`` is durable: note when, keep what the check
-    needs of the newest one, and drop the one before it."""
-    save["t_durable"] = now() if pending.done() else t_end
+def _durable(ctx, save, step: int, t_end: float) -> None:
+    """The loop has seen that the take of ``save`` is durable (its watcher
+    has stamped when it became so): keep what the check needs of the newest
+    one, and drop the one before it."""
+    save.pop("watcher").join()
+    save["t_seen"] = now()
     save["end_step"] = step
     save["t_end"] = t_end
     if ctx.held is not None:

@@ -215,7 +215,14 @@ def test_rehearsal_runs_each_traffic_kind_end_to_end(cache_dir, kind, cell, trac
     proc = _run(cache_dir, "--workload", cell, "--seed", "2147483653", "--seconds", "1",
                 "--trace", str(trace), "--rehearsal")
     result = _result(proc)
-    assert set(result) - {"breakdown"} == RESULT_KEYS
+    assert set(result) - {"breakdown", "checks"} == RESULT_KEYS
+    # Each number compared stands beside its limit under the line's last key
+    # and on the last lines of standard error.
+    assert list(result)[-1] == "checks" and result["checks"]["failed"] == {"value": 0, "limit": 0}
+    compared = [ln for ln in proc.stderr.splitlines() if ln.startswith("perf compared: ")]
+    assert [ln.split()[2] for ln in compared] == list(result["checks"])
+    assert proc.stderr.rstrip().splitlines()[-1] == compared[-1]
+    assert all(row["value"] <= row["limit"] for row in result["checks"].values())
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] >= 1
     assert result["device"]["platform"] == "cpu"
     wanted = MANIFEST["per_layer"] if trace else MANIFEST["end_to_end"]

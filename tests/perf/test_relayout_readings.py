@@ -1,10 +1,11 @@
 """What the program says of a relayout (s:``relayout``, c:``stage.relayouts``,
-c:``stage.relayout_bytes``; PR 32) as the reducers that are here read it:
-``span_per_op`` and ``counted_bytes_per_state_byte``, on a hand-made
-observation and on what a real take gives the harness's own sink. No
-metric file names them yet: ``test_smallthinker.py`` pins the end of the
-manifest's ``per_layer`` list (PERF.md 7). CPU only."""
+c:``stage.relayout_bytes``; PR 32) as the benchmark reads it since PR 34:
+the two metric files ``relayout_ms`` and ``relayout_bytes_per_state_byte``,
+their four entries in the manifest, and the reducers they name
+(``span_per_op``, ``counted_bytes_per_state_byte``) on a hand-made
+observation and on what a real take gives the harness's own sink. CPU only."""
 
+import json
 import os
 import sys
 import time
@@ -20,7 +21,47 @@ from perf.reducers import counted_bytes_per_state_byte, counter_per_op, span_per
 from tpusnap import Snapshot, StateDict, metrics_sink  # noqa: E402
 from tpusnap.serialization import RELAYOUT_MIN_BYTES  # noqa: E402
 
-SPANS, COUNTERS = ["relayout"], ["stage.relayout_bytes"]
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    MANIFEST = json.load(_f)
+READINGS = {"relayout_ms": ("ms", "program_span"),
+            "relayout_bytes_per_state_byte": ("ratio", "program_counter")}
+ENTRIES = [m for m in MANIFEST["per_layer"] if m["name"].split(".")[0] in READINGS]
+# The span and the counter are the ones the metric files name.
+SPANS = harness.layer_metric_spec("relayout_ms")["args"]["spans"]
+COUNTERS = harness.layer_metric_spec("relayout_bytes_per_state_byte")["args"]["counters"]
+
+
+def test_the_metric_files_name_the_programs_span_and_counter():
+    assert SPANS == ["relayout"] and COUNTERS == ["stage.relayout_bytes"]
+    ms, share = (harness.layer_metric_spec(name) for name in READINGS)
+    assert (ms["reducer"], share["reducer"]) == ("span_per_op", "counted_bytes_per_state_byte")
+    assert not ms.get("count") and share["count"] is True  # a time is never printed from the CPU
+
+
+@pytest.mark.parametrize("entry", ENTRIES, ids=lambda m: m["name"])
+def test_an_entry_reads_a_cell_whose_state_has_a_leaf_to_turn(entry):
+    """One reading a cell with such a leaf: the sparse cell's head and its
+    moments, and on four chips the ``.sharded`` twin for the sharded head's.
+    The dense one-chip cells have none (50,304 is 393 x 128) and no entry."""
+    reading = entry["name"].split(".")[0]
+    assert (entry["unit"], entry["source"]) == READINGS[reading]
+    assert (entry["layer"], entry["moves"], entry["better"]) == (
+        "stage/hash", "train_tokens_per_s", "lower")
+    cells = {w["name"]: w for w in MANIFEST["workloads"]}
+    assert len(entry["workloads"]) == 1
+    chips = cells[entry["workloads"][0]]["chips"]
+    assert chips == (4 if entry["name"].endswith(".sharded") else 1)
+    assert cells[entry["workloads"][0]]["config"] != "pythia-410m"
+    spec = harness.layer_metric_spec(entry["name"])
+    assert callable(harness.load_module("reducers", spec["reducer"]).reduce) and spec["doc"]
+    # Appended: after every entry the manifest had at PR 32.
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    assert names.index(entry["name"]) > names.index("attn_share_of_step")
+
+
+def test_both_readings_have_their_one_chip_and_their_four_chip_entry():
+    assert sorted(m["name"] for m in ENTRIES) == sorted(
+        [r for r in READINGS] + [f"{r}.sharded" for r in READINGS])
 
 
 def _handmade(leaves, saves=2, state_bytes=1000):
