@@ -224,6 +224,37 @@ class Context:
         t.start()
         self.removals.append(t)
 
+    def fingerprints(self, tree):
+        """Two 32-bit sums over every leaf: of each element's bits, mixed
+        (murmur3's finalizer, one to one on 32 bits), and of the same
+        weighted by the element's place with an odd weight, so that one
+        altered element always shows and elements that changed places all
+        but always: what a restored state is compared with where the
+        state that was saved is gone, because a donating step has deleted
+        it. Eight bytes a leaf on the device, where a copy would be the
+        state again. One program a tree shape: warmed up in set-up
+        wherever the window calls it."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        def leaf(x):
+            h = _bits(x).astype(jnp.uint32)
+            h = (h ^ (h >> 16)) * jnp.uint32(0x85EBCA6B)
+            h = (h ^ (h >> 13)) * jnp.uint32(0xC2B2AE35)
+            h = h ^ (h >> 16)
+            place, stride = jnp.zeros(x.shape, jnp.uint32), 1
+            for axis in reversed(range(x.ndim)):
+                iota = lax.broadcasted_iota(jnp.uint32, x.shape, axis)
+                place = place + iota * jnp.uint32(stride % 2**32)
+                stride *= x.shape[axis]
+            return jnp.stack([jnp.sum(h, dtype=jnp.uint32),
+                              jnp.sum(h * (2 * place + 1), dtype=jnp.uint32)])
+
+        if "_fingerprints" not in self.__dict__:
+            self._fingerprints = jax.jit(lambda t: jax.tree.map(leaf, t))
+        return self._fingerprints(tree)
+
     def zeroed_targets(self):
         """A zeroed tree of the state's shapes and shardings (one program,
         built once: the window may call this)."""
@@ -282,36 +313,65 @@ def _leaf_paths(tree) -> List[str]:
     ]
 
 
+def _bits(x):
+    """A floating array's bits as unsigned integers of its width; any other
+    array as it is."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        width = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
+        return lax.bitcast_convert_type(x, width[x.dtype.itemsize])
+    return x
+
+
+def _laid_out_as(got, dtype, shape, sharding) -> bool:
+    import jax
+
+    return (isinstance(got, jax.Array) and got.dtype == dtype and got.shape == shape
+            and got.sharding.is_equivalent_to(sharding, len(shape)))
+
+
 def count_mismatches(want_tree, got_tree) -> int:
     """Elements of ``got_tree`` whose bits differ from ``want_tree``'s, plus
     one for every leaf whose type, shape or sharding differs. Compared on
     the device, leaf by leaf, so that no second copy of the state is made."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
-    def bits(x):
-        width = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
-        if jnp.issubdtype(x.dtype, jnp.floating):
-            return lax.bitcast_convert_type(x, width[x.dtype.itemsize])
-        return x
-
-    differ = jax.jit(lambda a, b: jnp.sum(bits(a) != bits(b), dtype=jnp.int32))
+    differ = jax.jit(lambda a, b: jnp.sum(_bits(a) != _bits(b), dtype=jnp.int32))
     bad = 0
     want_leaves, got_leaves = jax.tree.leaves(want_tree), jax.tree.leaves(got_tree)
     if len(want_leaves) != len(got_leaves):
         return max(len(want_leaves), len(got_leaves))
     for want, got in zip(want_leaves, got_leaves):
-        if (
-            not isinstance(got, jax.Array)
-            or got.dtype != want.dtype
-            or got.shape != want.shape
-            or not got.sharding.is_equivalent_to(want.sharding, want.ndim)
-        ):
+        if not _laid_out_as(got, want.dtype, want.shape, want.sharding):
             bad += 1
             continue
         bad += int(differ(want, got))
     return bad
+
+
+def count_fingerprint_mismatches(ctx: Context, want_prints, got_tree) -> int:
+    """``count_mismatches`` where the state to compare with is gone (a
+    donating step has deleted it) and ``Context.fingerprints`` of it, taken
+    while it lived, stand in its place: the leaves of ``got_tree`` whose
+    type, shape or sharding is not the state's, or whose fingerprints
+    differ from ``want_prints``'s."""
+    import jax
+    import numpy as np
+
+    got_leaves = jax.tree.leaves(got_tree)
+    shapes, shardings = jax.tree.leaves(ctx.state_shapes), jax.tree.leaves(ctx.state_shardings)
+    if len(got_leaves) != len(shapes):
+        return max(len(got_leaves), len(shapes))
+    bad = [not _laid_out_as(got, shape.dtype, shape.shape, sharding)
+           for got, shape, sharding in zip(got_leaves, shapes, shardings)]
+    if not any(bad):  # fingerprints of a tree of another layout would say nothing more
+        want = jax.tree.leaves(jax.device_get(want_prints))
+        got = jax.tree.leaves(jax.device_get(ctx.fingerprints(got_tree)))
+        bad = [not np.array_equal(w, g) for w, g in zip(want, got)]
+    return sum(bad)
 
 
 def seed_key(seed: int):
@@ -370,7 +430,14 @@ def program_first_steps(ctx: Context, tokens: Sequence[Any]) -> Dict[str, Any]:
     delta_norms = jax.jit(
         lambda a, b: jax.tree.map(lambda x, y: jnp.sqrt(jnp.sum(jnp.square(x - y))), a, b)
     )
-    params0 = ctx.state["params"]
+    # A step that donates deletes the state it is handed: what is read
+    # after a later step (the starting parameters, the first step's first
+    # moment) is then a copy of the harness's own, 4 bytes a parameter
+    # each, dropped with the comparison. Where nothing is donated the
+    # trees themselves stay, as they always did.
+    keep = (lambda tree: tree) if not ctx.donates else jax.jit(
+        lambda tree: jax.tree.map(jnp.copy, tree))
+    params0 = keep(ctx.state["params"])
     paths = _leaf_paths(params0)
     losses, grad_norms, first_mu = [], None, None
     for batch in tokens:
@@ -378,11 +445,12 @@ def program_first_steps(ctx: Context, tokens: Sequence[Any]) -> Dict[str, Any]:
         losses.append(float(loss))
         if grad_norms is None:
             # Adam's first moment after one step is (1 - b1) * g: the
-            # gradient as the optimizer got it. The step donates nothing,
-            # so this tree stays as it is until the comparison drops it.
+            # gradient as the optimizer got it. Kept (see above) until the
+            # comparison drops it.
             b1 = first_steps_module().ADAM["b1"]
-            first_mu = dict(zip(paths, jax.tree.leaves(ctx.state["opt"]["mu"])))
-            got = jax.device_get(norms(ctx.state["opt"]["mu"]))
+            mu = keep(ctx.state["opt"]["mu"])
+            first_mu = dict(zip(paths, jax.tree.leaves(mu)))
+            got = jax.device_get(norms(mu))
             grad_norms = {
                 p: float(v) / (1.0 - b1) for p, v in zip(paths, jax.tree.leaves(got))
             }
@@ -451,12 +519,16 @@ def find_devices(chips: int, rehearsal: bool):
 def build_program(config, devices, seed: int, **extra: Any) -> Context:
     """The system under test at the configuration's sizes, as the program
     that the configuration names builds it from the seed (mesh, state on
-    the device, the compiled train step, the shardings), and what is the
-    harness's own: the state's shapes and bytes, and the seeded token feed."""
+    the device, the compiled train step, the shardings, whether the step
+    donates its state), and what is the harness's own: the state's shapes
+    and bytes, and the seeded token feed."""
     import jax
     import numpy as np
 
     built = config_module(config, "program").build(config, devices, seed_key(seed))
+    # A program whose step deletes the state it is handed says so; the
+    # harness and the traffic kind then read no state after passing it on.
+    built["donates"] = bool(built.get("donates", False))
     state = built["state"]
     return Context(
         config=config, devices=devices, **built,
@@ -547,6 +619,14 @@ def _run(manifest, cell, args, t_process_start, devices, work_dir, trap) -> int:
         tracer=Tracer(bool(args.trace), os.path.join(work_dir, "trace")),
     )
     memory.mark("state_built")
+    if ctx.donates and not getattr(kind, "SERVES_A_DONATING_STEP", False):
+        print(
+            f"perf: the program {config['program']!r} donates its state to the step, and "
+            f"the traffic kind {traffic['kind']!r} reads a state after the step has had it "
+            "(it does not set SERVES_A_DONATING_STEP). No result is printed.",
+            file=sys.stderr,
+        )
+        return 2
     # The program's feed goes on where the first steps' batches end.
     for _ in tokens:
         ctx.next_tokens()

@@ -8,6 +8,17 @@ traced slice ``trace_lead_s`` and ``trace_max_s``. One take at a time: a
 save still draining when the next is due is waited for, and the wait is its
 stall. A save's time to durable ends when its take does (``_watch``), not
 at the step boundary where the loop learns of it.
+
+Under a program whose step donates its state (``ctx.donates``) the loop
+does what such a trainer must: ``pending.wait_staged()`` between
+``async_take`` and the next step, because that step deletes the arrays the
+take was handed. The wait lies inside that step's time, so inside the
+save's stall and the rate; its length is ``staged_wait_ms``. No state is
+read after ``train_step`` has had it: what the check compares the restored
+state with are fingerprints of every leaf's bits, taken on the device
+before the take (a device copy would be the state a second time, and by
+the compiler's sizes the step of the one configuration that donates leaves
+no room for it).
 """
 
 from __future__ import annotations
@@ -23,6 +34,9 @@ from tpusnap import Snapshot
 
 now = time.monotonic
 
+# The harness refuses a donating program under a kind that does not say this.
+SERVES_A_DONATING_STEP = True
+
 
 def _dirty_kb():
     """Page-cache bytes not yet written back when a save starts: a backlog
@@ -37,6 +51,29 @@ def _dirty_kb():
 def _take(ctx, n: int):
     path = os.path.join(ctx.work_dir, f"save_{n}")
     return path, Snapshot.async_take(path, ctx.app_state(ctx.state), **ctx.take_kwargs())
+
+
+def _donates(ctx) -> bool:
+    return getattr(ctx, "donates", False)
+
+
+def _kept_for_the_check(ctx):
+    """What ``restored_bits_differ`` compares with: the state handed to the
+    take, which stays alive where nothing is donated; the fingerprints of
+    its leaves where the next step deletes it (waited for, so that the
+    take's own clock starts on an idle device: their pass over the state
+    lies inside the save's stall and outside its blocked window)."""
+    if _donates(ctx):
+        return jax.block_until_ready(ctx.fingerprints(ctx.state))
+    return ctx.state
+
+
+def _wait_staged(pending) -> float:
+    """Seconds a donating trainer waits before the step that deletes what
+    the take was handed."""
+    t = now()
+    pending.wait_staged()
+    return now() - t
 
 
 def _watch(save, pending) -> threading.Thread:
@@ -58,8 +95,13 @@ def _watch(save, pending) -> threading.Thread:
 
 def setup(ctx) -> None:
     """One whole take under two steps, so that the slab-pack programs and
-    the native library are built before the window."""
+    the native library are built before the window; under a donating
+    step the fingerprints' program too."""
+    if _donates(ctx):
+        _kept_for_the_check(ctx)
     path, pending = _take(ctx, 0)
+    if _donates(ctx):
+        _wait_staged(pending)
     for _ in range(2):
         ctx.state, loss = ctx.train_step(ctx.state, ctx.put_tokens(ctx.next_tokens()))
         jax.block_until_ready(loss)
@@ -110,12 +152,14 @@ def run(ctx, seconds: float):
             if ctx.held is not None:
                 ctx.held["state"] = None  # one saved state alive beside the loop's, not two
             n_saves += 1
-            save = fresh = {"step": i, "t_begin": t_begin, "state": ctx.state,
+            save = fresh = {"step": i, "t_begin": t_begin, "state": _kept_for_the_check(ctx),
                             "dirty_kb": _dirty_kb()}
             save["t_call"] = now()
             try:
                 save["path"], pending = _take(ctx, n_saves)
                 save["watcher"] = _watch(save, pending)
+                if _donates(ctx):
+                    save["staged_wait_s"] = _wait_staged(pending)
             except Exception as e:  # counted, never hidden
                 ctx.say("save_failed", step=i, error=repr(e))
                 save["failed"] = True
@@ -153,6 +197,7 @@ def run(ctx, seconds: float):
             n = sv["end_step"] - sv["step"]
             stalls.append((sv["t_end"] - sv["t_begin"]) * 1e3 - n * quiet_ms)
     failed = sum(1 for sv in saves if sv.get("failed") or "t_durable" not in sv)
+    staged = [sv["staged_wait_s"] * 1e3 for sv in saves if "staged_wait_s" in sv]
     ops = [
         {"kind": "save", "t_call": sv["t_begin"], "t_done": sv.get("t_end", now())}
         for sv in saves
@@ -167,10 +212,12 @@ def run(ctx, seconds: float):
         "durable_seen_late_ms": [(sv["t_seen"] - sv["t_durable"]) * 1e3 for sv in saves
                                  if "t_seen" in sv],
         "dirty_kb_at_save": [sv.get("dirty_kb") for sv in saves],
+        **({"staged_wait_ms": staged} if staged else {}),
         "end_to_end": {
             "train_tokens_per_s": len(in_window) * tokens_per_step / seconds,
             "save_stall_ms": statistics.median(stalls) if stalls else None,
             "save_durable_s": statistics.median(durable) if durable else None,
+            **({"staged_wait_ms": statistics.median(staged)} if staged else {}),
         },
         "series": {
             "step_ms": [(e - s) * 1e3 for s, e, _ in in_window],
@@ -209,7 +256,11 @@ def check(ctx, result) -> None:
     targets = ctx.app_state(ctx.zeroed_targets())
     Snapshot(held["path"]).restore(targets)
     restored = targets["train"].tree
-    ctx.checks.add("restored_bits_differ", harness.count_mismatches(held["state"], restored), 0)
+    if _donates(ctx):  # leaves whose fingerprints differ; else elements whose bits do
+        differ = harness.count_fingerprint_mismatches(ctx, held["state"], restored)
+    else:
+        differ = harness.count_mismatches(held["state"], restored)
+    ctx.checks.add("restored_bits_differ", differ, 0)
     report = Snapshot(held["path"]).verify()
     if not report.clean:
         ctx.say("verify", summary=report.summary())

@@ -79,7 +79,7 @@ def test_the_manifest_has_the_configuration_and_its_one_cell():
     new = {m["name"]: m for m in MANIFEST["per_layer"] if m["name"] in NEW_METRICS}
     assert sorted(new) == sorted(NEW_METRICS)
     for name in NEW_METRICS[:3]:
-        assert new[name]["workloads"] == [OLD_CELL, CELL["name"]]
+        assert new[name]["workloads"] == [OLD_CELL, CELL["name"], "pythia-410m-24l.save-loop-donated"]
     for name in NEW_METRICS[3:]:
         assert new[name]["workloads"] == [CELL["name"]] and new[name]["layer"] == "train step"
     # Entries are appended: every one of the five comes after the last of PR 26's,
