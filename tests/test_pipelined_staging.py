@@ -356,6 +356,281 @@ def test_warm_pool_reuse_across_windows(tmp_path):
     _restore_and_check(path, state)
 
 
+# ------------------------------------- who counts towards the window
+
+
+class _Gate:
+    """Holds every device leaf's (and device slab's) staging until
+    ``open()``: whatever returns while it is shut waited for none."""
+
+    def __init__(self, monkeypatch):
+        import threading
+
+        from tpusnap.batcher import DeviceBatchedBufferStager
+        from tpusnap.io_preparers.array import ArrayBufferStager
+
+        self.event = threading.Event()
+        self.entered = []
+        gate = self
+
+        def gated(cls, name_of):
+            orig = cls._stage_blocking
+
+            def _stage_blocking(stager):
+                if not isinstance(getattr(stager, "arr", None), np.ndarray):
+                    gate.entered.append(name_of(stager))
+                    assert gate.event.wait(30), "gate never opened"
+                return orig(stager)
+
+            monkeypatch.setattr(cls, "_stage_blocking", _stage_blocking)
+
+        gated(ArrayBufferStager, lambda s: s.entry.location)
+        gated(DeviceBatchedBufferStager, lambda s: "slab")
+
+    def open(self):
+        self.event.set()
+
+
+@pytest.fixture
+def device_leaves(monkeypatch):
+    """CPU-backend arrays that answer as an accelerator's do: np.asarray
+    of them is said not to alias the device buffer."""
+    import tpusnap.io_preparers.array as array_mod
+
+    monkeypatch.setattr(
+        array_mod, "_asarray_aliases_device_buffer", lambda device: False
+    )
+
+
+def _device_state(n=4, per=_PER, seed=11, sharded=False):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    state = {k: jnp.asarray(v) for k, v in _state(n, per, seed).items()}
+    if sharded:  # each leaf cut in four shards along its one axis
+        mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
+        rows = NamedSharding(mesh, PartitionSpec("x"))
+        state = {k: jax.device_put(v, rows) for k, v in state.items()}
+    return state
+
+
+def _counters():
+    return tele_mod.LAST_TAKE_SUMMARY["counters"]
+
+
+@pytest.mark.parametrize(
+    "window,batching,sharded",
+    [
+        (_PER, False, False),
+        (1 << 30, False, False),
+        (1 << 30, True, False),
+        (_PER, False, True),
+    ],
+    ids=["over_window", "under_window", "device_slab", "shards"],
+)
+def test_device_leaves_are_staged_behind_the_return(
+    tmp_path, monkeypatch, device_leaves, window, batching, sharded
+):
+    """(a) A state of device leaves, larger or smaller than the window,
+    whole, packed into a slab or cut into shards: async_take returns
+    with nothing staged and nothing held, and the snapshot is the state
+    bit for bit."""
+    state = _device_state(sharded=sharded)
+    want = {k: np.asarray(v).copy() for k, v in state.items()}
+    gate = _Gate(monkeypatch)
+    path = str(tmp_path / "snap")
+    with override_batching_disabled(not batching), override_async_stage_window_bytes(
+        window
+    ):
+        pending = Snapshot.async_take(path, {"m": PytreeState(state)})
+        # Back although no leaf could be staged: the gate is shut.
+        assert not pending._pending_io_work.staging_complete()
+        assert not pending.staged()
+        gate.open()
+        assert pending.wait_staged(timeout=30)
+        pending.wait()
+    assert gate.entered  # the drain staged them, through the gate
+    assert _counters().get("scheduler.window_held_bytes", 0) == 0
+    assert _counters()["scheduler.window_released_bytes"] == 4 * _PER
+    assert _counters()["scheduler.bytes_staged"] == 4 * _PER
+    _restore_and_check(path, want)
+
+
+def test_numpy_leaves_hold_the_window_device_leaves_do_not(
+    tmp_path, monkeypatch, device_leaves
+):
+    """(b) numpy leaves beside device leaves: the numpy ones are cloned
+    before the return (an in-place write right after it does not reach
+    the snapshot), the device ones are staged behind it."""
+    host = _state(n=3, seed=5)
+    device = {f"d{i}": v for i, v in enumerate(_device_state(n=3).values())}
+    want = {k: np.asarray(v).copy() for k, v in {**host, **device}.items()}
+    gate = _Gate(monkeypatch)
+    path = str(tmp_path / "snap")
+    # The clone contract (COW's is wait_staged(), see the COW section).
+    with override_batching_disabled(True), override_async_cow(False):
+        pending = Snapshot.async_take(path, {"m": PytreeState({**host, **device})})
+        assert not pending._pending_io_work.staging_complete()
+        for v in host.values():
+            v.view(np.uint8)[:] = 0xAB
+        gate.open()
+        pending.wait()
+    assert len(gate.entered) == 3
+    # Staging cost, which in clone mode is twice a leaf's bytes.
+    assert _counters()["scheduler.window_held_bytes"] == 3 * 2 * _PER
+    assert _counters()["scheduler.window_released_bytes"] == 3 * 2 * _PER
+    _restore_and_check(path, want)
+
+
+@pytest.mark.parametrize("mode", ["window_0", "incremental"])
+def test_strict_modes_stage_device_leaves_before_the_return(
+    tmp_path, device_leaves, mode
+):
+    """(c) Window 0 and an incremental take are not pipelined: every
+    leaf, device leaves too, is staged when async_take returns."""
+    state = _device_state()
+    want = {k: np.asarray(v).copy() for k, v in state.items()}
+    path = str(tmp_path / "snap")
+    kwargs = {}
+    if mode == "incremental":
+        kwargs["incremental_from"] = str(tmp_path / "base")
+        Snapshot.take(
+            kwargs["incremental_from"],
+            {"m": PytreeState(state)},
+            _record_dedup_hashes=True,
+        )
+    with override_batching_disabled(True), override_async_stage_window_bytes(
+        0 if mode == "window_0" else 1 << 30
+    ):
+        pending = Snapshot.async_take(path, {"m": PytreeState(state)}, **kwargs)
+        assert pending._pending_io_work.staging_complete()
+        pending.wait()
+    assert _counters()["scheduler.window_held_bytes"] > 0
+    assert _counters().get("scheduler.window_released_bytes", 0) == 0
+    _restore_and_check(path, want)
+
+
+class _NamedStager(BufferStager):
+    """Stages at once and says where it was staged from; ``aliases`` is
+    the answer it gives the window."""
+
+    staged: list = []
+
+    def __init__(self, name, aliases):
+        self.name = name
+        self.aliases = aliases
+        self.data = os.urandom(500)
+
+    async def stage_buffer(self, executor=None):
+        _NamedStager.staged.append(self.name)
+        return self.data
+
+    def get_staging_cost_bytes(self) -> int:
+        return len(self.data)
+
+    def aliases_caller_memory(self) -> bool:
+        return self.aliases
+
+
+class _DuckStager:
+    """No BufferStager and no ``aliases_caller_memory``: a plugin's
+    stager from before the method existed."""
+
+    def __init__(self, name):
+        self.name = name
+        self.data = os.urandom(500)
+
+    async def stage_buffer(self, executor=None):
+        _NamedStager.staged.append(self.name)
+        return self.data
+
+    def get_staging_cost_bytes(self) -> int:
+        return len(self.data)
+
+
+@pytest.mark.parametrize(
+    "held,eager",
+    [
+        ([], ["e0", "e1"]),
+        (["h0", "h1"], []),
+        (["h0"], ["e0"]),
+        (["duck0", "duck1"], []),
+        ([], []),
+    ],
+    ids=["eager_set", "aliasing", "both", "no_method", "nothing_held"],
+)
+def test_window_holds_only_eager_and_aliasing_requests(tmp_path, held, eager):
+    """(d), (f) Of a pipelined take's requests the return waits for the
+    stage_eagerly set and for those that alias caller memory (a stager
+    without the method among them), whatever the window's size; every
+    other request is staged by the drain."""
+    _NamedStager.staged = []
+    released = [f"r{i}" for i in range(4)]
+
+    def make(name):
+        if name.startswith("duck"):
+            return _DuckStager(name)
+        return _NamedStager(name, aliases=name.startswith("h"))
+
+    write_reqs = [
+        WriteReq(path=name, buffer_stager=make(name))
+        for name in released[:2] + eager + held + released[2:]
+    ]
+
+    async def go():
+        pending = await execute_write_reqs(
+            write_reqs,
+            FSStoragePlugin(root=str(tmp_path)),
+            memory_budget_bytes=1 << 30,
+            rank=0,
+            pipelined_staging=True,
+            stage_eagerly=lambda wr: wr.path.startswith("e"),
+        )
+        at_return = set(_NamedStager.staged)
+        assert set(eager + held) <= at_return, at_return
+        # One request may have been dispatched beside them; never all.
+        assert not set(released) <= at_return, at_return
+        assert not pending.staging_complete()
+        await pending.complete()
+        assert pending.staging_complete()
+
+    with override_async_stage_window_bytes(1 << 20):
+        asyncio.run(go())
+    assert sorted(_NamedStager.staged) == sorted(eager + held + released)
+    for name in eager + held + released:
+        assert (tmp_path / name).exists()
+
+
+@pytest.mark.parametrize("rendezvous", [False, True], ids=["donated", "wait_staged"])
+def test_leaf_deleted_before_it_is_staged_fails_by_name(
+    tmp_path, monkeypatch, device_leaves, rendezvous
+):
+    """(e) A device leaf that a step donates (deletes) before the drain
+    has staged it fails the take with the leaf's name and the way out,
+    and commits nothing; after wait_staged() the same delete is safe."""
+    state = _device_state()
+    want = {k: np.asarray(v).copy() for k, v in state.items()}
+    gate = _Gate(monkeypatch)
+    path = str(tmp_path / "snap")
+    with override_batching_disabled(True), override_journal_disabled(True):
+        pending = Snapshot.async_take(path, {"m": PytreeState(state)})
+        if rendezvous:
+            gate.open()
+            assert pending.wait_staged(timeout=30)
+        state["w2"].delete()  # what donating it to a jitted step does
+        gate.open()
+        if rendezvous:
+            pending.wait()
+        else:
+            with pytest.raises(RuntimeError, match=r"w2.*wait_staged\(\)"):
+                pending.wait()
+    if rendezvous:
+        _restore_and_check(path, want)
+    else:
+        assert not os.path.exists(os.path.join(path, ".snapshot_metadata"))
+
+
 # ------------------------------------------------------------ COW mode
 
 

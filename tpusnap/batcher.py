@@ -28,8 +28,9 @@ from .io_types import (
     BufferType,
     ReadReq,
     WriteReq,
+    stager_aliases_caller_memory,
 )
-from .io_preparers.array import ArrayBufferStager
+from .io_preparers.array import ArrayBufferStager, DonatedBeforeStagedError
 from .knobs import (
     get_slab_size_threshold_bytes,
     is_batching_disabled,
@@ -66,6 +67,13 @@ def _batchable_tensor_entries(entries: List[Entry]) -> Dict[str, TensorEntry]:
             for chunk in entry.chunks:
                 out[chunk.tensor.location] = chunk.tensor
     return out
+
+
+def _any_member_aliases(members) -> bool:
+    """A slab counts towards an async take's blocked window when any
+    member's bytes may be written in place by the caller (see
+    ``BufferStager.aliases_caller_memory``)."""
+    return any(stager_aliases_caller_memory(s) for _, _, s in members)
 
 
 class BatchedBufferStager(BufferStager):
@@ -154,6 +162,9 @@ class BatchedBufferStager(BufferStager):
             return SKIP_WRITE
         return slab[:new_offset]
 
+    def aliases_caller_memory(self) -> bool:
+        return _any_member_aliases(self.members)
+
     def get_staging_cost_bytes(self) -> int:
         # The slab plus transiently one member's own staging cost; the
         # members' buffers are views/DMA targets released as they land.
@@ -202,6 +213,8 @@ class DeviceBatchedBufferStager(BufferStager):
                     executor, telemetry.handoff("stage", self._stage_blocking)
                 )
             return self._stage_blocking()
+        except DonatedBeforeStagedError:
+            raise  # the host path would only find the same array deleted
         except Exception as e:
             # Counted as well as logged, so a run that expects the
             # device path (chip_smoke.py) can assert it saw none.
@@ -219,6 +232,8 @@ class DeviceBatchedBufferStager(BufferStager):
         attrs = {"bytes": self.total, "slab_members": len(self.members)}
         # The pack alone: the program's dispatch until the packed buffer
         # is ready on the device, before any byte of it is fetched.
+        for _, _, s in self.members:
+            s.raise_if_donated()
         with telemetry.span("slab.pack", **attrs):
             packed = _pack_on_device(tuple(s.arr for _, _, s in self.members))
             packed.block_until_ready()
@@ -285,6 +300,11 @@ class DeviceBatchedBufferStager(BufferStager):
                 stager.entry.byte_range = [new_offset, new_offset + nbytes]
             new_offset += nbytes
         return out
+
+    def aliases_caller_memory(self) -> bool:
+        # The packed slab aliases nothing, but the host fallback stages
+        # the members themselves: the slab answers as they do.
+        return _any_member_aliases(self.members)
 
     def get_staging_cost_bytes(self) -> int:
         # Partial dedup holds the DMA'd slab AND the compacted copy at

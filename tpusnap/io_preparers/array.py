@@ -163,6 +163,12 @@ class ArrayBufferStager(BufferStager):
         # applied to the ORIGINAL array at stage time with tracing=False
         # (reference io_preparers/tensor.py:231-241).
         self.array_prepare_func = array_prepare_func
+        # May the caller write this leaf's bytes in place once
+        # async_take has returned? Asked once, of the array alone; a
+        # sync take has no such moment and is not asked.
+        self._aliases_caller_memory = (
+            not is_async_snapshot or _may_alias_live_memory(arr, None)
+        )
         # When the prefetch of this leaf's host copy was started, if it was.
         self.dtoh_started: Optional[float] = None
         if array_prepare_func is None:
@@ -182,6 +188,21 @@ class ArrayBufferStager(BufferStager):
             return False
         return isinstance(self.arr, np.ndarray) or self.dtoh_started is not None
 
+    def aliases_caller_memory(self) -> bool:
+        return self._aliases_caller_memory
+
+    def raise_if_donated(self) -> None:
+        """Fail the take if a step has donated (and so deleted) the
+        source array before it was staged, by the leaf's name and with
+        the way out, where JAX would say "Array has been deleted"."""
+        if isinstance(self.arr, jax.Array) and self.arr.is_deleted():
+            leaf = self.entry.location if self.entry is not None else "a leaf"
+            raise DonatedBeforeStagedError(
+                f"{leaf}: a step donated this array before it was staged: "
+                "call PendingSnapshot.wait_staged() before a step that "
+                "donates, or set TPUSNAP_ASYNC_STAGE_WINDOW_BYTES=0"
+            )
+
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         loop = asyncio.get_running_loop()
         if executor is not None:
@@ -194,6 +215,7 @@ class ArrayBufferStager(BufferStager):
         from ..knobs import is_checksum_disabled
 
         arr = self.arr
+        self.raise_if_donated()
         if self.array_prepare_func is not None:
             arr = self.array_prepare_func(arr, False)  # tracing=False
             if self.entry is not None and (
@@ -213,7 +235,11 @@ class ArrayBufferStager(BufferStager):
             if rec is not None and rec.enabled and not isinstance(arr, np.ndarray)
             else None
         )
-        host = np.asarray(arr)  # DtoH (no-op if DMA already done)
+        try:
+            host = np.asarray(arr)  # DtoH (no-op if DMA already done)
+        except RuntimeError:
+            self.raise_if_donated()  # deleted under the call
+            raise
         if dtoh_t0 is not None:
             # `dtoh` is this call alone. For a prefetched leaf that is
             # the residual wait for a copy started at prepare time, not
@@ -282,7 +308,7 @@ class ArrayBufferStager(BufferStager):
             clone = (
                 relaid is None
                 and self.is_async_snapshot
-                and _may_alias_live_memory(self.arr, host)
+                and self._aliases_caller_memory
             )
             if clone:
                 from ..knobs import is_async_cow_enabled
@@ -303,7 +329,7 @@ class ArrayBufferStager(BufferStager):
         if (
             relaid is None
             and self.is_async_snapshot
-            and _may_alias_live_memory(self.arr, host)
+            and self._aliases_caller_memory
         ):
             # Defensive clone: training resumes before I/O completes, and a
             # donated buffer could be overwritten under us. The native
@@ -674,6 +700,10 @@ def _may_alias_live_memory(arr: ArrayLike, host: np.ndarray) -> bool:
         except Exception:
             return True
     return True
+
+
+class DonatedBeforeStagedError(RuntimeError):
+    """A take's source array was deleted before it was staged."""
 
 
 def _relayout(host: np.ndarray) -> Optional[np.ndarray]:

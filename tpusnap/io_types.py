@@ -139,6 +139,24 @@ class BufferStager(abc.ABC):
         this so heartbeat percentages can reach 100."""
         return self.get_staging_cost_bytes()
 
+    def aliases_caller_memory(self) -> bool:
+        """Whether the bytes this request stages may live in memory the
+        caller can write IN PLACE once ``async_take`` has returned (a
+        numpy leaf, a ``pinned_host`` or CPU-backend array). Such a
+        request counts towards the blocked window of a pipelined async
+        take; one that answers False (an accelerator-resident array,
+        held by reference: it can only be donated, which deletes it and
+        fails the take loudly) is staged by the background drain. The
+        default is the safe answer: it counts."""
+        return True
+
+
+def stager_aliases_caller_memory(stager: BufferStager) -> bool:
+    """``stager.aliases_caller_memory()``; a stager that is no
+    ``BufferStager`` and has no such method counts as aliasing."""
+    ask = getattr(stager, "aliases_caller_memory", None)
+    return True if ask is None else bool(ask())
+
 
 @dataclass
 class WriteReq:

@@ -459,14 +459,18 @@ def get_stage_threads() -> int:
 def get_async_stage_window_bytes() -> Optional[int]:
     """Staging window of a pipelined async take (see
     :mod:`tpusnap.scheduler`): ``async_take`` returns control once the
-    first window of write requests is staged, and the background drain
-    stages subsequent windows interleaved with storage I/O under this
-    in-flight bound — blocked time and clone RSS are O(window) instead
-    of O(state). ``0`` disables pipelining: ``async_take`` then stages
-    the WHOLE state before returning (the pre-pipeline strict
-    semantics, for callers that mutate host-aliasing state in place
-    immediately after control returns instead of using
-    ``PendingSnapshot.wait_staged()``)."""
+    first window of the write requests that count towards it is
+    staged — those whose bytes the caller could write in place (numpy,
+    ``pinned_host`` and CPU-backend arrays, objects, any stager that
+    does not answer otherwise); an accelerator-resident leaf is held
+    by reference and never counts. The background drain stages the
+    rest interleaved with storage I/O under this in-flight bound —
+    blocked time and clone RSS are O(window) instead of O(state).
+    ``0`` disables pipelining: ``async_take`` then stages the WHOLE
+    state, device leaves too, before returning (the pre-pipeline strict
+    semantics, for callers that mutate host-aliasing state in place or
+    donate device state immediately after control returns instead of
+    using ``PendingSnapshot.wait_staged()``)."""
     val = _get_int_env(
         _ASYNC_STAGE_WINDOW_ENV_VAR, _DEFAULT_ASYNC_STAGE_WINDOW_BYTES
     )

@@ -57,6 +57,7 @@ from .io_types import (
     WriteIO,
     WriteReq,
     run_on_loop,
+    stager_aliases_caller_memory,
 )
 from .knobs import get_memory_budget_override_bytes
 
@@ -447,6 +448,9 @@ class _WritePipeline:
         # True when the stager reported the content is already persisted
         # (incremental dedup): the request completes with no storage I/O.
         self.skipped = False
+        # Whether a pipelined async take must stage this request before
+        # it returns (_WriteScheduler decides; every other mode: all).
+        self.in_window = True
 
     async def stage(self, executor: ThreadPoolExecutor) -> "_WritePipeline":
         from .io_types import SKIP_WRITE
@@ -577,15 +581,21 @@ class _WriteScheduler:
     - ``pipelined_staging`` (async takes): the in-flight staging budget
       is clamped to TPUSNAP_ASYNC_STAGE_WINDOW_BYTES and the blocked
       window ends at FIRST-WINDOW-STAGED — the engine has staged one
-      window's worth of requests and proven the pipeline flows; the
+      window's worth of the requests that count towards it; the
       drain then clones window N+1 while window N's writes release
       buffers (and budget) back, so blocked time and clone RSS are both
-      O(window) instead of O(state). ``stage_eagerly`` selects requests
-      that must still stage INSIDE the blocked window (multi-process
-      takes: stagers that annotate manifest entries at stage time, whose
-      values would otherwise miss the by-value manifest gather). The I/O
-      gate stays shut during the blocked window exactly as in
-      prioritize mode, and opens permanently once control returns.
+      O(window) instead of O(state). A request counts when its bytes
+      may alias memory the caller can write in place once control is
+      back (``BufferStager.aliases_caller_memory``; the window exists
+      for that caller), or when ``stage_eagerly`` selects it
+      (multi-process takes: stagers that annotate manifest entries at
+      stage time, whose values would otherwise miss the by-value
+      manifest gather). Every other request — an accelerator-resident
+      array, held by reference — is queued behind those for the drain;
+      with none that counts the window dispatches what the budget
+      admits and closes at once. The I/O gate stays shut during the
+      blocked window exactly as in prioritize mode, and opens
+      permanently once control returns.
     """
 
     def __init__(
@@ -640,32 +650,48 @@ class _WriteScheduler:
             _WritePipeline(wr, storage, self.executor, self.hash_executor, tele)
             for wr in write_reqs
         ]
-        cost_key = lambda p: p.staging_cost  # noqa: E731
-        if self.pipelined and stage_eagerly is not None:
-            # Eager requests lead the queue: they must be staged before
-            # the blocked window may close. Within each group, large
-            # first — they occupy budget longest and their I/O overlaps
-            # the staging of everything behind them.
-            eager = sorted(
-                (p for p in pls if stage_eagerly(p.write_req)),
-                key=cost_key,
-                reverse=True,
-            )
-            rest = sorted(
-                (p for p in pls if not stage_eagerly(p.write_req)),
-                key=cost_key,
-                reverse=True,
-            )
-            self.pipelines = deque(eager + rest)
-            # Identity set, not a count: with TPUSNAP_STAGE_THREADS >= 2
-            # an interleaved NON-eager stager can complete first, and a
-            # bare countdown would let the blocked window close while an
-            # eager (manifest-annotating) stager is still in flight.
-            self.eager_pending = {id(p) for p in eager}
-        else:
-            self.pipelines = deque(sorted(pls, key=cost_key, reverse=True))
-            self.eager_pending = set()
+        # Who must be staged before a pipelined async take returns: the
+        # eager set, then every request whose bytes the caller could
+        # write in place afterwards. The rest (accelerator-resident
+        # arrays, held by reference) is queued behind them for the drain.
+        # Within each group, large first — they occupy budget longest
+        # and their I/O overlaps the staging of everything behind them.
+        eager: List[_WritePipeline] = []
+        held: List[_WritePipeline] = []
+        released: List[_WritePipeline] = []
+        for p in pls:
+            if not self.pipelined:
+                held.append(p)
+            elif stage_eagerly is not None and stage_eagerly(p.write_req):
+                eager.append(p)
+            elif stager_aliases_caller_memory(p.write_req.buffer_stager):
+                held.append(p)
+            else:
+                p.in_window = False
+                released.append(p)
+        self.pipelines = deque(
+            p
+            for group in (eager, held, released)
+            for p in sorted(group, key=lambda p: p.staging_cost, reverse=True)
+        )
+        # Identity set, not a count: with TPUSNAP_STAGE_THREADS >= 2
+        # an interleaved NON-eager stager can complete first, and a
+        # bare countdown would let the blocked window close while an
+        # eager (manifest-annotating) stager is still in flight.
+        self.eager_pending = {id(p) for p in eager}
         total_cost = sum(p.staging_cost for p in pls)
+        released_cost = sum(p.staging_cost for p in released)
+        if self.pipelined or prioritize_staging:
+            # An async take, whichever mode: what the return waits for
+            # (before the window's clamp below) and what it does not.
+            telemetry.incr(
+                "scheduler.window_held_bytes",
+                total_cost - released_cost,
+                rec=tele,
+            )
+            telemetry.incr(
+                "scheduler.window_released_bytes", released_cost, rec=tele
+            )
         if self.pipelined:
             window = get_async_stage_window_bytes()
             if window is not None:
@@ -691,8 +717,12 @@ class _WriteScheduler:
         self.budget = memory_budget_bytes
         self.reporter.total_budget = memory_budget_bytes
         # First-window target: the blocked window stages at least this
-        # much staging cost (everything, when the state fits the window).
-        self.first_window_target = min(memory_budget_bytes, total_cost)
+        # much staging cost of the requests that count towards it (all
+        # of them, when they fit the window; nothing, when there is none).
+        self.first_window_target = min(
+            memory_budget_bytes, total_cost - released_cost
+        )
+        self.window_staged_cost = 0
         self.staging_tasks: Set[asyncio.Task] = set()
         self.io_tasks: Set[asyncio.Task] = set()
         self.ready_for_io: List[_WritePipeline] = []
@@ -818,6 +848,8 @@ class _WriteScheduler:
 
     def _on_staged(self, pipeline: "_WritePipeline") -> None:
         self.staged_cost_total += pipeline.staging_cost
+        if pipeline.in_window:
+            self.window_staged_cost += pipeline.staging_cost
         if not self.pipelined:
             return
         self._window_accum += pipeline.staging_cost
@@ -845,7 +877,7 @@ class _WriteScheduler:
     def _first_window_done(self) -> bool:
         return (
             not self.eager_pending
-            and self.staged_cost_total >= self.first_window_target
+            and self.window_staged_cost >= self.first_window_target
         )
 
     def _finish_staging(self) -> None:
@@ -944,6 +976,11 @@ class _WriteScheduler:
         self.reporter.mark_blocked_window_done()
         if self.pipelined:
             self.blocked = False  # I/O gate opens for the drain
+            if self.staging_tasks:
+                # One turn of the loop, so that what was dispatched and
+                # not waited for reaches the staging executor now and
+                # not when the drain first runs the loop.
+                await asyncio.sleep(0)
             if self.tele is not None:
                 self.tele.record_span(
                     "stage_blocked",

@@ -509,12 +509,16 @@ class Snapshot:
                 stream_capture=_stream_capture,
             )
             # Control returns to training here: the blocked window is
-            # over — the first staging window is staged (ALL staging,
-            # when the state fits TPUSNAP_ASYNC_STAGE_WINDOW_BYTES or
-            # the take is incremental); residual windows clone on the
-            # background drain, interleaved with storage I/O. Callers
-            # that mutate host-aliasing state IN PLACE synchronize on
-            # wait_staged(); functional JAX updates never need to.
+            # over — of the requests whose bytes the caller could write
+            # in place the first staging window is staged (all of them
+            # when they fit TPUSNAP_ASYNC_STAGE_WINDOW_BYTES; ALL
+            # staging when the take is incremental or the window 0);
+            # accelerator-resident leaves, held by reference, and the
+            # residual windows are staged on the background drain,
+            # interleaved with storage I/O. Callers that mutate
+            # host-aliasing state IN PLACE, and steps that DONATE their
+            # state, synchronize on wait_staged(); functional JAX
+            # updates that donate nothing never need to.
             return PendingSnapshot(
                 path=path,
                 pending_io_work=pending_io_work,
@@ -1723,10 +1727,12 @@ def _take_impl(
     mark("prepare", then="stage", write_reqs=len(write_reqs))
     # Async-take scheduling mode. PIPELINED (the default async path):
     # the blocked window stages only a TPUSNAP_ASYNC_STAGE_WINDOW_BYTES
-    # window of write requests before control returns; the remaining
-    # windows clone on the background drain, interleaved with their
-    # storage I/O — blocked time and clone RSS are O(window), not
-    # O(state). Incremental takes cannot pipeline: their dedup
+    # window of write requests before control returns, and only of
+    # those whose bytes the caller could write in place afterwards
+    # (scheduler._WriteScheduler: an accelerator-resident leaf never
+    # counts); the rest is staged on the background drain, interleaved
+    # with its storage I/O — blocked time and clone RSS are O(window),
+    # not O(state). Incremental takes cannot pipeline: their dedup
     # decisions mutate entry locations at stage time and must be final
     # before the manifest gather below, so they keep the strict
     # stage-everything-first mode (their blocked window is inherently
@@ -3324,19 +3330,23 @@ class PendingSnapshot(_BackgroundWork):
     def staged(self) -> bool:
         """Whether the snapshot content is frozen — safe for the caller
         to mutate host-aliasing state IN PLACE (raw numpy buffers,
-        pinned_host donation). Functional JAX updates never need this —
-        the stagers hold references, and staging a donated-and-deleted
-        device array fails loudly.
+        pinned_host donation) and to run a step that DONATES device
+        state. Functional JAX updates that donate nothing never need
+        this — the stagers hold references. A device array donated
+        (deleted) before it was staged fails the take by the leaf's
+        name, at every state size: accelerator-resident leaves are
+        never in the blocked window.
 
         Ordinarily this is staging-complete (no buffer aliases live
-        arrays any more): true at construction for non-pipelined takes;
-        pipelined takes (state larger than
-        TPUSNAP_ASYNC_STAGE_WINDOW_BYTES) stage their residual windows
-        on the background drain. Under TPUSNAP_ASYNC_COW the live bytes
-        stay aliased until each blob's write+verify lands, so this
-        reports THIS RANK's write-drain boundary instead (strictly
-        earlier than the cross-rank commit barrier) — the rendezvous
-        CONTRACT (staged() ⟹ safe to mutate) holds either way."""
+        arrays any more): true at construction for non-pipelined takes
+        (incremental, or window 0); pipelined takes stage their
+        accelerator-resident leaves, and what the window did not hold
+        of the rest, on the background drain. Under TPUSNAP_ASYNC_COW
+        the live bytes stay aliased until each blob's write+verify
+        lands, so this reports THIS RANK's write-drain boundary instead
+        (strictly earlier than the cross-rank commit barrier) — the
+        rendezvous CONTRACT (staged() ⟹ safe to mutate) holds either
+        way."""
         if self._cow_rendezvous:
             return self._pending_io_work.drained()
         return self._pending_io_work.staging_complete()
