@@ -387,6 +387,7 @@ def test_a_donating_step_that_returns_its_state_unchanged_is_not_correct(cache_d
 
 # ---- (e) the mix's interval, (f) the manifest's entries
 
+@pytest.mark.manifest_shape
 def test_the_mix_asks_no_more_of_the_storage_than_it_is_known_to_drain():
     """What PERF.md 6 found (PR 34, PR 36): the work directory's mount drains
     0.33 GB/s and not more, and the warm-up take of set-up counts: two saves
@@ -411,6 +412,7 @@ def test_the_mix_asks_no_more_of_the_storage_than_it_is_known_to_drain():
     assert (flagship["first_save_s"], flagship["save_every_s"]) == (2.0, 11.0)
 
 
+@pytest.mark.manifest_shape
 def test_the_manifests_entries_for_the_donated_cell():
     cells = {w["name"]: w for w in MANIFEST["workloads"]}
     configs = {c["name"]: c for c in MANIFEST["configs"]}
@@ -438,13 +440,22 @@ def test_the_manifests_entries_for_the_donated_cell():
     assert end_to_end["resume_s"]["bound"] == 0.075
     assert {m["name"]: m["bound"] for m in MANIFEST["end_to_end"] if m["name"] != "resume_s"} == {
         "setup_s": 0.1, "train_tokens_per_s": 0.1}
+    from test_harness import one_chip_save_loop_cells, within_the_four_chip_quota
+
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == "staged_wait_ms")
-    assert entry == {"name": "staged_wait_ms", "unit": "ms", "better": "lower",
-                     "source": "host_clock", "layer": "entry", "moves": "train_tokens_per_s",
-                     "workloads": [CELL]}
-    assert MANIFEST["per_layer"][-1] is entry  # appended
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
+        "name": "staged_wait_ms", "unit": "ms", "better": "lower", "source": "host_clock",
+        "layer": "entry", "moves": "train_tokens_per_s"}
+    # This cell first; a later cell whose program donates is listed after it.
+    assert entry["workloads"][0] == CELL
+    assert set(entry["workloads"]) <= set(one_chip_save_loop_cells())
+    # Appended: after every entry the manifest had at PR 34.
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    assert names.index("staged_wait_ms") > names.index("save_durable_s.one_chip")
     spec = harness.layer_metric_spec("staged_wait_ms")
     assert spec["reducer"] == "window_value" and spec["args"] == {"name": "staged_wait_ms"}
     assert not spec.get("count")  # a time: never printed from the CPU
-    four_chip = [w["name"] for w in MANIFEST["workloads"] if w["chips"] == 4]
-    assert four_chip == ["pythia-1b.save-loop"] and len(cells) == 5
+    # The one cell whose state exists only across chips keeps its four; how
+    # many may is a rule of the cells' number (``test_harness.py`` holds it).
+    assert cells["pythia-1b.save-loop"]["chips"] == 4
+    assert within_the_four_chip_quota(MANIFEST)

@@ -42,6 +42,23 @@ def _one_cell_per_traffic_kind():
     return sorted(seen.items())
 
 
+SAVE_LOOP_CELL = next(c["name"] for c in MANIFEST["workloads"] if c["traffic"] == "save_loop")
+
+
+def one_chip_save_loop_cells(manifest=MANIFEST):
+    """The one-chip cells whose mix is of kind ``save_loop``, in the
+    manifest's order: the cells that share the write path's readings."""
+    return [w["name"] for w in manifest["workloads"] if w["chips"] == 1
+            and _perf_json("traffic", f"{w['traffic']}.json")["kind"] == "save_loop"]
+
+
+def within_the_four_chip_quota(manifest):
+    """The contract's rule, for any number of cells: of a benchmark's cells
+    a quarter, rounded down, may ask for four chips, and one always may."""
+    four_chip = [w for w in manifest["workloads"] if w["chips"] == 4]
+    return len(four_chip) <= max(1, len(manifest["workloads"]) // 4)
+
+
 @pytest.fixture(scope="module")
 def cache_dir(tmp_path_factory):
     return str(tmp_path_factory.mktemp("perf_jax_cache"))
@@ -65,6 +82,7 @@ def _result(proc):
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
+@pytest.mark.manifest_shape
 def test_manifest_names_files_that_exist():
     for config in MANIFEST["configs"]:
         path = os.path.join(ROOT, config["file"])
@@ -88,6 +106,7 @@ def test_manifest_names_files_that_exist():
     assert _perf_json("peaks.json")["devices"]
 
 
+@pytest.mark.manifest_shape
 def test_every_moves_is_an_end_to_end_metric_its_cells_report():
     reported = {
         m["name"]: set(m.get("workloads", CELLS)) for m in MANIFEST["end_to_end"]
@@ -103,6 +122,7 @@ def test_every_moves_is_an_end_to_end_metric_its_cells_report():
         assert any(cell in m.get("workloads", CELLS) for m in MANIFEST["per_layer"])
 
 
+@pytest.mark.manifest_shape
 def test_names_and_units_hold_only_the_allowed_characters():
     metrics = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
     names = [m["name"] for m in metrics]
@@ -117,6 +137,22 @@ def test_names_and_units_hold_only_the_allowed_characters():
         assert all(NAME.match(k) for k in [config["name"], *config["reduced"]])
 
 
+@pytest.mark.manifest_shape
+@pytest.mark.parametrize("cell", [c for c in one_chip_save_loop_cells() if c != SAVE_LOOP_CELL])
+def test_a_one_chip_save_loop_cell_is_listed_wherever_the_first_one_is(cell):
+    """One write path, one set of readings: a later one-chip cell of a
+    ``save_loop`` mix drops none of the metrics that the first reports."""
+    for metric in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+        listed = metric.get("workloads", CELLS)
+        if SAVE_LOOP_CELL in listed:
+            assert cell in listed, metric["name"]
+
+
+@pytest.mark.manifest_shape
+def test_the_four_chip_cells_keep_to_their_quota():
+    assert within_the_four_chip_quota(MANIFEST)
+
+
 SHARED_REFERENCE = "first_steps"  # what every architecture's reference shares
 PROGRAM_FUNCTIONS = ("build",)
 REFERENCE_FUNCTIONS = ("sizes", "n_params", "state_bytes", "train_flops_per_token",
@@ -127,6 +163,7 @@ def _modules(folder):
     return {f[:-3] for f in os.listdir(os.path.join(PERF, folder)) if f.endswith(".py")}
 
 
+@pytest.mark.manifest_shape
 def test_no_cell_or_configuration_is_named_in_code():
     names = set(CELLS) | {c["name"] for c in MANIFEST["configs"]}
     for folder, _, files in os.walk(PERF):
@@ -393,7 +430,6 @@ STUB_CONFIG = {
     "limits": {"loss_gap": 1e-4, "grad_norm_gap": 1e-3, "delta_norm_gap": 1e-3,
                "grad_diff": 1e-3},
 }
-SAVE_LOOP_CELL = next(c["name"] for c in MANIFEST["workloads"] if c["traffic"] == "save_loop")
 STUB_CONFIGS = {  # name -> what its file changes of STUB_CONFIG
     "two-matrix": {},
     "two-matrix-no-residual": {"program": "two_matrix_no_residual"},
@@ -482,6 +518,7 @@ def test_a_configuration_that_names_no_program_or_reference_prints_nothing(
     assert f"names no {wanted} that exists" in proc.stderr
 
 
+@pytest.mark.manifest_shape
 @pytest.mark.parametrize("name", sorted(os.listdir(os.path.join(PERF, "configs"))))
 def test_every_configuration_names_a_program_and_a_reference_that_exist(name):
     from perf import harness
@@ -495,6 +532,7 @@ def test_every_configuration_names_a_program_and_a_reference_that_exist(name):
     assert all(callable(getattr(reference, f, None)) for f in REFERENCE_FUNCTIONS)
 
 
+@pytest.mark.manifest_shape
 @pytest.mark.parametrize("preset", sorted(
     {_perf_json("configs", f"{c['name']}.json")["rehearsal_config"] for c in MANIFEST["configs"]}))
 def test_the_program_keeps_its_contract_and_the_references_state_bytes(preset):

@@ -31,6 +31,7 @@ SPANS = harness.layer_metric_spec("relayout_ms")["args"]["spans"]
 COUNTERS = harness.layer_metric_spec("relayout_bytes_per_state_byte")["args"]["counters"]
 
 
+@pytest.mark.manifest_shape
 def test_the_metric_files_name_the_programs_span_and_counter():
     assert SPANS == ["relayout"] and COUNTERS == ["stage.relayout_bytes"]
     ms, share = (harness.layer_metric_spec(name) for name in READINGS)
@@ -38,20 +39,22 @@ def test_the_metric_files_name_the_programs_span_and_counter():
     assert not ms.get("count") and share["count"] is True  # a time is never printed from the CPU
 
 
+@pytest.mark.manifest_shape
 @pytest.mark.parametrize("entry", ENTRIES, ids=lambda m: m["name"])
 def test_an_entry_reads_a_cell_whose_state_has_a_leaf_to_turn(entry):
-    """One reading a cell with such a leaf: the sparse cell's head and its
-    moments, and on four chips the ``.sharded`` twin for the sharded head's.
-    The dense one-chip cells have none (50,304 is 393 x 128) and no entry."""
+    """A reading lists the cells with such a leaf, one or more: the sparse
+    cell's head and its moments, and on four chips the ``.sharded`` twin for
+    the sharded head's. The dense one-chip cells have none (50,304 is
+    393 x 128) and are in no list."""
     reading = entry["name"].split(".")[0]
     assert (entry["unit"], entry["source"]) == READINGS[reading]
     assert (entry["layer"], entry["moves"], entry["better"]) == (
         "stage/hash", "train_tokens_per_s", "lower")
     cells = {w["name"]: w for w in MANIFEST["workloads"]}
-    assert len(entry["workloads"]) == 1
-    chips = cells[entry["workloads"][0]]["chips"]
-    assert chips == (4 if entry["name"].endswith(".sharded") else 1)
-    assert cells[entry["workloads"][0]]["config"] != "pythia-410m"
+    assert len(entry["workloads"]) >= 1
+    for listed in entry["workloads"]:
+        assert cells[listed]["chips"] == (4 if entry["name"].endswith(".sharded") else 1)
+        assert cells[listed]["config"] != "pythia-410m"
     spec = harness.layer_metric_spec(entry["name"])
     assert callable(harness.load_module("reducers", spec["reducer"]).reduce) and spec["doc"]
     # Appended: after every entry the manifest had at PR 32.
@@ -59,6 +62,7 @@ def test_an_entry_reads_a_cell_whose_state_has_a_leaf_to_turn(entry):
     assert names.index(entry["name"]) > names.index("attn_share_of_step")
 
 
+@pytest.mark.manifest_shape
 def test_both_readings_have_their_one_chip_and_their_four_chip_entry():
     assert sorted(m["name"] for m in ENTRIES) == sorted(
         [r for r in READINGS] + [f"{r}.sharded" for r in READINGS])

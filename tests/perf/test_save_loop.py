@@ -122,6 +122,7 @@ def test_a_save_is_stamped_when_it_becomes_durable_not_when_the_loop_looks(monke
     assert result["end_to_end"]["save_durable_s"] == statistics.median(result["durable_s"])
 
 
+@pytest.mark.manifest_shape
 @pytest.mark.parametrize("name", MIXES)
 def test_the_window_holds_the_saves_that_the_mixs_file_gives(monkeypatch, name):
     """The schedule is by the clock: at a twentieth of the file's times and
@@ -139,6 +140,7 @@ def test_the_window_holds_the_saves_that_the_mixs_file_gives(monkeypatch, name):
     assert all(abs(g - scaled["save_every_s"]) < 0.06 for g in gaps), gaps
 
 
+@pytest.mark.manifest_shape
 def test_the_one_chip_mix_keeps_to_the_rate_that_the_storage_sustains():
     """What PERF.md 6 (PR 34) found on the chip: four saves of 3.65 GB a
     window of 45 s, 0.33 GB/s, drain; eight (every 5.5 s) do not, the

@@ -65,6 +65,7 @@ def _checks(stdout):
     return {r["name"]: r for r in rows}
 
 
+@pytest.mark.manifest_shape
 def test_the_manifest_has_the_configuration_and_its_one_cell():
     entry = next(c for c in MANIFEST["configs"] if c["name"] == CONFIG)
     held = _perf_json("configs", f"{CONFIG}.json")
@@ -78,10 +79,22 @@ def test_the_manifest_has_the_configuration_and_its_one_cell():
             assert CELL["name"] in cells, metric["name"]
     new = {m["name"]: m for m in MANIFEST["per_layer"] if m["name"] in NEW_METRICS}
     assert sorted(new) == sorted(NEW_METRICS)
+    # The slab readings are the write path's: every one-chip cell of a
+    # ``save_loop`` mix, in the manifest's order, whatever their number.
+    from test_harness import one_chip_save_loop_cells
+
+    save_cells = one_chip_save_loop_cells()
+    assert {OLD_CELL, CELL["name"]} <= set(save_cells)
     for name in NEW_METRICS[:3]:
-        assert new[name]["workloads"] == [OLD_CELL, CELL["name"], "pythia-410m-24l.save-loop-donated"]
+        assert new[name]["workloads"] == save_cells
+    # The step's shares are read off named scopes: this cell, and whichever
+    # later one-chip training cell names its scopes so.
+    cells = {w["name"]: w for w in MANIFEST["workloads"]}
+    trains = next(m for m in MANIFEST["end_to_end"] if m["name"] == "train_tokens_per_s")
     for name in NEW_METRICS[3:]:
-        assert new[name]["workloads"] == [CELL["name"]] and new[name]["layer"] == "train step"
+        assert CELL["name"] in new[name]["workloads"] and new[name]["layer"] == "train step"
+        for listed in new[name]["workloads"]:
+            assert cells[listed]["chips"] == 1 and listed in trains["workloads"], (name, listed)
     # Entries are appended: every one of the five comes after the last of PR 26's,
     # and a later PR's come after these.
     names = [m["name"] for m in MANIFEST["per_layer"]]

@@ -201,6 +201,7 @@ def test_the_reducer_finds_the_trace_beside_the_telemetry_dir(tmp_path, monkeypa
     assert tui.reduce({"trace": {"window_s": 9.0}}) is None
 
 
+@pytest.mark.manifest_shape
 @pytest.mark.parametrize("metric", NEW, ids=lambda m: m["name"])
 def test_new_entry_resolves_to_a_file_and_a_reducer(metric):
     from perf import harness
@@ -218,6 +219,7 @@ def test_new_entry_resolves_to_a_file_and_a_reducer(metric):
     assert names.index(metric["name"]) >= names.index("dtoh_bytes_per_state_byte.sharded") + 1
 
 
+@pytest.mark.manifest_shape
 def test_the_new_entries_are_the_table_of_the_issue():
     assert len(NEW) >= 23  # a later configuration's cells append the same readings under their own suffix
     assert sorted({m["name"].split(".")[0] for m in NEW}) == sorted(READINGS)
