@@ -2,16 +2,19 @@
 
 The reference library ships example *training scripts* (DDP / FSDP /
 torchrec DLRM, SURVEY.md §2 #23-24) but no model code of its own. tpusnap
-ships three model families: a flagship decoder transformer whose parameter
+ships four model families: a flagship decoder transformer whose parameter
 pytree exercises every sharding family the checkpoint preparers must
 handle — DP (replicated), FSDP (param-sharded), TP (tensor-parallel),
 SP/CP (ring attention over a sequence axis) and EP (expert-sharded MoE
 weights) —, a sharded embedding-table collection (the torchrec DMP
 analog: row/col/table-wise layouts, host-offloaded tables, row-wise
-Adagrad state), and one chip's share of a sparse-expert decoder with
+Adagrad state), one chip's share of a sparse-expert decoder with
 window and global attention (``smallthinker``: top-k token dispatch over
 the experts held here, one subtree a layer, so a state of many leaves of
-a few tens of MiB).
+a few tens of MiB), and a looped decoder (``ouro``: one stack of layers
+run several times over the same leaves, sandwich norms, an exit gate and
+a loss term at every pass, so a gradient that sums over a leaf's uses and
+a state with leaves of one element beside leaves of hundreds of MB).
 """
 
 from .embedding import (  # noqa: F401
@@ -19,6 +22,7 @@ from .embedding import (  # noqa: F401
     TableConfig,
     make_embedding_train_step,
 )
+from .ouro import Ouro, OuroConfig  # noqa: F401
 from .smallthinker import SmallThinker, SmallThinkerConfig  # noqa: F401
 from .transformer import (  # noqa: F401
     Transformer,
@@ -29,6 +33,8 @@ from .transformer import (  # noqa: F401
 
 __all__ = [
     "EmbeddingCollection",
+    "Ouro",
+    "OuroConfig",
     "SmallThinker",
     "SmallThinkerConfig",
     "TableConfig",
