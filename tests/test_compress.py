@@ -397,10 +397,11 @@ def _compile_events():
 
 @needs_native
 def test_sample_reads_a_jax_leaf_without_a_device_operation(monkeypatch):
-    """The sample's bytes are the host copy that ``prepare`` started:
-    between the stager's construction and the decision nothing is
-    lowered or compiled, and the stager's own ``dtoh`` span (with the
-    leaf's bytes) follows the sample's."""
+    """The sample's bytes are the host copy that the sampler starts for
+    its one source, the copy staging then uses (counted once): between
+    the stager's construction and the decision nothing is lowered or
+    compiled, and the stager's own ``dtoh`` span (with the leaf's
+    bytes) follows the sample's."""
     import jax.numpy as jnp
 
     monkeypatch.setattr(compress_mod, "AUTO_MIN_TAKE_BYTES", 1 << 18)
@@ -412,11 +413,15 @@ def test_sample_reads_a_jax_leaf_without_a_device_operation(monkeypatch):
     assert heard  # the listener does hear this backend
     rec = telemetry.TakeTelemetry(rank=0, enabled=True)
     with telemetry.metrics_sink(_SpanSink()) as sink, _compile_events() as compiles:
-        reqs, st = _mk_reqs(arr=leaf)  # prepare: the copy to the host starts
-        assert st.dtoh_started is not None and st.host_bytes_are_free()
+        reqs, st = _mk_reqs(arr=leaf)  # prepare: no copy is started yet
+        assert st.dtoh_started is None and st.host_bytes_are_free()
+        before = telemetry.counter_value("dtoh.enqueued_bytes")
         with override_compress(mode="auto", min_blob_bytes=65536):
             d = compress_mod.apply_take_policy(reqs, None, None, rec=rec)
         assert d.compress and d.sample_bytes == leaf.nbytes
+        assert st.dtoh_started is not None  # the sampler started it
+        assert st.start_dtoh() == leaf.nbytes  # the scheduler's call: no second copy
+        assert telemetry.counter_value("dtoh.enqueued_bytes") - before == leaf.nbytes
         with telemetry.use(rec):
             staged = st._stage_blocking()
     assert compiles == []

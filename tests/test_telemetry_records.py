@@ -223,15 +223,19 @@ def test_dtoh_transfer_starts_before_staging_and_counts_the_bytes(run):
     leaves = [r for r in transfers if "slab_members" not in r.attrs]
     slabs = [r for r in transfers if "slab_members" in r.attrs]
     assert len(leaves) == 3 and slabs
+    (prepare,) = _of(run, take1, "prepare")
     for r in leaves:
         work = run["by_id"][r.parent]
-        assert work.name == "stage.work" and r.start < work.start, (r, work)
+        # Started by the scheduler's dispatch: after prepare, which
+        # starts none, and before the leaf's own staging.
+        assert work.name == "stage.work" and prepare.end <= r.start < work.start, (r, work)
         assert r.kind == telemetry.WORK
     state = run["state"]
     small = sum(x.nbytes for x in jax.tree.leaves(state["small"]))
     big = sum(x.nbytes for x in jax.tree.leaves(state["big"]))
     assert sum(r.attrs["bytes"] for r in slabs) == small
-    # Every leaf's copy is started (and counted) at prepare time; the
+    # Every leaf's copy is started (and counted) once, when the
+    # scheduler's dispatch reaches it or a fixed depth before; the
     # members of a slab cross again inside it, and only the slab's
     # crossing is observed. So: the spans' bytes are the counter's, less
     # the members' prefetch, plus the slabs'.

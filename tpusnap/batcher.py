@@ -69,6 +69,14 @@ def _batchable_tensor_entries(entries: List[Entry]) -> Dict[str, TensorEntry]:
     return out
 
 
+def _start_members_dtoh(members) -> int:
+    """A slab's ``start_dtoh``: its members' copies. The device slab's
+    are started too, and thrown away when the pack succeeds (ROADMAP
+    S2 (b)): the host fallback fetches them, and the count of a take's
+    crossings holds the waste (``dtoh_bytes_per_state_byte``)."""
+    return sum(s.start_dtoh() for _, _, s in members)
+
+
 def _any_member_aliases(members) -> bool:
     """A slab counts towards an async take's blocked window when any
     member's bytes may be written in place by the caller (see
@@ -161,6 +169,9 @@ class BatchedBufferStager(BufferStager):
         if new_offset == 0:
             return SKIP_WRITE
         return slab[:new_offset]
+
+    def start_dtoh(self) -> int:
+        return _start_members_dtoh(self.members)
 
     def aliases_caller_memory(self) -> bool:
         return _any_member_aliases(self.members)
@@ -300,6 +311,9 @@ class DeviceBatchedBufferStager(BufferStager):
                 stager.entry.byte_range = [new_offset, new_offset + nbytes]
             new_offset += nbytes
         return out
+
+    def start_dtoh(self) -> int:
+        return _start_members_dtoh(self.members)
 
     def aliases_caller_memory(self) -> bool:
         # The packed slab aliases nothing, but the host fallback stages

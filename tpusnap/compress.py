@@ -12,9 +12,10 @@ tile-grain random access on the restore path.
 The policy is MEASURED ON THE TAKE, not configured
 (``TPUSNAP_COMPRESS=auto``, the default). Before anything is staged,
 the real codec pass runs over ``SAMPLE_BYTES`` of the host bytes of the
-eligible leaf staging reaches first (the largest; its copy to the host
-was started at prepare time, so the sample waits out only what is left
-of that copy and runs no device operation), and reads two numbers:
+eligible leaf staging reaches first (the largest; the sampler starts
+that one leaf's copy to the host, the copy staging will use, so the
+sample waits out one leaf's transfer and runs no device operation of
+its own), and reads two numbers:
 ``sample_ratio`` r (bytes out / bytes in) and ``sample_gbps`` c (bytes
 in / second, on the threads staging will use). The pipe's write ceiling
 p comes from the in-take roofline probes (``TPUSNAP_PROBE=1``,
@@ -324,11 +325,14 @@ def _sample_codec(eligible, rec) -> Optional[Tuple[float, float, int]]:
     st = max(sources, key=lambda s: s.get_planned_bytes())
     spans = rec is not None and rec.enabled
     t0 = rec.now() if spans else 0.0
+    # The copy staging will use, started here and counted once: the
+    # scheduler dispatches this request first and finds it under way.
+    st.start_dtoh()
     host = np.asarray(st.arr)
     if spans and not isinstance(st.arr, np.ndarray):
-        # What is left of the copy started at prepare time: the wait the
-        # staging thread would have paid for this leaf. It keeps that
-        # wait's name; the leaf's bytes stay with the stager's own span.
+        # This one leaf's transfer: the wait the staging thread would
+        # have paid for it. It keeps that wait's name; the leaf's bytes
+        # stay with the stager's own span.
         rec.record_span(
             "dtoh", t0, rec.now() - t0, kind=telemetry.WAIT, sample=True
         )

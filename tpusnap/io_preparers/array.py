@@ -3,10 +3,16 @@
 TPU-native counterpart of /root/reference/torchsnapshot/io_preparers/tensor.py.
 Where the reference stages with ``Tensor.to("cpu")`` in GIL-released
 TorchScript (tensor.py:247-305,351-358), this preparer uses XLA's async
-device→host DMA: ``jax.Array.copy_to_host_async()`` is enqueued at prepare
-time so the DMA overlaps with scheduling, and the thread-pooled
-``np.asarray`` in ``stage_buffer`` then finds the host copy ready (numpy
-releases the GIL for the copy; the PJRT transfer releases it too).
+device→host DMA: ``jax.Array.copy_to_host_async()``. A stager starts no
+copy when it is built; ``start_dtoh()`` does, once, and the write
+scheduler calls it as it dispatches requests, a fixed depth ahead of the
+thread that fetches (``_WriteScheduler._start_dtoh_ahead``): on the TPU
+runtime a program dispatched after a copy waits behind it, so a state's
+copies all started at once stand in front of the caller's next step,
+while a step does run between two leaves' copies. The thread-pooled
+``np.asarray`` in ``stage_buffer`` then waits out what is left of its
+leaf's copy (numpy releases the GIL for the copy; the PJRT transfer
+releases it too).
 
 Differences by design:
 - JAX arrays are immutable, so the reference's in-place load
@@ -31,6 +37,7 @@ import jax
 import numpy as np
 
 from .. import telemetry
+from ..host_offload import is_host_resident
 from ..io_types import (
     BufferConsumer,
     BufferStager,
@@ -68,16 +75,14 @@ def is_supported_array_dtype(arr: ArrayLike) -> bool:
 
 
 def enqueue_dtoh(arr: ArrayLike) -> Optional[float]:
-    """Start the device→host DMA early (overlaps with scheduling), and
-    return the ``time.monotonic()`` reading of the call (None where no
-    transfer was started): the start of the leaf's ``dtoh.transfer``.
+    """Start the device→host DMA ahead of the fetch, and return the
+    ``time.monotonic()`` reading of the call (None where no transfer
+    was started): the start of the leaf's ``dtoh.transfer``.
 
     Host-offloaded arrays (host_offload.py, the UVM analog) skip the
     enqueue: their buffers already live in host memory, so staging is a
     plain view — the reference's uvm_to_cpu shortcut
     (io_preparers/tensor.py:257-259)."""
-    from ..host_offload import is_host_resident
-
     if not isinstance(arr, jax.Array) or is_host_resident(arr):
         return None
     started = time.monotonic()
@@ -169,24 +174,39 @@ class ArrayBufferStager(BufferStager):
         self._aliases_caller_memory = (
             not is_async_snapshot or _may_alias_live_memory(arr, None)
         )
-        # When the prefetch of this leaf's host copy was started, if it was.
+        # When the prefetch of this leaf's host copy was started, if it
+        # was: by start_dtoh(), never here.
         self.dtoh_started: Optional[float] = None
-        if array_prepare_func is None:
-            # A transform usually changes the bytes; prefetching the
-            # untransformed array's DtoH would be wasted DMA.
-            self.dtoh_started = enqueue_dtoh(arr)
+        self._dtoh_asked = False
+
+    def _prefetches(self) -> bool:
+        """Whether ``start_dtoh`` has a copy to start: an accelerator's
+        array staged as it is. A transform usually changes the bytes, so
+        prefetching the untransformed array would be wasted DMA."""
+        return self.array_prepare_func is None and not is_host_resident(self.arr)
+
+    def start_dtoh(self) -> int:
+        """Start this leaf's copy to the host, once, and return the
+        bytes under way (0 where there is no copy to start)."""
+        if not self._dtoh_asked:
+            self._dtoh_asked = True
+            # A deleted array is staging's to report, by the leaf's name.
+            if self._prefetches() and not self.arr.is_deleted():
+                self.dtoh_started = enqueue_dtoh(self.arr)
+        return array_nbytes(self.arr) if self.dtoh_started is not None else 0
 
     def host_bytes_are_free(self) -> bool:
-        """Whether ``np.asarray(self.arr)`` runs no device operation
-        before staging does: a numpy leaf, or one whose copy to the host
-        was started above (the call then waits out what is left of that
-        copy, and JAX keeps the host value for ``_stage_blocking``'s own
-        call). Never for a leaf behind an ``array_prepare_func``, whose
-        staged bytes are another array's. The compress policy samples
-        only such a leaf."""
+        """Whether ``np.asarray(self.arr)`` yields the bytes staging
+        will stage and runs no device operation of its own: a numpy
+        leaf, or an accelerator's array with no ``array_prepare_func``
+        (after ``start_dtoh()`` the call waits out that one copy, and JAX
+        keeps the host value for ``_stage_blocking``'s own call). Never
+        for a leaf behind an ``array_prepare_func``, whose staged bytes
+        are another array's. The compress policy samples only such a
+        leaf."""
         if self.array_prepare_func is not None:
             return False
-        return isinstance(self.arr, np.ndarray) or self.dtoh_started is not None
+        return isinstance(self.arr, np.ndarray) or self._prefetches()
 
     def aliases_caller_memory(self) -> bool:
         return self._aliases_caller_memory
@@ -240,15 +260,19 @@ class ArrayBufferStager(BufferStager):
         except RuntimeError:
             self.raise_if_donated()  # deleted under the call
             raise
+        prefetched = arr is self.arr and self.dtoh_started is not None
+        if not prefetched and not is_host_resident(arr):
+            # No copy was under way: the fetch above was the transfer.
+            # Only a leaf behind an array_prepare_func may count here.
+            telemetry.incr("dtoh.cold_fetches")
         if dtoh_t0 is not None:
             # `dtoh` is this call alone. For a prefetched leaf that is
-            # the residual wait for a copy started at prepare time, not
-            # the transfer; `dtoh.transfer` runs from the start of the
-            # copy to here, where the host copy is SEEN ready: an upper
-            # bound on the transfer (ready is observed, not signalled,
-            # and a leaf staged late was ready long before).
+            # the residual wait for a copy the scheduler started ahead,
+            # not the transfer; `dtoh.transfer` runs from the start of
+            # the copy to here, where the host copy is SEEN ready: an
+            # upper bound on the transfer (ready is observed, not
+            # signalled, and a leaf staged late was ready long before).
             done = rec.now()
-            prefetched = arr is self.arr and self.dtoh_started is not None
             started = self.dtoh_started - rec.t0 if prefetched else dtoh_t0
             rec.record_span(
                 "dtoh", dtoh_t0, done - dtoh_t0, bytes=host.nbytes,
@@ -689,8 +713,6 @@ def _may_alias_live_memory(arr: ArrayLike, host: np.ndarray) -> bool:
     backend, and numpy sources alias the caller's array by
     construction — those always clone."""
     if isinstance(arr, jax.Array):
-        from ..host_offload import is_host_resident
-
         if is_host_resident(arr):
             return True
         try:

@@ -139,6 +139,13 @@ class BufferStager(abc.ABC):
         this so heartbeat percentages can reach 100."""
         return self.get_staging_cost_bytes()
 
+    def start_dtoh(self) -> int:
+        """Start whatever device-to-host copy this request's staging
+        will wait for, once, and return the bytes under way. The write
+        scheduler calls it a fixed depth ahead of the request it
+        dispatches; the default has no copy to start."""
+        return 0
+
     def aliases_caller_memory(self) -> bool:
         """Whether the bytes this request stages may live in memory the
         caller can write IN PLACE once ``async_take`` has returned (a
@@ -156,6 +163,13 @@ def stager_aliases_caller_memory(stager: BufferStager) -> bool:
     ``BufferStager`` and has no such method counts as aliasing."""
     ask = getattr(stager, "aliases_caller_memory", None)
     return True if ask is None else bool(ask())
+
+
+def stager_start_dtoh(stager: BufferStager) -> int:
+    """``stager.start_dtoh()``; a stager that is no ``BufferStager`` and
+    has no such method has no copy to start."""
+    start = getattr(stager, "start_dtoh", None)
+    return 0 if start is None else int(start())
 
 
 @dataclass

@@ -58,6 +58,7 @@ from .io_types import (
     WriteReq,
     run_on_loop,
     stager_aliases_caller_memory,
+    stager_start_dtoh,
 )
 from .knobs import get_memory_budget_override_bytes
 
@@ -66,6 +67,18 @@ logger = logging.getLogger(__name__)
 import os as _os
 
 _MAX_IO_CONCURRENCY = 16
+# How far ahead of the request it dispatches the write scheduler starts
+# copies to the host (`_WriteScheduler._start_dtoh_ahead`): the
+# dispatched request's own and the next one's always, further ones while
+# the bytes started and not yet staged are under this many. On the TPU
+# runtime a program dispatched after a copy waits behind it, and how long
+# follows the leaves in flight (scripts/dtoh_overlap_probe.py, on a v5e:
+# 16 steps of 80 ms beside 3.76 GB of copies cost +0.8 s with every copy
+# started at once; with leaves of 128-384 MiB +0.2-0.5 s one leaf ahead
+# and +0.6-0.9 s two ahead; leaves of 64 MiB nothing at eight in flight,
+# and they reach the host at 1.6 GB/s two in flight, 2.9 GB/s at eight).
+_DTOH_LOOKAHEAD_REQS = 1
+_DTOH_LOOKAHEAD_BYTES = 256 * 1024 * 1024
 # Staging/consume threads do memory-bandwidth work (memcpy, CRC,
 # deserialize) with the GIL released; more threads than cores only adds
 # GIL ping-pong and context switching (measured on the 1-vCPU dev host:
@@ -451,6 +464,9 @@ class _WritePipeline:
         # Whether a pipelined async take must stage this request before
         # it returns (_WriteScheduler decides; every other mode: all).
         self.in_window = True
+        # Bytes of this request's copy to the host that the scheduler
+        # started and staging has not fetched yet.
+        self.dtoh_unfetched = 0
 
     async def stage(self, executor: ThreadPoolExecutor) -> "_WritePipeline":
         from .io_types import SKIP_WRITE
@@ -564,7 +580,10 @@ class _WriteScheduler:
     the take's blocked-window boundary on the calling thread;
     ``drain`` (via :class:`PendingIOWork`) resumes the SAME loop — on
     the same event loop, possibly from a background thread — until every
-    request is staged AND written. Three modes:
+    request is staged AND written. A request's copy to the host is
+    started when the dispatch reaches it, a fixed depth ahead of the
+    thread that fetches it (``_start_dtoh_ahead``): never for the whole
+    state at once, whatever the mode. Three modes:
 
     - default (sync takes): blocked window = staging complete, staging
       and storage I/O fully overlapped throughout (the metric is total
@@ -679,6 +698,10 @@ class _WriteScheduler:
         # bare countdown would let the blocked window close while an
         # eager (manifest-annotating) stager is still in flight.
         self.eager_pending = {id(p) for p in eager}
+        # The requests not yet asked to start their copy to the host: a
+        # suffix of ``pipelines``, shorter by the lookahead.
+        self._dtoh_ahead = deque(self.pipelines)
+        self.dtoh_unfetched_bytes = 0
         total_cost = sum(p.staging_cost for p in pls)
         released_cost = sum(p.staging_cost for p in released)
         if self.pipelined or prioritize_staging:
@@ -759,6 +782,7 @@ class _WriteScheduler:
             if head.staging_cost > self.budget and in_flight:
                 break  # wait for memory to free up
             self.pipelines.popleft()
+            self._start_dtoh_ahead()
             self.budget -= head.staging_cost
             if self.tele is not None:
                 # High-water mark of budget in use (can exceed the
@@ -770,6 +794,37 @@ class _WriteScheduler:
             self.staging_tasks.add(
                 asyncio.ensure_future(head.stage(self.executor))
             )
+
+    def _start_dtoh_ahead(self) -> None:
+        """Start the copy to the host of the request just dispatched
+        (already off ``pipelines``) and of those that follow it in the
+        queue, ``_DTOH_LOOKAHEAD_REQS`` of them at least and further
+        while the bytes started and not yet staged are under
+        ``_DTOH_LOOKAHEAD_BYTES``. By position in the queue, whether or
+        not the budget admits those requests yet: this is the prefetch,
+        and the depth is what bounds the host copies that the runtime
+        holds outside the budget. With more than one staging thread the
+        depth counts from the last request dispatched."""
+        while self._dtoh_ahead:
+            # -1: the dispatched request itself, not asked before.
+            ahead = len(self.pipelines) - len(self._dtoh_ahead)
+            if (
+                ahead >= _DTOH_LOOKAHEAD_REQS
+                and self.dtoh_unfetched_bytes >= _DTOH_LOOKAHEAD_BYTES
+            ):
+                break
+            pipeline = self._dtoh_ahead.popleft()
+            started = stager_start_dtoh(pipeline.write_req.buffer_stager)
+            if not started:
+                continue
+            pipeline.dtoh_unfetched = started
+            self.dtoh_unfetched_bytes += started
+            if ahead >= 0:
+                telemetry.incr("dtoh.lookahead_starts", rec=self.tele)
+            if self.tele is not None:
+                self.tele.gauge_max(
+                    "dtoh.unfetched_bytes", self.dtoh_unfetched_bytes
+                )
 
     def _staging_budget_starved(self) -> bool:
         return (
@@ -928,6 +983,7 @@ class _WriteScheduler:
                     # (e.g. cost model overestimates); credit the
                     # difference.
                     self.budget += pipeline.staging_cost - pipeline.buf_size
+                    self.dtoh_unfetched_bytes -= pipeline.dtoh_unfetched
                     self.eager_pending.discard(id(pipeline))
                     # Heartbeat feed: bytes past the staging stage (the
                     # window async_take blocks training on).
