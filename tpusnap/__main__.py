@@ -802,9 +802,59 @@ def _fmt_seconds(s) -> str:
     return f"{s * 1e3:.1f}ms"
 
 
-def _render_trace(args, rollup, summaries, ranks, world_size, label) -> int:
+def _render_holder(rank, table, label) -> None:
+    """The table "while the take drained" (a restore: "while the restore
+    ran") of one rank: what `telemetry.HolderWatch` sampled of the thread
+    that called the operation and of the thread that ran its event loop,
+    and how late the loop was for requests that had finished."""
+    drained = "drained" if label == "take" else "ran"
+    print(f"\nwhile the {label} {drained} (rank {rank}):")
+    for track, who in (("caller", "the caller's thread"), ("loop", "the loop's thread")):
+        rows = table.get(track) or {}
+        if not rows:
+            continue
+        print(f"  {who}:")
+        for cls, row in sorted(rows.items(), key=lambda kv: -kv[1]["seconds"]):
+            sites = ", ".join(
+                f"{site} {_fmt_seconds(sec)}" for site, sec in row["sites"]
+            )
+            print(
+                f"    {cls:<12s} {_fmt_seconds(row['seconds']):>10s}"
+                + (f"  at {sites}" if sites else "")
+            )
+    resumed = table.get("resumed") or {}
+    if resumed:
+        print("  finished requests waiting for the loop (summed over requests):")
+        for name, sec in sorted(resumed.items()):
+            print(f"    {name:<18s} {_fmt_seconds(sec):>10s}")
+    counters = table.get("counters") or {}
+    for track in ("caller", "loop"):
+        cpu, runq = counters.get(f"{track}.cpu_us"), counters.get(f"{track}.runq_us")
+        if cpu is not None or runq is not None:
+            print(
+                f"  {track}: on a CPU {_fmt_seconds(None if cpu is None else cpu / 1e6)}, "
+                f"runnable without one {_fmt_seconds(None if runq is None else runq / 1e6)}"
+            )
+    if counters.get("watch.samples"):
+        late_max = (table.get("gauges") or {}).get("watch.late_max_us")
+        print(
+            f"  watch: {counters['watch.samples']} ticks, late by "
+            f"{_fmt_seconds(counters.get('watch.late_us', 0) / 1e6)} in all"
+            + (f" (worst {_fmt_seconds(late_max / 1e6)})" if late_max else "")
+        )
+    if counters.get("take.process_cpu_us"):
+        print(
+            "  process CPU over the take: "
+            f"{_fmt_seconds(counters['take.process_cpu_us'] / 1e6)}"
+        )
+
+
+def _render_trace(
+    args, rollup, summaries, ranks, world_size, label, holders=None
+) -> int:
     import json as _json
 
+    holders = {r: t for r, t in (holders or {}).items() if t}
     if args.json:
         print(
             _json.dumps(
@@ -814,6 +864,7 @@ def _render_trace(args, rollup, summaries, ranks, world_size, label) -> int:
                     "world_size": world_size,
                     "rollup": rollup,
                     "ranks": {str(r): s for r, s in sorted(summaries.items())},
+                    "holder": {str(r): t for r, t in sorted(holders.items())},
                 }
             )
         )
@@ -894,6 +945,9 @@ def _render_trace(args, rollup, summaries, ranks, world_size, label) -> int:
                 f"{_fmt_seconds(agg.get('p50_s')):>10s} "
                 f"{_fmt_seconds(agg.get('max_s')):>10s}"
             )
+    for rank, table in sorted(holders.items()):
+        if args.rank is None or args.rank == rank:
+            _render_holder(rank, table, label)
     return 0
 
 
@@ -947,7 +1001,13 @@ _NO_TELEMETRY_MSG = (
 
 
 def cmd_trace(args) -> int:
-    from .telemetry import rollup_summaries
+    from .telemetry import holder_table, rollup_summaries
+
+    def holders(docs):
+        return {
+            r: holder_table(d.get("traceEvents") or [], d.get("summary") or {})
+            for r, d in docs.items()
+        }
 
     if args.restore:
         docs = _load_restore_docs(args.path)
@@ -956,7 +1016,7 @@ def cmd_trace(args) -> int:
         summaries = {r: d.get("summary") or {} for r, d in docs.items()}
         rollup = rollup_summaries(list(summaries.values()))
         return _render_trace(
-            args, rollup, summaries, sorted(docs), len(docs), "restore"
+            args, rollup, summaries, sorted(docs), len(docs), "restore", holders(docs)
         )
 
     world_size, rollup, ranks = _load_take_traces(args.path)
@@ -974,7 +1034,7 @@ def cmd_trace(args) -> int:
         print(_NO_TELEMETRY_MSG, file=sys.stderr)
         return 3
     return _render_trace(
-        args, rollup, summaries, sorted(ranks), world_size, "take"
+        args, rollup, summaries, sorted(ranks), world_size, "take", holders(ranks)
     )
 
 

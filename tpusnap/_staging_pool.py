@@ -67,7 +67,6 @@ def acquire(nbytes: int) -> np.ndarray:
                 _outstanding[id(buf)] = weakref.ref(buf)
                 telemetry.incr("staging_pool.hits")
                 return buf
-    telemetry.incr("staging_pool.misses")
     buf = _native.aligned_empty(nbytes)
     with _lock:
         _outstanding[id(buf)] = weakref.ref(buf)

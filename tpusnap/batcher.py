@@ -217,11 +217,10 @@ class DeviceBatchedBufferStager(BufferStager):
         self.total = sum(n for _, n, _ in members)
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
-        loop = asyncio.get_running_loop()
         try:
             if executor is not None:
-                return await loop.run_in_executor(
-                    executor, telemetry.handoff("stage", self._stage_blocking)
+                return await telemetry.run_handoff(
+                    executor, "stage", self._stage_blocking
                 )
             return self._stage_blocking()
         except DonatedBeforeStagedError:

@@ -26,12 +26,11 @@ Differences by design:
 
 from __future__ import annotations
 
-import asyncio
 import logging
 import math
 import time
 from concurrent.futures import Executor
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -224,11 +223,8 @@ class ArrayBufferStager(BufferStager):
             )
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            return await loop.run_in_executor(
-                executor, telemetry.handoff("stage", self._stage_blocking)
-            )
+            return await telemetry.run_handoff(executor, "stage", self._stage_blocking)
         return self._stage_blocking()
 
     def _stage_blocking(self) -> BufferType:
@@ -1163,11 +1159,8 @@ class ArrayBufferConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(
-                executor, _consume_handoff(self._consume_blocking), buf
-            )
+            await _consume_handoff(executor, self._consume_blocking, buf)
         else:
             self._consume_blocking(buf)
 
@@ -1181,12 +1174,13 @@ class ArrayBufferConsumer(BufferConsumer):
         return tensor_nbytes(self.entry.dtype, self.entry.shape)
 
 
-def _consume_handoff(fn: Callable) -> Callable:
-    """A consumer's blocking body, wrapped for the consume executor:
-    the wait for a consume thread is ``consume.queued``; the body records
-    its own ``decode`` (checksum verify, decompress, copy into a host
-    target) and ``htod`` (``device_put`` into a jax target) spans."""
-    return telemetry.handoff("consume", fn, work=False)
+async def _consume_handoff(executor: Executor, fn: Callable, *args: Any) -> Any:
+    """A consumer's blocking body on the consume executor: the wait for
+    a consume thread is ``consume.queued``; the body records its own
+    ``decode`` (checksum verify, decompress, copy into a host target) and
+    ``htod`` (``device_put`` into a jax target) spans; how late the loop
+    was afterwards is ``consume.resumed``."""
+    return await telemetry.run_handoff(executor, "consume", fn, *args, work=False)
 
 
 def _maybe_verify(buf: BufferType, checksum: Optional[str], location: str) -> None:
@@ -1644,14 +1638,9 @@ class _CompressedConsumer(BufferConsumer):
 
     async def consume_read_io(self, read_io, executor: Optional[Executor] = None) -> None:
         buf = read_io.buf.getbuffer()
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(
-                executor,
-                _consume_handoff(self._consume_blocking),
-                buf,
-                read_io.crc32c,
-                read_io.crc_algo,
+            await _consume_handoff(
+                executor, self._consume_blocking, buf, read_io.crc32c, read_io.crc_algo
             )
         else:
             self._consume_blocking(buf, read_io.crc32c, read_io.crc_algo)
@@ -1660,11 +1649,8 @@ class _CompressedConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(
-                executor, _consume_handoff(self._consume_blocking), buf, None, None
-            )
+            await _consume_handoff(executor, self._consume_blocking, buf, None, None)
         else:
             self._consume_blocking(buf, None, None)
         await self._after_consume(executor)
@@ -1737,13 +1723,8 @@ class _CompressedConsumer(BufferConsumer):
             self.fut.obj = self.host_out
             return
         if executor is not None:
-            loop = asyncio.get_running_loop()
-            self.fut.obj = await loop.run_in_executor(
-                executor,
-                _consume_handoff(finalize_into_target),
-                self.host_out,
-                self.obj_out,
-                True,
+            self.fut.obj = await _consume_handoff(
+                executor, finalize_into_target, self.host_out, self.obj_out, True
             )
         else:
             self.fut.obj = finalize_into_target(
@@ -1812,11 +1793,8 @@ class _TileConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
-        loop = asyncio.get_running_loop()
         if executor is not None:
-            await loop.run_in_executor(
-                executor, _consume_handoff(self._consume_blocking), buf
-            )
+            await _consume_handoff(executor, self._consume_blocking, buf)
         else:
             self._consume_blocking(buf)
         await self._after_consume(executor)
@@ -1836,13 +1814,8 @@ class _TileConsumer(BufferConsumer):
         # mismatched-dtype target) — run it in the executor so the
         # event loop keeps dispatching other entries' reads.
         if executor is not None:
-            loop = asyncio.get_running_loop()
-            self.fut.obj = await loop.run_in_executor(
-                executor,
-                _consume_handoff(finalize_into_target),
-                self.host_out,
-                self.obj_out,
-                True,
+            self.fut.obj = await _consume_handoff(
+                executor, finalize_into_target, self.host_out, self.obj_out, True
             )
         else:
             self.fut.obj = finalize_into_target(

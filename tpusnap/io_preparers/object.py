@@ -11,7 +11,6 @@ scheduler's memory budget relies on.
 
 from __future__ import annotations
 
-import asyncio
 from concurrent.futures import Executor
 from typing import Any, List, Optional, Tuple
 
@@ -59,11 +58,8 @@ class ObjectBufferConsumer(BufferConsumer):
 
         _maybe_verify(buf, self.checksum, self.location)
         if executor is not None:
-            loop = asyncio.get_running_loop()
-            self.fut.obj = await loop.run_in_executor(
-                executor,
-                telemetry.handoff("consume", pickle_from_bytes, work="decode"),
-                bytes(buf),
+            self.fut.obj = await telemetry.run_handoff(
+                executor, "consume", pickle_from_bytes, bytes(buf), work="decode"
             )
         else:
             self.fut.obj = pickle_from_bytes(bytes(buf))

@@ -443,13 +443,10 @@ class _ScatterConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
-        loop = asyncio.get_running_loop()
         if executor is not None:
             from .array import _consume_handoff
 
-            await loop.run_in_executor(
-                executor, _consume_handoff(self._scatter), buf
-            )
+            await _consume_handoff(executor, self._scatter, buf)
         else:
             self._scatter(buf)
         # Assembly bookkeeping stays on the event-loop thread: no races.

@@ -463,6 +463,9 @@ def run_on_loop(event_loop: asyncio.AbstractEventLoop, coro):
     resources) the next ``run_until_complete`` would resume it —
     writing into the previous call's buffers. Cancel and drain the
     top-level task before re-raising."""
+    from . import telemetry  # imports this module
+
+    telemetry.note_loop_thread()  # a watched operation samples this thread
     task = event_loop.create_task(coro) if asyncio.iscoroutine(coro) else coro
     try:
         return event_loop.run_until_complete(task)

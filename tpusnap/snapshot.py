@@ -635,7 +635,7 @@ class Snapshot:
         return PendingRestore(self, app_state, comm, memory_budget)
 
     def _restore_locked(
-        self, app_state, comm, per_key_barrier, memory_budget=None
+        self, app_state, comm, per_key_barrier, memory_budget=None, caller=None
     ) -> None:
         # Restore telemetry: a dedicated recorder (thread-local overlay,
         # so an in-flight take's global recorder is never disturbed)
@@ -644,7 +644,11 @@ class Snapshot:
         # spans. The snapshot is immutable, so the trace persists to
         # the LOCAL trace dir (TPUSNAP_TELEMETRY_DIR) — rendered by
         # `python -m tpusnap trace --restore <path>`.
-        tele = telemetry.begin_restore(comm.rank)
+        # `caller`: the thread that called async_restore, where this is
+        # its background thread; a watched restore samples it, and the
+        # thread that runs the loop, from here on.
+        tele = telemetry.begin_restore(comm.rank, caller=caller)
+        tele.watch_begin()
         tele.meta.update(path=self.path, world_size=comm.world_size)
         mark = telemetry.PhaseMarker(rec=tele, from_start=True)
         mark.begin("restore.plan")
@@ -681,7 +685,7 @@ class Snapshot:
             from .knobs import clear_tuned_plan
 
             clear_tuned_plan()
-            tele.finalize()
+            tele.close()
             summary = tele.summary()
             telemetry.publish_restore_summary(summary)
             if tele.enabled:
@@ -3107,6 +3111,9 @@ class PendingSnapshot(_BackgroundWork):
             tele.meta["async_blocked_s"] = round(blocked_s, 6)
             tele.record_span("async_blocked", 0.0, blocked_s)
             telemetry.release_global(tele)
+            # From here to the take's end a watched take samples what
+            # holds this thread, the caller's, and the drain's loop.
+            tele.watch_begin()
         self._start()
 
     def _body(self) -> None:
@@ -3404,6 +3411,7 @@ class PendingRestore(_BackgroundWork):
         self._app_state = app_state
         self._comm = comm
         self._memory_budget = memory_budget
+        self._caller = telemetry.thread_key()
         self._start()
 
     def _body(self) -> None:
@@ -3413,6 +3421,7 @@ class PendingRestore(_BackgroundWork):
                 self._comm,
                 per_key_barrier=False,
                 memory_budget=self._memory_budget,
+                caller=self._caller,
             )
 
     def wait(self) -> None:

@@ -76,13 +76,10 @@ class FSStoragePlugin(StoragePlugin):
             # transfer and avoids aiofiles' per-chunk hop overhead. Also the
             # small-write path when aiofiles is not installed. The hand-off
             # records `write.queued` (the wait for one of the 8 threads)
-            # and `write.work` (the write) on that thread.
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(
-                self._get_executor(),
-                telemetry.handoff("write", _write_file, bytes=len(buf)),
-                path,
-                buf,
+            # and `write.work` (the write) on that thread, and
+            # `write.resumed` (the loop's lateness afterwards) here.
+            await telemetry.run_handoff(
+                self._get_executor(), "write", _write_file, path, buf, bytes=len(buf)
             )
         else:
             async with aiofiles.open(path, "wb") as f:
@@ -96,12 +93,10 @@ class FSStoragePlugin(StoragePlugin):
             # handled at commit time (write_atomic fsyncs every
             # directory this plugin created).
             # A second trip through the same executor: its queue wait is
-            # one more `write.queued`, the fsync itself `write.fsync`.
-            loop = asyncio.get_running_loop()
-            await loop.run_in_executor(
-                self._get_executor(),
-                telemetry.handoff("write", _fsync_path, work="write.fsync"),
-                str(path),
+            # one more `write.queued`, the fsync itself `write.fsync`,
+            # the loop's lateness after it one more `write.resumed`.
+            await telemetry.run_handoff(
+                self._get_executor(), "write", _fsync_path, str(path), work="write.fsync"
             )
 
     async def write_atomic(self, write_io: WriteIO, durable: bool = False) -> None:
@@ -168,9 +163,8 @@ class FSStoragePlugin(StoragePlugin):
                         f.seek(offset)
                     return f.read(n)
 
-            loop = asyncio.get_running_loop()
-            data = await loop.run_in_executor(
-                self._get_executor(), telemetry.handoff("read", work, bytes=n)
+            data = await telemetry.run_handoff(
+                self._get_executor(), "read", work, bytes=n
             )
             read_io.buf = io.BytesIO(data)
             return
@@ -193,8 +187,8 @@ class FSStoragePlugin(StoragePlugin):
                 path, offset, n, dst, want_crc=read_io.want_crc
             )
 
-        got, crc, algo = await self._submit_tracked(
-            self._get_executor(), telemetry.handoff("read", work, bytes=n)
+        got, crc, algo = await telemetry.run_handoff(
+            self._get_executor(), "read", work, submit=self._submit_tracked, bytes=n
         )
         if got != n:
             raise IOError(
@@ -235,8 +229,8 @@ class FSStoragePlugin(StoragePlugin):
             got = _read_range(path, offset, n, arr.data)
             return arr, got, None, None
 
-        arr, got, crc, algo = await self._submit_tracked(
-            self._get_executor(), telemetry.handoff("read", work, bytes=n)
+        arr, got, crc, algo = await telemetry.run_handoff(
+            self._get_executor(), "read", work, submit=self._submit_tracked, bytes=n
         )
         if want_crc and got == n:
             read_io.crc32c = crc

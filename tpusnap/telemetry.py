@@ -9,9 +9,26 @@ rank. This module is that instrument:
   stage (flatten, the G1 plan gather, prepare, staging, checksum
   passes, storage writes, budget waits, barriers/KV waits). Span
   capture is gated by the ``TPUSNAP_TELEMETRY`` knob (on by default;
-  the disabled path is a single dict lookup + ``None`` check).
+  the disabled path is a single dict lookup + ``None`` check, and
+  starts no thread).
+- **Background threads of a take or restore whose spans are on** — two,
+  and no more whatever is registered: ``tpusnap-rss`` (this module's
+  :class:`~tpusnap.rss_profiler.RSSSampler`, the process's RSS every
+  100 ms) and, in a take, ``tpusnap-progress`` (:mod:`tpusnap.progress`,
+  the heartbeat, at ``TPUSNAP_HEARTBEAT_S``). Where a ``MetricsSink``
+  was registered when the operation began, ``tpusnap-rss`` also carries
+  the :class:`HolderWatch` and wakes every 5 ms from ``async_take``'s
+  return (a restore's start) to the operation's end; RSS is read every
+  100 ms still.
+- **The holder** — what kept the two threads tpusnap does not own the
+  time of from running: the thread that called ``async_take`` /
+  ``restore`` and the thread that runs the operation's event loop,
+  sampled (:class:`HolderWatch`: ``caller.<class>`` / ``loop.<class>``
+  spans, the ``caller.*`` / ``loop.*`` / ``watch.*`` counters), and the
+  loop's lateness per request, measured (``<name>.resumed``, see
+  :func:`run_handoff`).
 - **Counters** — atomic, ALWAYS-ON (knob-independent): retry attempts
-  per classification, injected faults, staging-pool hits/misses, bytes
+  per classification, injected faults, staging-pool hits, bytes
   written, dedup skips. Cheap enough for the hot path (one lock'd
   ``dict`` add).
 - **Gauges** — high-water marks (scheduler budget in use, peak RSS
@@ -52,11 +69,14 @@ must never fail a take.
 
 from __future__ import annotations
 
+import asyncio
 import contextvars
 import itertools
 import json
 import logging
 import math
+import os
+import sys
 import threading
 import time
 from contextlib import contextmanager, nullcontext
@@ -152,19 +172,21 @@ _ambient: "contextvars.ContextVar[Optional[Tuple[TakeTelemetry, int]]]" = (
 class OpenSpan:
     """Handle of a span that is still open: its ``id`` (what children
     name as their parent) and its ``attrs``, which the body may add to
-    before the span closes."""
+    before the span closes; ``end`` is the record's own end once it has
+    closed."""
 
-    __slots__ = ("id", "attrs")
+    __slots__ = ("id", "attrs", "end")
 
     def __init__(self, span_id: int, attrs: Dict[str, Any]) -> None:
         self.id = span_id
         self.attrs = attrs
+        self.end: Optional[float] = None
 
 
 _trace_me: Any = None  # jax.profiler.TraceAnnotation, False if unavailable
 
 
-def _annotate(name: str, op: str) -> Any:
+def _annotate(name: str, op: str, prefix: str = "tpusnap:") -> Any:
     """Open ``tpusnap:<name>`` in the profiler's own trace (a TraceMe on
     the calling thread) and return it, or None without jax. With no
     profile running this is one atomic load inside TraceMe (~0.6 us)."""
@@ -178,7 +200,7 @@ def _annotate(name: str, op: str) -> Any:
             _trace_me = False
     if not _trace_me:
         return None
-    ann = _trace_me(f"tpusnap:{name}", op=op)
+    ann = _trace_me(f"{prefix}{name}", op=op)
     ann.__enter__()
     return ann
 
@@ -225,6 +247,9 @@ class MetricsSink:
 
 
 _sinks: Tuple[MetricsSink, ...] = ()
+# Beside each sink, the callback a span goes to, looked up once when the
+# sink is registered: ``on_span_record`` where its class defines one.
+_span_sinks: Tuple[Tuple[MetricsSink, str], ...] = ()
 _sinks_lock = threading.Lock()
 # (sink class name, callback name) pairs already warned about since the
 # last take/restore began — a broken exporter logs ONE rate-limited
@@ -238,16 +263,28 @@ def _reset_sink_warnings() -> None:
         _sink_warned.clear()
 
 
+def _span_callback(sink: MetricsSink) -> str:
+    """A sink whose class defines ``on_span_record`` gets the record;
+    any other (a ``MetricsSink`` that overrides only ``on_span``, or a
+    duck-typed sink written before records existed) gets ``on_span``."""
+    handler = getattr(type(sink), "on_span_record", None)
+    if handler is None or handler is MetricsSink.on_span_record:
+        return "on_span"
+    return "on_span_record"
+
+
 def register_metrics_sink(sink: MetricsSink) -> None:
-    global _sinks
+    global _sinks, _span_sinks
     with _sinks_lock:
         _sinks = _sinks + (sink,)
+        _span_sinks = _span_sinks + ((sink, _span_callback(sink)),)
 
 
 def unregister_metrics_sink(sink: MetricsSink) -> None:
-    global _sinks
+    global _sinks, _span_sinks
     with _sinks_lock:
         _sinks = tuple(s for s in _sinks if s is not sink)
+        _span_sinks = tuple(p for p in _span_sinks if p[0] is not sink)
 
 
 @contextmanager
@@ -293,12 +330,8 @@ def _notify_one(sink: MetricsSink, method: str, *args) -> None:
 
 
 def _notify_span(record: SpanRecord) -> None:
-    """A sink whose class defines ``on_span_record`` gets the record;
-    any other (a ``MetricsSink`` that overrides only ``on_span``, or a
-    duck-typed sink written before records existed) gets ``on_span``."""
-    for sink in _sinks:
-        handler = getattr(type(sink), "on_span_record", None)
-        if handler is None or handler is MetricsSink.on_span_record:
+    for sink, callback in _span_sinks:
+        if callback == "on_span":
             _notify_one(
                 sink, "on_span", record.name, record.duration_s, record.attrs
             )
@@ -579,8 +612,15 @@ class TakeTelemetry:
     start on that clock."""
 
     def __init__(
-        self, rank: int, enabled: Optional[bool] = None, kind: str = "take"
+        self,
+        rank: int,
+        enabled: Optional[bool] = None,
+        kind: str = "take",
+        caller: Optional[Tuple[int, int, Optional[int]]] = None,
     ) -> None:
+        """``caller`` is the :func:`thread_key` of the thread whose call
+        this operation is, where that is not the constructing thread
+        (``async_restore``'s)."""
         self.rank = rank
         self.enabled = is_telemetry_enabled() if enabled is None else enabled
         # One identifier for every span of this take or restore.
@@ -614,14 +654,19 @@ class TakeTelemetry:
         self._inflight: Dict[object, Tuple[str, str]] = {}
         self._last_phase: Optional[str] = None
         self._rss_sampler = None
+        # The holder watch rides the RSS sampler's thread, and only
+        # where a sink was registered when the operation began.
+        self._watch: Optional[HolderWatch] = None
         if self.enabled:
             try:
                 from .rss_profiler import RSSSampler
 
-                self._rss_sampler = RSSSampler(interval_sec=0.1)
+                if _sinks:
+                    self._watch = HolderWatch(self, caller or thread_key())
+                self._rss_sampler = RSSSampler(interval_sec=0.1, rider=self._watch)
                 self._rss_sampler.start()
             except Exception:
-                self._rss_sampler = None
+                self._rss_sampler = self._watch = None
 
     # --- recording ------------------------------------------------------
 
@@ -635,8 +680,10 @@ class TakeTelemetry:
         return ambient[1] if ambient is not None and ambient[0] is self else None
 
     def _keep(self, record: SpanRecord) -> None:
-        with self._lock:
-            self._spans.append(record)
+        # No lock: a list's append is atomic, and so is the copy that the
+        # readers take under the lock. A lock here would be taken by every
+        # recording thread, the event loop's among them, once a span.
+        self._spans.append(record)
         _notify_span(record)
 
     def record_span(
@@ -700,7 +747,7 @@ class TakeTelemetry:
         try:
             yield sp
         finally:
-            end = time.monotonic()
+            end = sp.end = time.monotonic()
             self.op_exit(token)
             if annotation is not None:
                 annotation.__exit__(None, None, None)
@@ -720,6 +767,7 @@ class TakeTelemetry:
         name: str,
         fn: Callable[..., Any],
         work: Union[bool, str] = True,
+        ended: Optional[List[float]] = None,
         **attrs: Any,
     ) -> Callable[..., Any]:
         """Wrap ``fn`` for an executor. Call this where the function is
@@ -730,7 +778,10 @@ class TakeTelemetry:
         for a body that records its own spans. Both are children of the
         span that is open at the submit (the request's span), and so is
         whatever the body records: the recorder and that parent are
-        installed on the worker for the body's length."""
+        installed on the worker for the body's length. ``ended``, a list,
+        is given the instant the body ended, on the worker: the work
+        span's own end (:func:`run_handoff` counts the loop's lateness
+        from it)."""
         if not self.enabled:
             return fn
         parent = self.ambient_parent()
@@ -746,10 +797,13 @@ class TakeTelemetry:
             )
             ambient = _ambient.set((self, parent) if parent is not None else None)
             body = self.span(work_name, **attrs) if work_name else nullcontext()
+            sp = None
             try:
-                with use(self), body:
+                with use(self), body as sp:
                     return fn(*args, **kwargs)
             finally:
+                if ended is not None:
+                    ended.append(sp.end if sp is not None else time.monotonic())
                 _ambient.reset(ambient)
 
         return run
@@ -858,11 +912,22 @@ class TakeTelemetry:
     # --- finalization ---------------------------------------------------
 
     def finalize(self) -> None:
-        """Freeze the take wall-clock and stop the RSS sampler.
+        """Freeze the take wall-clock and stop the RSS sampler (a watched
+        take reads its RSS peak here, hands what the watch has seen so
+        far to the record, and keeps the thread until :meth:`close`).
         Idempotent; spans recorded after this still reach sinks but are
         not part of the persisted trace's coverage window."""
         if self._finalized_wall_s is not None:
             return
+        if self._watch is not None and self._watch.period_s is not None:
+            try:
+                self._watch.flush()
+                self._rss_sampler.sample()
+                self.gauge_max(
+                    "peak_rss_delta_bytes", float(self._rss_sampler.peak_delta)
+                )
+            except Exception:
+                pass
         self._finalized_wall_s = self.now()
         annotation, self._phase_annotation = self._phase_annotation, None
         if annotation is not None:
@@ -870,15 +935,40 @@ class TakeTelemetry:
         ambient = _ambient.get()
         if ambient is not None and ambient[0] is self:
             _ambient.set(None)  # a phase that a failure left begun
-        if self._rss_sampler is not None:
+        if self._watch is None or self._watch.period_s is None:
+            self._stop_sampler()
+
+    def _stop_sampler(self) -> None:
+        sampler, self._rss_sampler = self._rss_sampler, None
+        if sampler is not None:
             try:
-                self._rss_sampler.stop()
-                self.gauge_max(
-                    "peak_rss_delta_bytes", float(self._rss_sampler.peak_delta)
-                )
+                sampler.stop()  # a watch's last spans land on its thread here
+                self.gauge_max("peak_rss_delta_bytes", float(sampler.peak_delta))
             except Exception:
                 pass
-            self._rss_sampler = None
+
+    def watch_begin(self) -> None:
+        """From here to :meth:`close` the watch samples the caller's and
+        the loop's thread (``async_take`` calls this as it returns, a
+        restore as it starts). Nothing without a watch."""
+        if self._watch is not None and self._rss_sampler is not None:
+            self._watch.begin()
+            self._rss_sampler.poke()
+
+    def note_loop_thread(self) -> None:
+        """The calling thread is about to run this operation's event
+        loop."""
+        if self._watch is not None and (
+            self._watch.loop is None or self._watch.loop[0] != threading.get_ident()
+        ):
+            self._watch.loop = thread_key()
+
+    def close(self) -> None:
+        """The operation's end: the end of the sampler's thread, where a
+        watch kept it past :meth:`finalize` (its last spans are recorded
+        as it ends), and :meth:`finalize`."""
+        self._stop_sampler()
+        self.finalize()
 
     @property
     def take_wall_s(self) -> float:
@@ -1027,6 +1117,389 @@ class TakeTelemetry:
         )
 
 
+# -------------------------------------------------------------- holder
+
+WATCH_PERIOD_S = 0.005
+
+# What a sampled frame's code object is, looked up once a code object:
+# this package's; the user's (anything outside the standard library and
+# the installed packages); an installed package's other than jax; the
+# standard library's or jax's, with four functions of them told apart.
+(_F_USER, _F_TPUSNAP, _F_THIRD, _F_LIB, _F_WAIT, _F_PUT, _F_SELECT, _F_RUN_ONCE) = range(8)
+_PKG_DIR = os.path.dirname(os.path.abspath(__file__)) + os.sep
+_STDLIB_DIR = os.path.dirname(os.path.abspath(os.__file__)) + os.sep
+# The functions of jax in which a thread waits for the device to finish,
+# and the one under which it hands the device an array.
+_JAX_WAITS = frozenset({"block_until_ready", "device_get", "_value"})
+_JAX_PUTS = frozenset({"device_put"})
+_frame_kinds: Dict[Any, int] = {}
+
+
+def _frame_kind(code: Any) -> int:
+    kind = _frame_kinds.get(code)
+    if kind is None:
+        path, name = code.co_filename, code.co_name
+        parts = path.split(os.sep)
+        if path.startswith(_PKG_DIR):
+            kind = _F_TPUSNAP
+        elif "jax" in parts or "jaxlib" in parts:
+            kind = _F_WAIT if name in _JAX_WAITS else _F_PUT if name in _JAX_PUTS else _F_LIB
+        elif "site-packages" in parts or "dist-packages" in parts:
+            kind = _F_THIRD
+        elif path.startswith("<") or path.startswith(_STDLIB_DIR):
+            kind = (
+                _F_SELECT if name == "select" and parts[-1] == "selectors.py"
+                else _F_RUN_ONCE if name == "_run_once" and parts[-1] == "base_events.py"
+                else _F_LIB
+            )
+        else:
+            kind = _F_USER
+        _frame_kinds[code] = kind
+    return kind
+
+
+def classify_caller(frame: Any) -> Tuple[str, Any]:
+    """``(class, (code, line))`` of the calling thread's stack, innermost
+    frame first: ``tpusnap`` where the innermost frame outside jax, the
+    standard library and the installed packages is this package's
+    (``wait_staged``, ``wait``, ``done``, a second ``async_take``); else
+    ``wait_device`` under a ``block_until_ready`` (``device_get``, an
+    array's ``_value``) of jax, ``transfer`` under a ``device_put`` of
+    jax; else ``other``: the user's code, and a jitted call's dispatch,
+    which has no Python frame. The site is that innermost frame (an
+    installed package's where the stack holds no other)."""
+    under, fallback, depth = None, None, 0
+    top = frame
+    while frame is not None and depth < 64:
+        kind = _frame_kind(frame.f_code)
+        if kind == _F_TPUSNAP:
+            return "tpusnap", (frame.f_code, frame.f_lineno)
+        if kind == _F_USER:
+            return under or "other", (frame.f_code, frame.f_lineno)
+        if kind == _F_THIRD and fallback is None:
+            fallback = (frame.f_code, frame.f_lineno)
+        elif under is None and kind == _F_WAIT:
+            under = "wait_device"
+        elif under is None and kind == _F_PUT:
+            under = "transfer"
+        frame, depth = frame.f_back, depth + 1
+    return under or "other", fallback or (top.f_code, top.f_lineno)
+
+
+def classify_loop(frame: Any) -> Tuple[Optional[str], Any]:
+    """``idle`` where the loop's thread stands in the selector,
+    ``callback`` where it runs anything else under the loop (its site:
+    the innermost frame outside the standard library and jax), None where the
+    thread is not running a loop at all."""
+    if _frame_kind(frame.f_code) == _F_SELECT:
+        return "idle", None
+    site, depth = None, 0
+    while frame is not None and depth < 64:
+        kind = _frame_kind(frame.f_code)
+        if kind == _F_RUN_ONCE:
+            return "callback", site
+        if site is None and kind in (_F_USER, _F_TPUSNAP, _F_THIRD):
+            site = (frame.f_code, frame.f_lineno)
+        frame, depth = frame.f_back, depth + 1
+    return None, None
+
+
+def _site_name(site: Any) -> Optional[str]:
+    if site is None:
+        return None
+    code, line = site
+    return f"{os.path.basename(code.co_filename)}:{code.co_name}:{line}"
+
+
+def thread_key() -> Tuple[int, int, Optional[int]]:
+    """The calling thread as a watch follows it: its ``threading`` ident,
+    its native id, and the id of its CPU-time clock (taken by the thread
+    itself, while it certainly lives), None where the platform has none."""
+    ident = threading.get_ident()
+    try:
+        clock: Optional[int] = time.pthread_getcpuclockid(ident)
+    except Exception:
+        clock = None
+    return ident, threading.get_native_id(), clock
+
+
+class _ThreadClocks:
+    """One thread's nanoseconds on a CPU (its CPU-time clock) and
+    nanoseconds runnable and waiting for one (the second field of
+    ``/proc/self/task/<tid>/schedstat``, kept open). ``read`` gives None
+    for what the platform does not give: a sandboxed kernel may have no
+    schedstat (the chip machine's has none), and then there is no
+    run-queue reading."""
+
+    def __init__(self, native_id: int, cpu_clock: Optional[int]) -> None:
+        self.cpu_clock = cpu_clock
+        try:
+            self.fd: Optional[int] = os.open(
+                f"/proc/self/task/{native_id}/schedstat", os.O_RDONLY
+            )
+        except OSError:
+            self.fd = None
+
+    def read(self) -> Tuple[Optional[int], Optional[int]]:
+        cpu = runq = None
+        if self.cpu_clock is not None:
+            try:
+                cpu = time.clock_gettime_ns(self.cpu_clock)
+            except OSError:  # the thread is gone
+                self.cpu_clock = None
+        if self.fd is not None:
+            try:
+                runq = int(os.pread(self.fd, 96, 0).split()[1])
+            except (OSError, ValueError, IndexError):
+                self.close()
+        return cpu, runq
+
+    def close(self) -> None:
+        fd, self.fd = self.fd, None
+        if fd is not None:
+            try:
+                os.close(fd)
+            except OSError:
+                pass
+
+
+class _Track:
+    """One watched thread's open span: its class, since when, how many
+    ticks saw it, at which sites, and the thread's clocks at its start."""
+
+    def __init__(
+        self, name: str, ident: int, native_id: int, cpu_clock: Optional[int],
+        start: float,
+    ) -> None:
+        self.name, self.ident, self.native_id = name, ident, native_id
+        self.cls: Optional[str] = None
+        self.seen = False
+        self.start = start
+        self.samples = 0
+        self.sites: Dict[Any, int] = {}
+        self.clocks = _ThreadClocks(native_id, cpu_clock)
+        self.clocks_last = self.clocks_start = self.clocks.read()
+        self.carry_ns = [0, 0]  # what a counter in microseconds left over
+        self.annotation: Any = None
+
+
+class HolderWatch:
+    """What kept the thread that called ``async_take`` / ``restore``, and
+    the thread that runs the operation's event loop, from running: both
+    sampled every :data:`WATCH_PERIOD_S` from :meth:`begin` to :meth:`end`
+    on the RSS sampler's thread (``RSSSampler``'s ``rider``), which this
+    operation has anyway. Only an operation that began with a
+    ``MetricsSink`` registered has one.
+
+    A tick takes ``sys._current_frames()`` once and classes the two
+    threads' stacks (:func:`classify_caller`, :func:`classify_loop`).
+    Consecutive ticks of one class are one span ``caller.<class>`` /
+    ``loop.<class>`` (kind ``wait``; thread ``caller`` / ``loop``),
+    recorded when the class changes: attrs ``site`` (``file:function:line``
+    of the frame most seen), ``samples``, ``thread`` and, where the kernel
+    has them, ``cpu_ms`` / ``runq_ms`` of that thread over the span (its
+    CPU-time clock; schedstat). While a caller class lasts this thread
+    holds open the profiler annotation ``tpusnap-caller:<class>``.
+    Counters a tick: ``caller.cpu_us`` / ``loop.cpu_us`` and
+    ``caller.runq_us`` / ``loop.runq_us`` (the same clocks' growth; a
+    counter the platform cannot feed is absent), ``watch.samples``,
+    ``watch.late_us`` (how far the tick overshot its period: what any
+    Python thread pays here to get the lock and a core back; gauge
+    ``watch.late_max_us``), ``watch.tick_us`` (the tick's own wall time:
+    some 50 us of Python, and more where the thread lost the lock or its
+    core inside it). At the end: ``watch.cpu_us`` (this thread's
+    own CPU time while it watched) and, of a take,
+    ``take.process_cpu_us`` (user + system time of the whole process from
+    the take's start to its end)."""
+
+    def __init__(
+        self, rec: "TakeTelemetry", caller: Tuple[int, int, Optional[int]]
+    ) -> None:
+        self.rec = rec
+        self.caller = caller  # as thread_key() gives it
+        self.loop: Optional[Tuple[int, int, Optional[int]]] = None  # the drain says
+        self.period_s: Optional[float] = None  # None: not watching
+        self._lock = threading.Lock()  # a tick against flush()
+        self._tracks: Dict[str, _Track] = {}
+        self._thread_cpu0: Optional[float] = None
+        self._rusage0 = self._process_cpu_s()
+
+    @staticmethod
+    def _process_cpu_s() -> Optional[float]:
+        try:
+            import resource
+
+            usage = resource.getrusage(resource.RUSAGE_SELF)
+            return usage.ru_utime + usage.ru_stime
+        except Exception:
+            return None
+
+    def begin(self) -> None:
+        with self._lock:
+            self._tracks["caller"] = _Track("caller", *self.caller, time.monotonic())
+            self.period_s = WATCH_PERIOD_S
+
+    # --- on the sampler's thread ----------------------------------------
+
+    def tick(self, now: float, late_s: float) -> None:
+        try:
+            self._tick(now, late_s)
+        except Exception:  # telemetry never fails a take: stop watching
+            self.period_s = None
+            logger.debug("holder watch stopped", exc_info=True)
+
+    def _tick(self, now: float, late_s: float) -> None:
+        frames = sys._current_frames()
+        with self._lock:
+            if self._thread_cpu0 is None:
+                self._thread_cpu0 = time.thread_time()
+            loop = self.loop
+            track = self._tracks.get("loop")
+            if loop is not None and (track is None or track.ident != loop[0]):
+                if track is not None:
+                    self._switch(track, now, None)
+                    track.clocks.close()
+                self._tracks["loop"] = _Track("loop", *loop, now)
+            for track in self._tracks.values():
+                frame = frames.get(track.ident)
+                if frame is None:
+                    cls, site = None, None
+                elif track.name == "caller":
+                    cls, site = classify_caller(frame)
+                else:
+                    cls, site = classify_loop(frame)
+                if cls != track.cls:
+                    self._switch(track, now, cls)
+                if cls is not None:
+                    track.samples += 1
+                    if site is not None:
+                        track.sites[site] = track.sites.get(site, 0) + 1
+                self._count_clocks(track)
+        del frames
+        late_us = int(late_s * 1e6)
+        rec = self.rec
+        incr("watch.samples", rec=rec)
+        if late_us:
+            incr("watch.late_us", late_us, rec=rec)
+            rec.gauge_max("watch.late_max_us", late_us)
+        incr("watch.tick_us", int((time.monotonic() - now) * 1e6), rec=rec)
+
+    def _count_clocks(self, track: _Track) -> None:
+        last = track.clocks_last
+        got = track.clocks_last = track.clocks.read()
+        for i, what in enumerate(("cpu_us", "runq_us")):
+            if got[i] is None or last[i] is None:
+                continue
+            ns = got[i] - last[i] + track.carry_ns[i]
+            track.carry_ns[i] = ns % 1000
+            if ns >= 1000:
+                incr(f"{track.name}.{what}", ns // 1000, rec=self.rec)
+
+    def _record(self, track: _Track, now: float) -> None:
+        """The track's open span, as far as it has come; it goes on from
+        now under the same class."""
+        if track.cls is not None and now > track.start:
+            attrs: Dict[str, Any] = {"samples": track.samples, "thread": track.native_id}
+            if track.sites:
+                attrs["site"] = _site_name(max(track.sites, key=track.sites.get))
+            for i, what in enumerate(("cpu_ms", "runq_ms")):
+                if None not in (track.clocks_last[i], track.clocks_start[i]):
+                    attrs[what] = round(
+                        (track.clocks_last[i] - track.clocks_start[i]) / 1e6, 3
+                    )
+            self.rec._keep(
+                SpanRecord(
+                    next(_span_ids), f"{track.name}.{track.cls}", track.start, now,
+                    track.name, WAIT, self.rec.op, None, attrs,
+                )
+            )
+        if track.cls is not None or track.seen:
+            track.start = now  # a track's first span starts where the watch began
+        track.seen = True
+        track.samples, track.sites, track.clocks_start = 0, {}, track.clocks_last
+
+    def _switch(self, track: _Track, now: float, cls: Optional[str]) -> None:
+        self._record(track, now)
+        track.cls = cls
+        if track.name == "caller":
+            if track.annotation is not None:
+                track.annotation.__exit__(None, None, None)
+            track.annotation = (
+                _annotate(cls, self.rec.op, prefix="tpusnap-caller:")
+                if cls is not None
+                else None
+            )
+
+    def end(self) -> None:
+        """The sampler's thread is exiting: close what is open."""
+        with self._lock:
+            watched = self.period_s is not None
+            self.period_s = None
+            now = time.monotonic()
+            for track in self._tracks.values():
+                self._switch(track, now, None)
+                track.clocks.close()
+            self._tracks.clear()
+        if not watched:
+            return
+        rec = self.rec
+        if self._thread_cpu0 is not None:
+            incr(
+                "watch.cpu_us",
+                int((time.thread_time() - self._thread_cpu0) * 1e6),
+                rec=rec,
+            )
+        cpu_s = self._process_cpu_s()
+        if rec.meta.get("kind") == "take" and None not in (cpu_s, self._rusage0):
+            incr("take.process_cpu_us", int((cpu_s - self._rusage0) * 1e6), rec=rec)
+
+    # --- on any thread ----------------------------------------------------
+
+    def flush(self) -> None:
+        """Hand the open spans to the record as far as they have come
+        (a take's trace is persisted before the take ends)."""
+        with self._lock:
+            now = time.monotonic()
+            for track in self._tracks.values():
+                self._record(track, now)
+
+
+def holder_table(trace_events: List[Dict[str, Any]], summary: Dict[str, Any]) -> Dict[str, Any]:
+    """What the watch and :func:`run_handoff` recorded of one rank's
+    operation, from its persisted trace: seconds by ``caller.<class>``
+    with the three sites most seen, seconds by ``loop.<class>``, the sum
+    of each ``<name>.resumed``, and the ``caller.*`` / ``loop.*`` /
+    ``watch.*`` / ``take.*`` counters. Empty where nothing was recorded."""
+    tracks: Dict[str, Dict[str, Dict[str, Any]]] = {"caller": {}, "loop": {}}
+    resumed: Dict[str, float] = {}
+    for ev in trace_events:
+        name = ev.get("name", "")
+        if ev.get("ph") != "X":
+            continue
+        seconds = ev.get("dur", 0.0) / 1e6
+        track, _, cls = name.partition(".")
+        if track in tracks and ev.get("tid") == track:
+            row = tracks[track].setdefault(cls, {"seconds": 0.0, "sites": {}})
+            row["seconds"] += seconds
+            site = (ev.get("args") or {}).get("site")
+            if site:
+                row["sites"][site] = row["sites"].get(site, 0.0) + seconds
+        elif name.endswith(".resumed"):
+            resumed[name] = resumed.get(name, 0.0) + seconds
+    for rows in tracks.values():
+        for row in rows.values():
+            row["sites"] = sorted(row["sites"].items(), key=lambda kv: -kv[1])[:3]
+    counters = {
+        k: v
+        for k, v in (summary.get("counters") or {}).items()
+        if k.split(".")[0] in ("caller", "loop", "watch", "take")
+    }
+    gauges = {k: v for k, v in (summary.get("gauges") or {}).items() if k.startswith("watch.")}
+    if not (tracks["caller"] or tracks["loop"] or resumed):
+        return {}
+    return {**tracks, "resumed": resumed, "counters": counters, "gauges": gauges}
+
+
 # --------------------------------------------- ambient current recorder
 
 # The take installs its recorder process-globally; background threads
@@ -1089,12 +1562,15 @@ def begin_take(rank: int) -> TakeTelemetry:
     return rec
 
 
-def begin_restore(rank: int) -> TakeTelemetry:
+def begin_restore(
+    rank: int, caller: Optional[Tuple[int, int, Optional[int]]] = None
+) -> TakeTelemetry:
     """Create a restore recorder (NOT installed globally — restores
     overlay it thread-locally via :func:`use` so an in-flight take's
-    global recorder is never disturbed)."""
+    global recorder is never disturbed). ``caller``: see
+    :class:`TakeTelemetry`."""
     _begin_common()
-    rec = TakeTelemetry(rank, kind="restore")
+    rec = TakeTelemetry(rank, kind="restore", caller=caller)
     rec.meta["kind"] = "restore"
     rec.meta["job_id"] = _job_id()
     return rec
@@ -1125,7 +1601,7 @@ def end_take(rec: TakeTelemetry) -> None:
         clear_tuned_plan()
     except Exception:
         pass
-    rec.finalize()
+    rec.close()
     release_global(rec)
     summary = rec.summary()
     LAST_TAKE_SUMMARY = summary
@@ -1185,6 +1661,15 @@ def span(
         yield sp
 
 
+def _request_recorder() -> Optional[TakeTelemetry]:
+    """The recorder a hand-off belongs to: the one whose span is open
+    where it is submitted (the request's), else the ambient one."""
+    ambient = _ambient.get()
+    if ambient is not None and ambient[0]._finalized_wall_s is None:
+        return ambient[0]
+    return current()
+
+
 def handoff(
     name: str, fn: Callable[..., Any], work: Union[bool, str] = True, **attrs: Any
 ) -> Callable[..., Any]:
@@ -1192,14 +1677,62 @@ def handoff(
     its body are told apart (see :meth:`TakeTelemetry.handoff`). The
     recorder is the one whose span is open where this is called (the
     request's), else the ambient one; with neither, ``fn`` as it is."""
-    ambient = _ambient.get()
-    if ambient is not None and ambient[0]._finalized_wall_s is None:
-        rec = ambient[0]
-    else:
-        rec = current()
+    rec = _request_recorder()
     if rec is None:
         return fn
     return rec.handoff(name, fn, work=work, **attrs)
+
+
+def note_loop_thread() -> None:
+    """The calling thread is about to run the ambient operation's event
+    loop (see :meth:`TakeTelemetry.note_loop_thread`)."""
+    rec = current()
+    if rec is not None:
+        rec.note_loop_thread()
+
+
+async def run_handoff(
+    executor: Any,
+    name: str,
+    fn: Callable[..., Any],
+    *args: Any,
+    work: Union[bool, str] = True,
+    submit: Optional[Callable[[Any, Callable[[], Any]], Any]] = None,
+    **attrs: Any,
+) -> Any:
+    """Run ``fn(*args)`` on ``executor`` through :func:`handoff` and await
+    it: what every executor call of the pipeline goes through. Beside the
+    hand-off's spans on the worker it records, on the event loop's thread,
+    ``<name>.resumed`` (kind ``wait``, a child of the request's span, no
+    annotation: nothing runs): from the instant the worker's body ended
+    (the work span's own end, stamped on the worker) to the instant this
+    coroutine runs again. That is how late the loop was for a request
+    that had finished; with it the request's await span closes:
+    ``queued + work + resumed`` a trip, plus what the coroutine itself ran
+    between its trips. ``submit(executor, fn)`` stands in for
+    ``loop.run_in_executor`` where the plug-in tracks its futures (then
+    ``fn`` takes no arguments)."""
+    loop = asyncio.get_running_loop()
+    rec = _request_recorder()
+    if rec is None or not rec.enabled:
+        if submit is not None:
+            return await submit(executor, fn)
+        return await loop.run_in_executor(executor, fn, *args)
+    ended: List[float] = []
+    parent = rec.ambient_parent()
+    wrapped = rec.handoff(name, fn, work=work, ended=ended, **attrs)
+    try:
+        if submit is not None:
+            return await submit(executor, wrapped)
+        return await loop.run_in_executor(executor, wrapped, *args)
+    finally:
+        if ended:
+            rec._keep(
+                SpanRecord(
+                    next(_span_ids), f"{name}.resumed", ended[0], time.monotonic(),
+                    threading.current_thread().name, WAIT, rec.op, parent, {},
+                )
+            )
 
 
 def event(name: str, **attrs: Any) -> None:
