@@ -377,6 +377,48 @@ class TestReadInto:
                 with pytest.raises((IOError, ValueError)):
                     Snapshot(str(tmp_path / "s")).restore(target)
 
+    @pytest.mark.parametrize("checksum_off", [False, True], ids=["crc_on", "crc_off"])
+    @pytest.mark.parametrize("target", ["numpy", "jax"])
+    @pytest.mark.parametrize("damage", ["truncated", "longer"])
+    def test_blob_of_another_length_fails_loudly(
+        self, tmp_path, damage, target, checksum_off
+    ):
+        """A blob shorter OR longer than its manifest entry implies fails
+        the restore, for an in-place (numpy) and a device (jax) target,
+        with checksums on and off: the length a read takes from its
+        request picks the path, never what counts as the blob. The errors
+        are the ones the parent raised (recorded there, PR 44): every
+        case a ChecksumError with checksums on (an IOError) and, off, the
+        deserialize's ValueError. The blob is 4 MiB, so the jax target's
+        read takes the plug-in's reader threads, as a large leaf's does."""
+        import jax.numpy as jnp
+
+        from tpusnap._native import ChecksumError
+        from tpusnap.knobs import override_checksum_disabled
+
+        arr = np.arange(1 << 20, dtype=np.float32)
+        with override_slab_size_threshold_bytes(1024):
+            Snapshot.take(str(tmp_path / "s"), {"m": StateDict(w=arr)})
+        blob = str(tmp_path / "s" / "0" / "m" / "w")
+        assert os.path.getsize(blob) == arr.nbytes
+        with open(blob, "r+b") as f:
+            if damage == "truncated":
+                f.truncate(arr.nbytes // 2)
+            else:
+                f.seek(0, os.SEEK_END)
+                f.write(b"\x01" * 4096)
+        before = np.full_like(arr, -1.0)
+        dest = before.copy() if target == "numpy" else jnp.asarray(before)
+        state = {"m": StateDict(w=dest)}
+        with override_checksum_disabled(checksum_off):
+            with pytest.raises(ValueError if checksum_off else ChecksumError):
+                Snapshot(str(tmp_path / "s")).restore(state)
+        # The error is the whole outcome: the target is not quietly
+        # replaced by a prefix of the blob.
+        if target == "jax":
+            assert state["m"]["w"] is dest
+        np.testing.assert_array_equal(np.asarray(dest)[arr.size // 2 :], -1.0)
+
 
 class TestAbortPath:
     """A failed read must surface the ORIGINAL error, leave no stranded

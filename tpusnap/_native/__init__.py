@@ -166,6 +166,8 @@ def _bind(lib: ctypes.CDLL) -> None:
         ctypes.c_size_t,
     ]
     lib.ts_read_range.restype = ctypes.c_int64
+    lib.ts_touch_pages.argtypes = [ctypes.c_void_p, ctypes.c_size_t]
+    lib.ts_touch_pages.restype = None
     lib.ts_read_range_direct.argtypes = [
         ctypes.c_char_p,
         ctypes.c_void_p,
@@ -380,6 +382,18 @@ def aligned_empty(nbytes: int, align: int = 4096) -> np.ndarray:
     out = raw[off : off + nbytes]
     advise_hugepages(out)
     return out
+
+
+def touch_pages(buf: np.ndarray) -> None:
+    """Write one byte a page of ``buf``, a fresh buffer a read is about to
+    land in, from user space and without the interpreter's lock: see
+    ``ts_touch_pages``. The buffer's contents are undefined before and
+    after."""
+    lib = _load()
+    if lib is None:
+        buf.reshape(-1).view(np.uint8)[::_PAGE] = 0
+        return
+    lib.ts_touch_pages(buf.ctypes.data, buf.nbytes)
 
 
 def write_file(path: str, buf) -> None:

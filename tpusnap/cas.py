@@ -395,12 +395,7 @@ class CASStore:
         honored), falling back to the store's remote mirror when the
         local copy was evicted AND the store journal holds upload
         evidence for the key."""
-        trial = ReadIO(
-            path=blob_path(key),
-            byte_range=read_io.byte_range,
-            into=read_io.into,
-            want_crc=read_io.want_crc,
-        )
+        trial = read_io.as_new_request(blob_path(key))
         try:
             await self.plugin.read(trial)
         except FileNotFoundError:
@@ -412,12 +407,7 @@ class CASStore:
 
             rp = url_to_storage_plugin(remote, _store_options(None))
             try:
-                trial = ReadIO(
-                    path=blob_path(key),
-                    byte_range=read_io.byte_range,
-                    into=read_io.into,
-                    want_crc=read_io.want_crc,
-                )
+                trial = read_io.as_new_request(blob_path(key))
                 await rp.read(trial)
                 telemetry.incr("cas.remote_fallback_reads")
             finally:

@@ -588,7 +588,13 @@ class FaultInjectionStoragePlugin(StoragePlugin):
             if self.plan.short_reads:
                 # Deliver a seeded truncation of the real bytes, then fail
                 # the op — simulating a connection dropped mid-transfer.
-                trial = ReadIO(path=read_io.path, byte_range=read_io.byte_range)
+                # No `into`: the torn bytes go to the caller's buf, never
+                # into its restore target.
+                trial = ReadIO(
+                    path=read_io.path,
+                    byte_range=read_io.byte_range,
+                    expected_nbytes=read_io.expected_nbytes,
+                )
                 try:
                     await self.inner.read(trial)
                     data = trial.buf.getvalue()

@@ -1137,36 +1137,43 @@ class ArrayBufferConsumer(BufferConsumer):
         self.into_mv = writable_byte_view(obj_out, entry.dtype, entry.shape)
 
     async def consume_read_io(self, read_io, executor: Optional[Executor] = None) -> None:
+        hashed = self._verify_read_time_checksum(read_io)
         if read_io.in_place:
-            self._finalize_in_place(read_io)
+            # Bytes are already in obj_out's memory.
+            self.fut.obj = self.obj_out
             return
-        await self.consume_buffer(read_io.buf.getbuffer(), executor)
+        await self.consume_buffer(read_io.buf.getbuffer(), executor, verify=not hashed)
 
-    def _finalize_in_place(self, read_io) -> None:
-        # Bytes are already in obj_out's memory; only verify the read-time
-        # checksum against the manifest (an int compare, no data pass).
-        if self.entry.checksum is not None and read_io.crc32c is not None:
-            from .. import _native
+    def _verify_read_time_checksum(self, read_io) -> bool:
+        """Where the storage plug-in hashed the bytes as it read them (in
+        place, or into its scratch buffer on the reader thread, beside the
+        other streams' I/O), verify that value against the manifest: an
+        int compare, no data pass. False: it did not, and a buffer still
+        has to be hashed."""
+        if self.entry.checksum is None or read_io.crc32c is None:
+            return False
+        from .. import _native
 
-            _native.verify_checksum_value(
-                read_io.crc32c,
-                read_io.crc_algo,
-                self.entry.checksum,
-                self.verify_location,
-            )
-        self.fut.obj = self.obj_out
+        _native.verify_checksum_value(
+            read_io.crc32c,
+            read_io.crc_algo,
+            self.entry.checksum,
+            self.verify_location,
+        )
+        return True
 
     async def consume_buffer(
-        self, buf: BufferType, executor: Optional[Executor] = None
+        self, buf: BufferType, executor: Optional[Executor] = None, verify: bool = True
     ) -> None:
         if executor is not None:
-            await _consume_handoff(executor, self._consume_blocking, buf)
+            await _consume_handoff(executor, self._consume_blocking, buf, verify)
         else:
-            self._consume_blocking(buf)
+            self._consume_blocking(buf, verify)
 
-    def _consume_blocking(self, buf: BufferType) -> None:
-        with telemetry.span("decode", bytes=memoryview(buf).nbytes):
-            _maybe_verify(buf, self.entry.checksum, self.verify_location)
+    def _consume_blocking(self, buf: BufferType, verify: bool = True) -> None:
+        if verify:
+            with telemetry.span("decode", bytes=memoryview(buf).nbytes):
+                _maybe_verify(buf, self.entry.checksum, self.verify_location)
         value = materialize_array(self.entry, buf, self.obj_out)
         self.fut.obj = value
 
@@ -1365,7 +1372,13 @@ class ArrayIOPreparer:
                 byte_range=byte_range,
                 buffer_consumer=consumer,
                 into=consumer.into_mv,
-                want_crc=consumer.into_mv is not None and _want_crc(entry),
+                # In place, or a whole blob into the plug-in's scratch
+                # buffer: hashed where it is read. A member of a slab is
+                # read as part of a spanning range and hashed in consume.
+                want_crc=(consumer.into_mv is not None or byte_range is None)
+                and _want_crc(entry),
+                # A raw blob written whole is exactly its tensor's bytes.
+                expected_nbytes=nbytes if byte_range is None else None,
                 logical_path=logical_path,
             )
         ]

@@ -216,6 +216,12 @@ class ChunkedArrayIOPreparer:
                     into=consumer.into_mv,
                     want_crc=consumer.into_mv is not None
                     and _want_crc(tensor_entry),
+                    # A raw chunk written whole is exactly its rows' bytes.
+                    expected_nbytes=(
+                        tensor_nbytes(tensor_entry.dtype, tensor_entry.shape)
+                        if byte_range is None
+                        else None
+                    ),
                     logical_path=logical_path,
                 )
             )

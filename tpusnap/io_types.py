@@ -22,7 +22,7 @@ import asyncio
 import contextlib as _contextlib
 import io
 import threading as _threading
-from concurrent.futures import Executor
+from concurrent.futures import Executor, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Generic, List, Optional, Tuple, TypeVar, Union
 
@@ -103,6 +103,29 @@ class ReadIO:
     # reads; the scheduler's recorder then attributes the read to the
     # ambient storage tier (local/remote).
     source: Optional[str] = None
+    # The length of a whole-blob read (``byte_range`` None) as the
+    # manifest implies it, where the request knows it. A plug-in that
+    # would otherwise ask the backend for the object's size on the event
+    # loop's thread, to choose a path and a length, takes this instead and
+    # checks the real size where it reads (the fs plug-in: on the reader
+    # thread); a blob of another length fails as it does when the size is
+    # asked. None: the plug-in asks. Middlewares that copy a ReadIO per
+    # attempt carry it.
+    expected_nbytes: Optional[int] = None
+
+    def as_new_request(self, path: Optional[str] = None) -> "ReadIO":
+        """What this ReadIO asks for (of ``path``, where a middleware reads
+        the same bytes from elsewhere) and none of what a read has filled
+        in: the copy a middleware makes per attempt or per tier. Every
+        request field goes through here, so that none is dropped on the
+        way to the plug-in."""
+        return ReadIO(
+            path=self.path if path is None else path,
+            byte_range=self.byte_range,
+            into=self.into,
+            want_crc=self.want_crc,
+            expected_nbytes=self.expected_nbytes,
+        )
 
 
 class _SkipWrite:
@@ -210,6 +233,10 @@ class ReadReq:
     # read-time checksum of the delivered bytes.
     into: Optional[memoryview] = None
     want_crc: bool = False
+    # The blob's length as the manifest implies it, for a whole-blob read
+    # (``byte_range`` None) whose preparer knows it; see
+    # ReadIO.expected_nbytes.
+    expected_nbytes: Optional[int] = None
     # Access-ledger attribution: the MANIFEST path this physical read
     # serves ("<rank>/<logical_path>" — the storage ``path`` is a blob
     # location, shared across leaves and meaningless to a reader).
@@ -412,6 +439,29 @@ def close_may_join() -> bool:
     """Whether a plugin ``close()`` may join threads (False only inside
     :func:`finalizer_close_scope`)."""
     return not getattr(_finalizer_close, "active", False)
+
+
+def start_all_workers(executor: ThreadPoolExecutor) -> None:
+    """Start every thread ``executor`` may have, now, so that no later
+    ``submit`` starts one.
+
+    The stdlib pool starts a thread inside ``submit`` whenever none is
+    idle, and ``Thread.start()`` waits for the new thread to come up: on
+    the thread that runs an event loop that is a blocking call a request,
+    paid beside the reads already running (on the benchmark's host 0.4 ms
+    alone, 63 ms beside two or three reads, 0.7 s beside eight: PERF.md 6,
+    PR 44), which handed a restore's reads to their readers one by one
+    and kept every finished read from its consumer meanwhile. Here the
+    starts happen together, before the first
+    body runs: ``max_workers`` bodies that wait on ``go`` need as many
+    threads, so each submit that finds no idle worker starts one;
+    afterwards every submit is a queue put."""
+    go = _threading.Event()
+    try:
+        for _ in range(executor._max_workers):
+            executor.submit(go.wait)
+    finally:
+        go.set()
 
 
 def shutdown_plugin_executor(executor) -> None:

@@ -325,12 +325,7 @@ class RetryingStoragePlugin(StoragePlugin):
             # partially filled buf/into or set crc fields — results are
             # copied back only from a fully successful attempt, so no
             # torn read state ever reaches a consumer.
-            trial = ReadIO(
-                path=read_io.path,
-                byte_range=read_io.byte_range,
-                into=read_io.into,
-                want_crc=read_io.want_crc,
-            )
+            trial = read_io.as_new_request()
             await self.inner.read(trial)
             return trial
 

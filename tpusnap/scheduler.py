@@ -59,6 +59,7 @@ from .io_types import (
     run_on_loop,
     stager_aliases_caller_memory,
     stager_start_dtoh,
+    start_all_workers,
 )
 from .knobs import get_memory_budget_override_bytes
 
@@ -1183,6 +1184,7 @@ class _ReadPipeline:
             byte_range=self.read_req.byte_range,
             into=self.read_req.into,
             want_crc=self.read_req.want_crc,
+            expected_nbytes=self.read_req.expected_nbytes,
         )
         with telemetry.span(
             "storage_read", kind=telemetry.WAIT, rec=self.tele, path=self.read_req.path
@@ -1245,6 +1247,11 @@ async def execute_read_reqs(
     executor = ThreadPoolExecutor(
         max_workers=_MAX_CPU_CONCURRENCY, thread_name_prefix="tpusnap-consume"
     )
+    # Its threads are started here, with no read under way yet: the first
+    # consume is submitted from this thread beside the reads that follow
+    # it, where a thread's start costs what a read's hand-off must not
+    # (PERF.md 6, PR 44).
+    start_all_workers(executor)
     reporter = _Reporter(rank=rank, verb="read", total_reqs=len(read_reqs))
     # Ambient recorder (the restore path installs one thread-locally);
     # None for uninstrumented callers (verify's own engine, read_object

@@ -21,7 +21,7 @@ except ModuleNotFoundError:  # gated dep: fall back to thread-pool I/O
 import numpy as np
 
 from .. import telemetry
-from ..io_types import ReadIO, StoragePlugin, WriteIO
+from ..io_types import ReadIO, StoragePlugin, WriteIO, start_all_workers
 from ..memoryview_stream import MemoryviewStream
 
 # Buffers >= this go through the thread-pool native writer; small writes
@@ -50,6 +50,8 @@ class FSStoragePlugin(StoragePlugin):
         self.root = root
         self._dir_cache: Set[pathlib.Path] = set()
         self._executor: Optional[ThreadPoolExecutor] = None
+        self._readers_of: Optional[ThreadPoolExecutor] = None  # all started
+        self._a_reader_ran = False
 
     def _ensure_parent(self, path: pathlib.Path) -> None:
         parent = path.parent
@@ -141,27 +143,47 @@ class FSStoragePlugin(StoragePlugin):
 
     async def read(self, read_io: ReadIO) -> None:
         path = os.path.join(self.root, read_io.path)
+        # A whole-blob read chooses its path (in place, native, small) and
+        # its length by the blob's size. Where the request brings the
+        # length the manifest implies (`expected_nbytes`), this thread,
+        # which runs the event loop and every other read's dispatch, asks
+        # the filesystem nothing: the reader thread asks for the file's
+        # real size before it reads, and reads a file of another length
+        # exactly as if its size had been asked here.
+        # `known`: None for a ranged read, else whether the length came
+        # with the request.
+        known = None
         if read_io.byte_range is not None:
             offset, end = read_io.byte_range
+        elif read_io.expected_nbytes is not None:
+            offset, end, known = 0, read_io.expected_nbytes, True
         else:
-            offset, end = 0, os.path.getsize(path)
+            offset, end, known = 0, os.path.getsize(path), False
         n = end - offset
         # Exact-size match only: a truncated blob (n = actual file size <
         # destination) must fall through to the generic path, whose
         # deserialize raises on the size mismatch even with checksums off.
         if read_io.into is not None and n == read_io.into.nbytes:
-            await self._native_read_into(read_io, path, offset, n)
-            return
+            try:
+                await self._native_read_into(read_io, path, offset, n, known)
+                return
+            except _OtherLength as e:
+                # Shorter or longer than the request said, and nothing
+                # written: the generic path reads what is there.
+                n, known = e.args[0], None
         if n >= _NATIVE_WRITE_THRESHOLD:
-            read_io.buf = await self._native_read(path, offset, n, read_io)
+            read_io.buf = await self._native_read(path, offset, n, read_io, known)
             return
+        # A small blob whose length came with the request is read to its
+        # end, whatever its length: what asking for its size would read.
+        upto = -1 if known else n
         if aiofiles is None:
 
             def work():
                 with open(path, "rb") as f:
                     if offset:
                         f.seek(offset)
-                    return f.read(n)
+                    return f.read(upto)
 
             data = await telemetry.run_handoff(
                 self._get_executor(), "read", work, bytes=n
@@ -171,25 +193,65 @@ class FSStoragePlugin(StoragePlugin):
         async with aiofiles.open(path, "rb") as f:
             if offset:
                 await f.seek(offset)
-            read_io.buf = io.BytesIO(await f.read(n))
+            read_io.buf = io.BytesIO(await f.read(upto))
 
-    async def _native_read_into(self, read_io: ReadIO, path: str, offset: int, n: int) -> None:
+    async def _to_reader(self, work, n: int, known: Optional[bool]):
+        """Hand ``work`` to a reader thread, tracked for
+        ``drain_in_flight``. ``known`` says where a whole-blob read's
+        length came from (None: a ranged read), which is counted here:
+        c:``read.length_known`` (with the request: this thread made no
+        filesystem call for the read) or c:``read.length_asked`` (this
+        thread, the event loop's, asked the filesystem before the
+        hand-off). Before the first hand-off all eight threads are
+        started: one started by a later submit would be started on this
+        thread beside the reads already under way (a plug-in that only
+        writes starts its threads as its writes need them, as ever). The
+        first body of this plug-in says how many of its threads were alive
+        when it began (g:``fs.readers_at_first_read``, on the recorder of
+        the operation that dispatched the read)."""
+        if known is not None:
+            telemetry.incr("read.length_known" if known else "read.length_asked")
+        executor = self._get_executor()
+        if self._readers_of is not executor:
+            self._readers_of = executor
+            start_all_workers(executor)
+        rec = telemetry.current()
+
+        def body():
+            if not self._a_reader_ran:
+                self._a_reader_ran = True
+                if rec is not None:
+                    alive = sum(t.is_alive() for t in list(executor._threads))
+                    rec.gauge_max("fs.readers_at_first_read", float(alive))
+            return work()
+
+        return await telemetry.run_handoff(
+            executor, "read", body, submit=self._submit_tracked, bytes=n
+        )
+
+    async def _native_read_into(
+        self, read_io: ReadIO, path: str, offset: int, n: int, known: Optional[bool] = None
+    ) -> None:
         """In-place read: bytes land directly in the consumer-provided
         destination (the restore target's memory) with the checksum fused
         into the native copy-out — no scratch buffer, no separate verify
-        pass, no deserialize+copy pass in the consume stage."""
+        pass, no deserialize+copy pass in the consume stage. Where ``n``
+        came with the request (``known``) and the file is another length,
+        raises ``_OtherLength`` with nothing read."""
         dst = read_io.into
 
         def work():
             from .. import _native
 
+            if known:
+                real = os.path.getsize(path)
+                if real != n:
+                    raise _OtherLength(real)
             return _native.read_range_into(
                 path, offset, n, dst, want_crc=read_io.want_crc
             )
 
-        got, crc, algo = await telemetry.run_handoff(
-            self._get_executor(), "read", work, submit=self._submit_tracked, bytes=n
-        )
+        got, crc, algo = await self._to_reader(work, n, known)
         if got != n:
             raise IOError(
                 f"short read: got {got} of {n} bytes at offset {offset} "
@@ -200,7 +262,9 @@ class FSStoragePlugin(StoragePlugin):
         read_io.crc_algo = algo
         read_io.buf = MemoryviewStream(dst[:n])
 
-    async def _native_read(self, path: str, offset: int, n: int, read_io=None):
+    async def _native_read(
+        self, path: str, offset: int, n: int, read_io=None, known: Optional[bool] = None
+    ):
         """Single GIL-released pread in a thread (native helper), landing
         in an *uninitialized* numpy buffer — preallocating via BytesIO
         would zero-fill n bytes first. The allocation itself also happens
@@ -212,26 +276,35 @@ class FSStoragePlugin(StoragePlugin):
         computed here on the read thread — overlapping other streams'
         I/O — so the consume stage verifies a 4-byte value instead of
         re-reading the buffer (sharded-shard reads use this; dense numpy
-        targets go further via the in-place ``into`` path)."""
+        targets go further via the in-place ``into`` path).
+
+        Where ``n`` came with the request (``known``), the read's length
+        is the file's real size, asked here on the reader thread: a blob
+        shorter or longer than the manifest implies is delivered as it
+        is, and the consumer's deserialize raises on it as it always
+        has."""
         want_crc = read_io is not None and read_io.want_crc
 
         def work():
             from .. import _native
 
+            size = os.path.getsize(path) if known else n
             # 4096-aligned so the native direct read preads straight into
             # this buffer (zero-copy) instead of bouncing every chunk.
-            arr = _native.aligned_empty(n)
+            arr = _native.aligned_empty(size)
+            # Its pages' first touch, here and not inside the read, whose
+            # faults on a sandboxed kernel hold up every other thread of
+            # the restore (see ts_touch_pages).
+            _native.touch_pages(arr)
             if want_crc:
                 got, crc, algo = _native.read_range_into(
-                    path, offset, n, arr, want_crc=True
+                    path, offset, size, arr, want_crc=True
                 )
-                return arr, got, crc, algo
-            got = _read_range(path, offset, n, arr.data)
-            return arr, got, None, None
+                return arr, size, got, crc, algo
+            got = _read_range(path, offset, size, arr.data)
+            return arr, size, got, None, None
 
-        arr, got, crc, algo = await telemetry.run_handoff(
-            self._get_executor(), "read", work, submit=self._submit_tracked, bytes=n
-        )
+        arr, n, got, crc, algo = await self._to_reader(work, n, known)
         if want_crc and got == n:
             read_io.crc32c = crc
             read_io.crc_algo = algo
@@ -294,6 +367,11 @@ def _durable_commit() -> bool:
     from ..knobs import is_durable_commit_enabled
 
     return is_durable_commit_enabled()
+
+
+class _OtherLength(Exception):
+    """The file is not the length that came with the read's request; its
+    one argument is the length it has."""
 
 
 def _fsync_path(path: str) -> None:

@@ -428,12 +428,7 @@ class TieredStoragePlugin(StoragePlugin):
             # tier. A fresh ReadIO per tier, retry-middleware style, so
             # a partially-filled local attempt never leaks upward.
             pass
-        trial = ReadIO(
-            path=read_io.path,
-            byte_range=read_io.byte_range,
-            into=read_io.into,
-            want_crc=read_io.want_crc,
-        )
+        trial = read_io.as_new_request()
         await self._remote_plugin().read(trial)
         telemetry.incr("tier.remote_fallback_reads")
         read_io.buf = trial.buf
