@@ -2,7 +2,7 @@
 
 The reference library ships example *training scripts* (DDP / FSDP /
 torchrec DLRM, SURVEY.md §2 #23-24) but no model code of its own. tpusnap
-ships four model families: a flagship decoder transformer whose parameter
+ships five model families: a flagship decoder transformer whose parameter
 pytree exercises every sharding family the checkpoint preparers must
 handle — DP (replicated), FSDP (param-sharded), TP (tensor-parallel),
 SP/CP (ring attention over a sequence axis) and EP (expert-sharded MoE
@@ -11,10 +11,16 @@ analog: row/col/table-wise layouts, host-offloaded tables, row-wise
 Adagrad state), one chip's share of a sparse-expert decoder with
 window and global attention (``smallthinker``: top-k token dispatch over
 the experts held here, one subtree a layer, so a state of many leaves of
-a few tens of MiB), and a looped decoder (``ouro``: one stack of layers
+a few tens of MiB), a looped decoder (``ouro``: one stack of layers
 run several times over the same leaves, sandwich norms, an exit gate and
 a loss term at every pass, so a gradient that sums over a leaf's uses and
-a state with leaves of one element beside leaves of hundreds of MB).
+a state with leaves of one element beside leaves of hundreds of MB), and
+one chip's share of a latent-attention, sparse-expert decoder (``joyai``:
+keys and values rebuilt from a low-rank latent in every query block, a
+leading dense layer, a shared expert beside a sigmoid-routed share of the
+experts with a correction bias that no step changes, and a
+multi-token-prediction module that reads the embedding and the head a
+second time, so a state of hundreds of leaves, most of them a few MB).
 """
 
 from .embedding import (  # noqa: F401
@@ -22,6 +28,7 @@ from .embedding import (  # noqa: F401
     TableConfig,
     make_embedding_train_step,
 )
+from .joyai import JoyAI, JoyAIConfig  # noqa: F401
 from .ouro import Ouro, OuroConfig  # noqa: F401
 from .smallthinker import SmallThinker, SmallThinkerConfig  # noqa: F401
 from .transformer import (  # noqa: F401
@@ -33,6 +40,8 @@ from .transformer import (  # noqa: F401
 
 __all__ = [
     "EmbeddingCollection",
+    "JoyAI",
+    "JoyAIConfig",
     "Ouro",
     "OuroConfig",
     "SmallThinker",
