@@ -9,9 +9,17 @@ evicted from the page cache the way the benchmark's ``resume_loop`` does
 by ``--widths`` threads in turn, each read as the fs plug-in's reader makes
 it: ``_native.read_range`` into a fresh ``_native.aligned_empty`` buffer.
 One JSON line a width (``read``), with the bytes, the seconds, GB/s and the
-median and slowest blob. ``--reuse`` reads into one buffer a thread, written
+median and slowest blob. ``--reuse`` alone reads into one buffer a thread, written
 once before the clock starts, instead: what the mount gives when no page of
 the destination is touched for the first time (a restore's buffers are fresh).
+``--reuse 2,4,8`` passes buffers from one read to the next as a restore could
+(``pool`` lines, one a count and width): the threads share at most that many
+buffers, none of which exists when the clock starts; a read takes the smallest
+free buffer that holds it, else allocates one and touches its pages as the
+plug-in does (``_native.touch_pages``) while fewer than the count exist, else
+waits for one to come back; a buffer comes back ``--hold-ms`` after its read
+ended (the time a consumer keeps it). A count of the number of blobs is what
+the plug-in does without a pool: every read touches a buffer of its own.
 
 ``--calls K`` adds the two calls a restore used to make on its event
 loop's thread between a read's dispatch and its hand-off (``calls`` lines):
@@ -87,22 +95,17 @@ def write_blobs(root: str, count: int, nbytes: int) -> None:
             _native._write_all(path, memoryview(buf))
 
 
-def read_all(blobs: List[Tuple[str, int]], width: int, reuse: bool = False) -> Dict[str, float]:
-    """Read every blob once on ``width`` threads; the blobs are evicted first."""
-    from tpusnap import _native
-
+def _read_on_threads(blobs: List[Tuple[str, int]], width: int, read, per_thread=None) -> Dict[str, float]:
+    """Evict the blobs, then ``read(path, size, *mine)`` each once, largest
+    first, on ``width`` threads (``mine``: what ``per_thread()`` made for
+    the thread before the clock started, if given)."""
     evict([p for p, _ in blobs])
     todo = list(reversed(blobs))  # pop() takes the largest
     lock = threading.Lock()
     each: List[float] = []
     errors: List[BaseException] = []
-    warm = []
-    if reuse:
-        for _ in range(width):
-            warm.append(_native.aligned_empty(blobs[0][1]))
-            warm[-1].fill(1)
 
-    def reader(mine=None) -> None:
+    def reader(*mine) -> None:
         while True:
             with lock:
                 if not todo:
@@ -110,10 +113,7 @@ def read_all(blobs: List[Tuple[str, int]], width: int, reuse: bool = False) -> D
                 path, size = todo.pop()
             t = now()
             try:
-                arr = _native.aligned_empty(size) if mine is None else mine[:size]
-                got = _native.read_range(path, 0, size, arr.data)
-                if got != size:
-                    raise IOError(f"short read: {got} of {size} bytes from {path}")
+                read(path, size, *mine)
             except BaseException as e:
                 errors.append(e)
                 return
@@ -122,7 +122,8 @@ def read_all(blobs: List[Tuple[str, int]], width: int, reuse: bool = False) -> D
                 each.append(dt)
 
     threads = [
-        threading.Thread(target=reader, name=f"probe-read-{i}", args=(warm[i] if reuse else None,))
+        threading.Thread(target=reader, name=f"probe-read-{i}",
+                         args=(per_thread(),) if per_thread else ())
         for i in range(width)
     ]
     t0 = now()
@@ -136,13 +137,129 @@ def read_all(blobs: List[Tuple[str, int]], width: int, reuse: bool = False) -> D
     total = sum(s for _, s in blobs)
     return {
         "width": width,
-        "reuse": reuse,
         "blobs": len(blobs),
         "bytes": total,
         "seconds": seconds,
         "gb_per_s": total / seconds / 1e9,
         "blob_median_s": statistics.median(each),
         "blob_max_s": max(each),
+    }
+
+
+def read_all(blobs: List[Tuple[str, int]], width: int, reuse: bool = False) -> Dict[str, float]:
+    """Read every blob once on ``width`` threads; the blobs are evicted first."""
+    from tpusnap import _native
+
+    def read(path: str, size: int, mine=None) -> None:
+        arr = _native.aligned_empty(size) if mine is None else mine[:size]
+        got = _native.read_range(path, 0, size, arr.data)
+        if got != size:
+            raise IOError(f"short read: {got} of {size} bytes from {path}")
+
+    def warm():
+        buf = _native.aligned_empty(blobs[0][1])
+        buf.fill(1)
+        return buf
+
+    return {**_read_on_threads(blobs, width, read, warm if reuse else None), "reuse": reuse}
+
+
+class _Pool:
+    """At most ``count`` buffers, passed from read to read; empty at first."""
+
+    def __init__(self, count: int) -> None:
+        self.count = count
+        self.cond = threading.Condition()
+        self.free: list = []
+        self.made = 0
+        self.fresh_bytes = self.reused_bytes = 0
+        self.wait_s = self.touch_s = self.fresh_read_s = self.reused_read_s = 0.0
+
+    def take(self, size: int):
+        """``(buffer, fresh)``."""
+        from tpusnap import _native
+
+        with self.cond:
+            t = now()
+            while True:
+                fits = [i for i, b in enumerate(self.free) if b.nbytes >= size]
+                if fits:
+                    buf = self.free.pop(min(fits, key=lambda i: self.free[i].nbytes))
+                    self.reused_bytes += size
+                    self.wait_s += now() - t
+                    return buf, False
+                if self.made < self.count or len(self.free) == self.made:
+                    if self.made >= self.count:  # every buffer is free and too small
+                        self.free.sort(key=lambda b: b.nbytes)
+                        del self.free[0]
+                        self.made -= 1
+                    self.made += 1
+                    self.fresh_bytes += size
+                    self.wait_s += now() - t
+                    break
+                self.cond.wait()
+        t = now()
+        buf = _native.aligned_empty(size)
+        _native.touch_pages(buf)
+        with self.cond:
+            self.touch_s += now() - t
+        return buf, True
+
+    def give(self, buf) -> None:
+        with self.cond:
+            self.free.append(buf)
+            self.cond.notify_all()
+
+
+def read_pooled(blobs: List[Tuple[str, int]], width: int, count: int, hold_s: float) -> Dict[str, float]:
+    """Every blob once on ``width`` threads that share ``count`` buffers; a
+    buffer goes back ``hold_s`` after its read ended, on one thread that is
+    started before the clock (a thread's start beside reads is dear there)."""
+    import queue
+
+    pool = _Pool(count)
+    held: "queue.SimpleQueue" = queue.SimpleQueue()
+
+    def holder() -> None:
+        while True:
+            item = held.get()
+            if item is None:
+                return
+            back_at, buf = item
+            time.sleep(max(0.0, back_at - now()))
+            pool.give(buf)
+
+    consumer = threading.Thread(target=holder, name="probe-hold")
+    consumer.start()
+
+    def read(path: str, size: int) -> None:
+        from tpusnap import _native
+
+        buf, fresh = pool.take(size)
+        t = now()
+        got = _native.read_range(path, 0, size, buf[:size].data)
+        with pool.cond:
+            if fresh:
+                pool.fresh_read_s += now() - t
+            else:
+                pool.reused_read_s += now() - t
+        if got != size:
+            raise IOError(f"short read: {got} of {size} bytes from {path}")
+        if hold_s:
+            held.put((now() + hold_s, buf))
+        else:
+            pool.give(buf)
+
+    try:
+        line = _read_on_threads(blobs, width, read)
+    finally:
+        held.put(None)
+        consumer.join()
+    return {
+        **line, "buffers": count, "hold_ms": hold_s * 1e3, "buffers_made": pool.made,
+        "fresh_bytes": pool.fresh_bytes, "reused_bytes": pool.reused_bytes,
+        "wait_s": pool.wait_s, "touch_s": pool.touch_s, "fresh_read_s": pool.fresh_read_s,
+        "reused_read_s": pool.reused_read_s,
     }
 
 
@@ -223,7 +340,9 @@ def main(argv=None) -> int:
     parser.add_argument("--min-mib", type=float, default=4.0)
     parser.add_argument("--widths", default="1,2,4,8")
     parser.add_argument("--repeats", type=int, default=1)
-    parser.add_argument("--reuse", action="store_true")
+    parser.add_argument("--reuse", nargs="?", const="thread", default=None,
+                        help="alone: one warm buffer a thread; '2,4,8': buffers passed between reads")
+    parser.add_argument("--hold-ms", type=float, default=0.0)
     parser.add_argument("--calls", type=int, default=0)
     parser.add_argument("--beside", type=int, default=8)
     args = parser.parse_args(argv)
@@ -243,8 +362,13 @@ def main(argv=None) -> int:
                           "largest": blobs[0][1], "smallest": blobs[-1][1]}), flush=True)
         for _ in range(args.repeats):
             for width in (int(w) for w in args.widths.split(",")):
-                line = read_all(blobs, width, args.reuse)
-                print(json.dumps({"probe": "read", **line}), flush=True)
+                if args.reuse in (None, "thread"):
+                    line = read_all(blobs, width, args.reuse is not None)
+                    print(json.dumps({"probe": "read", **line}), flush=True)
+                    continue
+                for count in (int(c) for c in args.reuse.split(",")):
+                    line = read_pooled(blobs, width, count, args.hold_ms / 1e3)
+                    print(json.dumps({"probe": "pool", **line}), flush=True)
         if args.calls:
             for line in time_calls(blobs, args.calls, args.beside):
                 print(json.dumps({"probe": "calls", **line}), flush=True)

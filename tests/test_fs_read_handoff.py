@@ -273,6 +273,37 @@ def test_cold_read_probe_on_a_tiny_snapshot(tmp_path, capsys):
     assert probe.main(["--path", str(tmp_path)]) == 2  # nothing to read there
 
 
+@pytest.mark.parametrize("hold_ms", ["0", "2"])
+def test_cold_read_probe_passes_buffers_between_reads(tmp_path, capsys, hold_ms):
+    """``--reuse 2,6``: the threads share at most that many buffers, none
+    there at the start; with six buffers for six blobs every read touches
+    its own, with two the other four blobs land in memory read into before,
+    whether a buffer comes back at once or a consumer keeps it a while."""
+    import json
+
+    path = os.path.join(os.path.dirname(__file__), "..", "scripts", "cold_read_probe.py")
+    spec = importlib.util.spec_from_file_location("cold_read_probe", path)
+    probe = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(probe)
+    rc = probe.main(
+        ["--dir", str(tmp_path), "--blobs", "6", "--blob-mib", "1", "--min-mib", "0.5",
+         "--widths", "1,4", "--reuse", "2,6", "--hold-ms", hold_ms]
+    )
+    assert rc == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    pools = [ln for ln in lines if ln["probe"] == "pool"]
+    assert [(p["width"], p["buffers"]) for p in pools] == [(1, 2), (1, 6), (4, 2), (4, 6)]
+    for p in pools:
+        assert p["bytes"] == p["fresh_bytes"] + p["reused_bytes"] == 6 << 20
+        assert p["buffers_made"] <= p["buffers"] and p["gb_per_s"] > 0
+        assert p["fresh_bytes"] == p["buffers_made"] << 20
+        assert p["touch_s"] > 0 and p["fresh_read_s"] > 0 and p["wait_s"] >= 0
+        assert (p["reused_read_s"] > 0) == (p["reused_bytes"] > 0)
+    assert all(p["reused_bytes"] >= 4 << 20 for p in pools if p["buffers"] == 2)
+    assert not any(ln["probe"] == "read" for ln in lines)
+    assert os.listdir(tmp_path) == []
+
+
 def test_a_whole_blob_into_scratch_is_hashed_where_it_is_read(tmp_path):
     """A device target's blob is hashed on the reader thread (``want_crc``)
     and the consumer compares the value: no ``decode`` pass over the
