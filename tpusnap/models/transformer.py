@@ -256,9 +256,9 @@ class Transformer:
         return nll.mean()
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps=1e-6):
     xf = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
+    rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
 
 
