@@ -216,6 +216,54 @@ def test_copies_start_in_staging_order_a_fixed_depth_ahead(tmp_path, sizes, host
         assert lookahead == ahead_starts
 
 
+@pytest.mark.parametrize("leaves,leaf_kib,depth_kib", [(6, 64, 64), (8, 64, 128), (12, 16, 64)],
+                         ids=["a_leaf_a_depth", "two_leaves_a_depth", "four_leaves_a_depth"])
+def test_owned_copies_alive_on_the_chip_are_bounded_by_the_depth(tmp_path, monkeypatch, leaves, leaf_kib, depth_kib):
+    """PR 51: a large accelerator leaf crosses from a copy on the chip
+    that tpusnap owns, made when the lookahead reaches the leaf and let
+    go when its bytes are seen on the host. So the copies alive at once
+    are the copies started and not yet staged: the one being staged and
+    the depth ahead of it, never the state. (A CPU array never
+    qualifies: the rule's backend test and the device's free bytes are
+    patched. A pipelined take, where steps may run beside the drain.)"""
+    from tpusnap.io_preparers import array as array_preparer
+
+    leaf, depth = leaf_kib * 1024, depth_kib * 1024
+    monkeypatch.setattr(array_preparer, "_lies_on_one_accelerator", lambda arr: True)
+    monkeypatch.setattr(array_preparer, "_device_free_bytes", lambda device: 1 << 40)
+    monkeypatch.setattr(array_preparer, "RELAYOUT_MIN_BYTES", 4096)
+    monkeypatch.setattr(scheduler_mod, "_DTOH_LOOKAHEAD_BYTES", depth)
+    arrays = [jnp.full((leaf // 4,), float(i), jnp.float32) for i in range(leaves)]
+    stagers = [_stager(x, f"0/w{i}") for i, x in enumerate(arrays)]
+    write_reqs = [WriteReq(path=s.entry.location, buffer_stager=s) for s in stagers]
+    plain, held = array_preparer._own_copy, []
+
+    def counted(arr):
+        # The copies tpusnap holds while one more is made, that one included.
+        held.append(1 + sum(s._owned is not None for s in stagers))
+        return plain(arr)
+
+    monkeypatch.setattr(array_preparer, "_own_copy", counted)
+    before = telemetry.counter_value("dtoh.owned_leaves")
+
+    async def go():
+        pending = await execute_write_reqs(
+            write_reqs, FSStoragePlugin(str(tmp_path)), 1 << 30, rank=0, pipelined_staging=True
+        )
+        await pending.complete()
+
+    with override_stage_threads(1):
+        asyncio.run(go())
+    assert len(held) == leaves == telemetry.counter_value("dtoh.owned_leaves") - before
+    # The leaf being staged, and what the depth lets start ahead of it:
+    # the next request always, further ones while under the depth's bytes.
+    assert max(held) <= 1 + max(REQS, -(-depth // leaf))
+    assert max(held) >= 2  # there is a lookahead: a copy is made beside one that is held
+    assert all(s._owned is None for s in stagers)
+    for i, x in enumerate(arrays):
+        assert (tmp_path / "0" / f"w{i}").read_bytes() == np.asarray(x).tobytes()
+
+
 def test_copies_are_started_ahead_of_a_head_that_waits_for_budget(tmp_path, monkeypatch):
     """The budget admits one request at a time and its write does not
     end: the head of the queue waits, with its copy under way (that is

@@ -7,6 +7,7 @@ only; no time read here is a device's."""
 
 import asyncio
 import concurrent.futures
+import contextlib
 import json
 import os
 import re
@@ -198,7 +199,8 @@ class Parked:
         pending = PendingSnapshot.__new__(PendingSnapshot)
         pending._cow_rendezvous = False
         pending._done = threading.Event()
-        pending._pending_io_work = types.SimpleNamespace(wait_staged=self.gates["tpusnap"].wait)
+        pending._pending_io_work = types.SimpleNamespace(
+            wait_staged=self.gates["tpusnap"].wait, caller_waits=contextlib.nullcontext)
         pending.wait_staged()
 
     def frame(self):

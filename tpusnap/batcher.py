@@ -77,6 +77,15 @@ def _start_members_dtoh(members) -> int:
     return sum(s.start_dtoh() for _, _, s in members)
 
 
+def _note_members(members) -> None:
+    """Tell each member's stager that a slab carries it: its bytes cross
+    inside the slab, so a large member is not copied on the chip for a
+    crossing of its own (``ArrayBufferStager._crosses_owned``)."""
+    for _, _, s in members:
+        if isinstance(s, ArrayBufferStager):
+            s.in_slab = True
+
+
 def _any_member_aliases(members) -> bool:
     """A slab counts towards an async take's blocked window when any
     member's bytes may be written in place by the caller (see
@@ -100,6 +109,7 @@ class BatchedBufferStager(BufferStager):
         # members: [(offset, nbytes, stager)]
         self.members = members
         self.total = sum(n for _, n, _ in members)
+        _note_members(members)
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         from . import _native
@@ -215,6 +225,7 @@ class DeviceBatchedBufferStager(BufferStager):
     def __init__(self, members: List[Tuple[int, int, ArrayBufferStager]]) -> None:
         self.members = members
         self.total = sum(n for _, n, _ in members)
+        _note_members(members)
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         try:

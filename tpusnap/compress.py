@@ -328,7 +328,7 @@ def _sample_codec(eligible, rec) -> Optional[Tuple[float, float, int]]:
     # The copy staging will use, started here and counted once: the
     # scheduler dispatches this request first and finds it under way.
     st.start_dtoh()
-    host = np.asarray(st.arr)
+    host = st.host_array()
     if spans and not isinstance(st.arr, np.ndarray):
         # This one leaf's transfer: the wait the staging thread would
         # have paid for it. It keeps that wait's name; the leaf's bytes

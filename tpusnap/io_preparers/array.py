@@ -12,7 +12,16 @@ copies all started at once stand in front of the caller's next step,
 while a step does run between two leaves' copies. The thread-pooled
 ``np.asarray`` in ``stage_buffer`` then waits out what is left of its
 leaf's copy (numpy releases the GIL for the copy; the PJRT transfer
-releases it too).
+releases it too). Where the caller's steps may run beside the transfer, a
+large accelerator leaf does not cross from the caller's own buffer:
+``start_dtoh()`` has the runtime copy it on the chip (``_own_copy``: no
+compiled program, the leaf's shape, type and layout) and copies that
+buffer, which only tpusnap holds and which is let go once its bytes are
+seen on the host (``_crosses_owned``; docs/design.md, "The owned
+crossing": the steps beside a draining take lose a third less). A caller
+that stands in the take or in ``wait_staged()`` runs no step the copy could
+protect and would only wait for it, and a device that has not the room
+free keeps it for the caller: the leaf then crosses as it lies.
 
 Differences by design:
 - JAX arrays are immutable, so the reference's in-place load
@@ -28,9 +37,11 @@ from __future__ import annotations
 
 import logging
 import math
+import threading
 import time
+import weakref
 from concurrent.futures import Executor
-from typing import Any, Callable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -105,6 +116,72 @@ def enqueue_dtoh(arr: ArrayLike) -> Optional[float]:
     return started
 
 
+def _own_copy(arr: jax.Array) -> jax.Array:
+    """A second buffer on ``arr``'s own device holding its elements, in its
+    shape, type and layout; the caller gets the only reference to it. Made
+    by the runtime (``may_alias=False`` is a copy even onto the device the
+    array lies on), not by a compiled program: no shape costs a
+    compilation or a cache lookup."""
+    return jax.device_put(arr, may_alias=False)
+
+
+def _lies_on_one_accelerator(arr: ArrayLike) -> bool:
+    """Whether ``arr`` is a whole array in one accelerator's own memory,
+    whose ``np.asarray`` is a transfer the runtime lands on the host.
+    Never a CPU backend's array, whose ``np.asarray`` is a view: nothing
+    crosses, and a copy would only be one more. Asked of the backend's
+    own probe, not of its name."""
+    return (
+        isinstance(arr, jax.Array)
+        and len(arr.devices()) == 1
+        and not _may_alias_live_memory(arr, None)
+    )
+
+
+def _is_out_of_device_memory(e: BaseException) -> bool:
+    return isinstance(e, jax.errors.JaxRuntimeError) and "RESOURCE_EXHAUSTED" in str(e)
+
+
+# The share of a device's memory that owned copies never reach into: what
+# the allocator's fragments and a step a little larger than any before it
+# may need (a sixteenth: 1 GiB of a v5e's 16).
+_OWNED_CLEAR_SHARE = 16
+# Bytes of owned copies alive on each device, every take's together: a
+# copy counts from its making until the last reference to it dies.
+_owned_live: Dict[Any, int] = {}
+_owned_live_lock = threading.Lock()
+
+
+def _device_free_bytes(device) -> Optional[int]:
+    """What of ``device``'s memory no allocation of this process has ever
+    reached (its limit less the allocator's peak: the caller's state, its
+    steps' temporaries and tpusnap's own earlier copies), less the share
+    kept clear; None where the backend reports no such numbers."""
+    stats = device.memory_stats()
+    try:
+        limit = int(stats["bytes_limit"])
+        return limit - int(stats["peak_bytes_in_use"]) - limit // _OWNED_CLEAR_SHARE
+    except (TypeError, KeyError):
+        return None
+
+
+def _note_owned(device, nbytes: int) -> None:
+    with _owned_live_lock:
+        _owned_live[device] = _owned_live.get(device, 0) + nbytes
+
+
+def _has_room_for_owned(device, nbytes: int) -> bool:
+    """Whether a copy of ``nbytes`` may be made on ``device``: the caller's
+    next step allocates its temporaries beside every copy alive, and must
+    find what it found before the take. A copy that the runtime would
+    make all the same could fail that step instead of itself. A device
+    that cannot be asked has no room."""
+    free = _device_free_bytes(device)
+    with _owned_live_lock:
+        live = _owned_live.get(device, 0)
+    return free is not None and live + nbytes <= free
+
+
 class ArrayBufferStager(BufferStager):
     def __init__(
         self,
@@ -177,6 +254,17 @@ class ArrayBufferStager(BufferStager):
         # was: by start_dtoh(), never here.
         self.dtoh_started: Optional[float] = None
         self._dtoh_asked = False
+        # Set by a slab that takes this leaf as a member: its bytes cross
+        # inside the slab (or are fetched by the slab's host fallback).
+        self.in_slab = False
+        # Cleared by the write scheduler before ``start_dtoh()`` where the
+        # caller runs no step while this leaf crosses: it stands in the
+        # take, or in ``wait_staged()`` (``_WriteScheduler._steps_may_run``).
+        self.beside_steps = True
+        # tpusnap's own copy of the leaf on the chip, from start_dtoh()
+        # until its bytes are seen on the host (see _crosses_owned): what
+        # copy_to_host_async() was called on.
+        self._owned: Optional[jax.Array] = None
 
     def _prefetches(self) -> bool:
         """Whether ``start_dtoh`` has a copy to start: an accelerator's
@@ -184,18 +272,91 @@ class ArrayBufferStager(BufferStager):
         prefetching the untransformed array would be wasted DMA."""
         return self.array_prepare_func is None and not is_host_resident(self.arr)
 
+    def _crosses_owned(self) -> bool:
+        """Whether a leaf that ``_prefetches()`` crosses from a copy on
+        the chip that tpusnap owns, and not from the caller's buffer,
+        which its next step takes as an argument. Read off the array and
+        the stager alone: a whole leaf in one accelerator's memory, not a
+        slab's member, and large enough to matter (``RELAYOUT_MIN_BYTES``:
+        below it a leaf is in a slab or small)."""
+        return (
+            not self.in_slab
+            and array_nbytes(self.arr) >= RELAYOUT_MIN_BYTES
+            and _lies_on_one_accelerator(self.arr)
+        )
+
     def start_dtoh(self) -> int:
         """Start this leaf's copy to the host, once, and return the
-        bytes under way (0 where there is no copy to start)."""
+        bytes under way (0 where there is no copy to start). Where the
+        caller runs no step meanwhile (``beside_steps`` cleared), a leaf
+        that would cross from an owned copy crosses as it lies, and
+        ``dtoh.owned_waived`` counts it: the copy would protect nothing,
+        and the transfer would wait for it."""
         if not self._dtoh_asked:
             self._dtoh_asked = True
             # A deleted array is staging's to report, by the leaf's name.
             if self._prefetches() and not self.arr.is_deleted():
-                self.dtoh_started = enqueue_dtoh(self.arr)
+                if self._crosses_owned():
+                    if self.beside_steps:
+                        self.dtoh_started = self._start_owned()
+                    else:
+                        telemetry.incr("dtoh.owned_waived")
+                if self.dtoh_started is None:
+                    self.dtoh_started = enqueue_dtoh(self.arr)
         return array_nbytes(self.arr) if self.dtoh_started is not None else 0
 
+    def _start_owned(self) -> Optional[float]:
+        """Copy the leaf on the chip and start that copy's transfer;
+        return when the transfer was started (``time.monotonic()``), or
+        None where the chip has no room for the copy, by its own count
+        before the copy or by the runtime's refusal: the leaf then
+        crosses as it lies."""
+        nbytes = array_nbytes(self.arr)
+        device = next(iter(self.arr.devices()))
+        if not _has_room_for_owned(device, nbytes):
+            telemetry.incr("dtoh.owned_fallbacks")
+            return None
+        with telemetry.span("dtoh.own_copy", kind=telemetry.WORK, bytes=nbytes):
+            try:
+                owned = _own_copy(self.arr)
+                _note_owned(device, nbytes)
+                weakref.finalize(owned, _note_owned, device, -nbytes)
+                # Where a leaf that crosses as it lies starts its own.
+                started = time.monotonic()
+                owned.copy_to_host_async()
+            except RuntimeError as e:
+                if not _is_out_of_device_memory(e):
+                    self.raise_if_donated()  # deleted under the call
+                    raise
+                telemetry.incr("dtoh.owned_fallbacks")
+                return None
+        self._owned = owned
+        telemetry.incr("dtoh.enqueued_bytes", nbytes)
+        telemetry.incr("dtoh.owned_bytes", nbytes)
+        telemetry.incr("dtoh.owned_leaves")
+        return started
+
+    def host_array(self) -> np.ndarray:
+        """The leaf's bytes on the host: the one fetch of the one copy
+        ``start_dtoh()`` started (JAX keeps the host value with the array
+        it was fetched from, so a second call costs nothing), for staging
+        and for the codec policy's sampler alike."""
+        owned = self._owned
+        if owned is not None:
+            try:
+                return np.asarray(owned)
+            except jax.errors.JaxRuntimeError as e:
+                if not _is_out_of_device_memory(e):
+                    raise
+                # The copy found no room on its way: the leaf crosses as
+                # it lies, and that crossing is counted too.
+                self._owned = None
+                telemetry.incr("dtoh.owned_fallbacks")
+                enqueue_dtoh(self.arr)
+        return np.asarray(self.arr)
+
     def host_bytes_are_free(self) -> bool:
-        """Whether ``np.asarray(self.arr)`` yields the bytes staging
+        """Whether ``host_array()`` yields the bytes staging
         will stage and runs no device operation of its own: a numpy
         leaf, or an accelerator's array with no ``array_prepare_func``
         (after ``start_dtoh()`` the call waits out that one copy, and JAX
@@ -252,10 +413,13 @@ class ArrayBufferStager(BufferStager):
             else None
         )
         try:
-            host = np.asarray(arr)  # DtoH (no-op if DMA already done)
+            # DtoH (no-op if DMA already done)
+            host = self.host_array() if arr is self.arr else np.asarray(arr)
         except RuntimeError:
             self.raise_if_donated()  # deleted under the call
             raise
+        # The owned copy leaves the chip once its bytes are seen here.
+        self._owned = None
         prefetched = arr is self.arr and self.dtoh_started is not None
         if not prefetched and not is_host_resident(arr):
             # No copy was under way: the fetch above was the transfer.

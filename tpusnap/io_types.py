@@ -188,11 +188,18 @@ def stager_aliases_caller_memory(stager: BufferStager) -> bool:
     return True if ask is None else bool(ask())
 
 
-def stager_start_dtoh(stager: BufferStager) -> int:
+def stager_start_dtoh(stager: BufferStager, beside_steps: bool = True) -> int:
     """``stager.start_dtoh()``; a stager that is no ``BufferStager`` and
-    has no such method has no copy to start."""
+    has no such method has no copy to start. ``beside_steps``: whether
+    the caller's steps may run while the bytes cross; a stager that
+    would do something for their sake (``ArrayBufferStager.beside_steps``)
+    is told where they cannot."""
     start = getattr(stager, "start_dtoh", None)
-    return 0 if start is None else int(start())
+    if start is None:
+        return 0
+    if not beside_steps and hasattr(stager, "beside_steps"):
+        stager.beside_steps = False
+    return int(start())
 
 
 @dataclass

@@ -37,6 +37,7 @@ Semantics preserved:
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
 import socket
 import threading
@@ -44,7 +45,7 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
-from typing import Callable, List, Optional, Set
+from typing import Callable, Iterator, List, Optional, Set
 
 import psutil
 
@@ -418,6 +419,21 @@ class PendingIOWork:
     def wait_staged(self, timeout: Optional[float] = None) -> bool:
         return self.scheduler.staging_done_event.wait(timeout)
 
+    @contextlib.contextmanager
+    def caller_waits(self) -> Iterator[None]:
+        """Around a caller's wait for the staging
+        (``PendingSnapshot.wait_staged``): a thread that stands there
+        dispatches no step, which the scheduler reads as it starts the
+        copies to the host (``_WriteScheduler._steps_may_run``)."""
+        scheduler = self.scheduler
+        with scheduler.waiting_lock:
+            scheduler.callers_waiting += 1
+        try:
+            yield
+        finally:
+            with scheduler.waiting_lock:
+                scheduler.callers_waiting -= 1
+
     def drained(self) -> bool:
         """Whether THIS RANK's write drain (all writes + COW verifies)
         finished — under COW this, not staging-complete, is when live
@@ -760,6 +776,10 @@ class _WriteScheduler:
         # finishes — the COW-mode safe-to-mutate boundary, strictly
         # earlier than the cross-rank commit barrier.
         self.drained_event = threading.Event()
+        # Threads inside ``PendingSnapshot.wait_staged()`` now
+        # (``PendingIOWork.caller_waits``).
+        self.callers_waiting = 0
+        self.waiting_lock = threading.Lock()
         self._stall_start: Optional[float] = None
         self._stage_phase_start = tele.now() if tele is not None else 0.0
         self._window_index = 0
@@ -796,6 +816,17 @@ class _WriteScheduler:
                 asyncio.ensure_future(head.stage(self.executor))
             )
 
+    def _steps_may_run(self) -> bool:
+        """Whether the caller may dispatch steps while the copy about to
+        start crosses: only a pipelined async take hands control back
+        before the staging is complete (every other take's caller stands
+        in the take until then), and a caller that has come back to wait
+        for the staging (``wait_staged()``, before a step that donates)
+        stands still again. A copy on the chip protects those steps and
+        costs the transfer behind it the copy's time, so it is made only
+        where they may run."""
+        return self.pipelined and not self.callers_waiting
+
     def _start_dtoh_ahead(self) -> None:
         """Start the copy to the host of the request just dispatched
         (already off ``pipelines``) and of those that follow it in the
@@ -815,7 +846,9 @@ class _WriteScheduler:
             ):
                 break
             pipeline = self._dtoh_ahead.popleft()
-            started = stager_start_dtoh(pipeline.write_req.buffer_stager)
+            started = stager_start_dtoh(
+                pipeline.write_req.buffer_stager, self._steps_may_run()
+            )
             if not started:
                 continue
             pipeline.dtoh_unfetched = started

@@ -3366,23 +3366,26 @@ class PendingSnapshot(_BackgroundWork):
         import time as _time
 
         deadline = None if timeout is None else _time.monotonic() + timeout
-        while True:
-            step = 0.05
-            if deadline is not None:
-                remaining = deadline - _time.monotonic()
-                if remaining <= 0:
+        # The thread that waits here runs no step: the drain starts no
+        # copy on the chip for its sake meanwhile (scheduler._steps_may_run).
+        with self._pending_io_work.caller_waits():
+            while True:
+                step = 0.05
+                if deadline is not None:
+                    remaining = deadline - _time.monotonic()
+                    if remaining <= 0:
+                        return self.staged()
+                    step = min(step, remaining)
+                settled = (
+                    self._pending_io_work.wait_drained(step)
+                    if self._cow_rendezvous
+                    else self._pending_io_work.wait_staged(step)
+                )
+                if settled:
+                    return True
+                if self.done():
+                    self._join_and_reraise()
                     return self.staged()
-                step = min(step, remaining)
-            settled = (
-                self._pending_io_work.wait_drained(step)
-                if self._cow_rendezvous
-                else self._pending_io_work.wait_staged(step)
-            )
-            if settled:
-                return True
-            if self.done():
-                self._join_and_reraise()
-                return self.staged()
 
     def wait(self) -> Snapshot:
         self._join_and_reraise()
