@@ -1,7 +1,10 @@
 """When a leaf's copy to the host is started (PR 41): never when its
 stager is built, by the write scheduler when its dispatch reaches the
 request or a fixed depth before, and by the codec policy's sampler for
-its one source. Counts and orders on the CPU, never a rate."""
+its one source. The depth is PR 41's where the caller's steps may run
+beside the copies, and four times that, or the take's host-memory budget
+where it is less, where none can (PR 52: the caller stands in the take, or
+in ``wait_staged()``). Counts and orders on the CPU, never a rate."""
 
 import asyncio
 import importlib.util
@@ -30,6 +33,7 @@ from tpusnap.storage_plugins.fs import FSStoragePlugin
 
 MIB = 1 << 20
 DEPTH = scheduler_mod._DTOH_LOOKAHEAD_BYTES
+DEEP = scheduler_mod._DTOH_LOOKAHEAD_BYTES_NO_STEPS
 REQS = scheduler_mod._DTOH_LOOKAHEAD_REQS
 
 
@@ -138,8 +142,17 @@ class _Recorded(BufferStager):
     def get_staging_cost_bytes(self) -> int:
         return self.nbytes
 
+    def aliases_caller_memory(self) -> bool:
+        return False  # as an accelerator's leaf: an async take returns before it is staged
 
-def _run_recorded(tmp_path, sizes, host=(), threads=1):
+
+# Who could dispatch a step while the copies cross: the caller of a
+# pipelined ``async_take`` that has returned; nobody once that caller
+# stands in ``wait_staged()``, and nobody under ``take``.
+WHO = ["a_step_may_run", "the_caller_waits", "the_caller_stands_in_the_take"]
+
+
+def _run_recorded(tmp_path, sizes, host=(), threads=1, who="a_step_may_run", budget=1 << 40):
     """One pass of the write scheduler over recording stagers of
     ``sizes`` (those at the indices ``host`` have no copy to start);
     returns the log, the queue's order and the take's summary."""
@@ -155,9 +168,17 @@ def _run_recorded(tmp_path, sizes, host=(), threads=1):
     async def go():
         with telemetry.use(rec):
             pending = await execute_write_reqs(
-                write_reqs, FSStoragePlugin(str(tmp_path)), 1 << 40, rank=0
+                write_reqs, FSStoragePlugin(str(tmp_path)), budget, rank=0,
+                pipelined_staging=who != "the_caller_stands_in_the_take",
             )
-            await pending.complete()
+            if who == "the_caller_waits":
+                # The take has returned with its first requests dispatched;
+                # its caller comes back to wait for the rest.
+                log.append(("caller_waits", order[0]))
+                with pending.caller_waits():
+                    await pending.complete()
+            else:
+                await pending.complete()
 
     with override_stage_threads(threads):
         asyncio.run(go())
@@ -177,29 +198,43 @@ def _run_recorded(tmp_path, sizes, host=(), threads=1):
     ],
     ids=["256MiB", "64MiB", "16MiB", "over_the_depth", "the_dense_cell", "host_leaves_between", "two_threads"],
 )
-def test_copies_start_in_staging_order_a_fixed_depth_ahead(tmp_path, sizes, host, threads):
-    log, order, by_name, summary = _run_recorded(tmp_path, sizes, host=host, threads=threads)
+@pytest.mark.parametrize("who", WHO)
+def test_copies_start_in_staging_order_a_fixed_depth_ahead(tmp_path, sizes, host, threads, who):
+    """The depth is ``_DTOH_LOOKAHEAD_BYTES`` beside a caller that may
+    step. With nobody to step it is ``_DTOH_LOOKAHEAD_BYTES_NO_STEPS``
+    (the host-memory budget is larger here): that much is under way at
+    the first dispatch (``take``), or as soon as the caller has come to
+    wait, and what was started past the stepping depth is counted."""
+    log, order, by_name, summary = _run_recorded(tmp_path, sizes, host=host, threads=threads, who=who)
     copying = [n for n in order if by_name[n].copies]
     starts = [n for kind, n in log if kind == "start"]
     assert starts == copying  # in the queue's order, each once
     assert [n for kind, n in log if kind == "stage"] == order
     position = {n: i for i, n in enumerate(order)}
     started, unfetched, dispatched, peak, ahead_starts = set(), 0, -1, 0, 0
+    deep, deep_starts, deep_bytes = who == "the_caller_stands_in_the_take", 0, 0
+    n_staged = 0  # with one thread, the request being dispatched when a copy starts
     for kind, name in log:
         i = position[name]
-        if kind == "stage":
+        if kind == "caller_waits":
+            deep = True
+        elif kind == "stage":
             dispatched = max(dispatched, i)
             # Its own copy before its staging, and the next request's too.
             due = [n for n in order[i : i + 1 + REQS] if by_name[n].copies]
             assert set(due) <= started, (name, due)
         elif kind == "staged":
             unfetched -= by_name[name].nbytes if by_name[name].copies else 0
+            n_staged += 1
         else:
             # Never more than the depth ahead of the last request
             # dispatched: the next one always, further ones while under
             # the depth. (A request is dispatched, and its lookahead
             # run, before its staging is logged: hence `+ threads`.)
-            assert i <= dispatched + threads + REQS or unfetched < DEPTH, (name, dispatched, unfetched)
+            within = i <= dispatched + threads + REQS or unfetched < DEPTH
+            assert within or (deep and unfetched < DEEP), (name, dispatched, unfetched)
+            if not (i <= n_staged + REQS or unfetched < DEPTH):  # past PR 41's depth
+                deep_starts, deep_bytes = deep_starts + 1, deep_bytes + by_name[name].nbytes
             ahead_starts += i > dispatched + 1
             started.add(name)
             unfetched += by_name[name].nbytes
@@ -208,12 +243,57 @@ def test_copies_start_in_staging_order_a_fixed_depth_ahead(tmp_path, sizes, host
     largest = max(sizes)
     bound = max(DEPTH + largest, (1 + REQS) * largest)
     gauge = summary["gauges"]["dtoh.unfetched_bytes"]
-    assert peak <= gauge <= bound + (threads - 1) * largest, (peak, gauge, bound)
-    lookahead = summary["counters"]["dtoh.lookahead_starts"]
+    counters = summary["counters"]
+    if who == "a_step_may_run":
+        assert peak <= gauge <= bound + (threads - 1) * largest, (peak, gauge, bound)
+        assert "dtoh.deep_starts" not in counters and "dtoh.deep_bytes" not in counters
+    else:
+        # The caller's arrival (or the take's first dispatch) starts what
+        # it finds unstarted, up to the depth, before another request is staged.
+        arrival = log.index(("caller_waits", order[0])) if who == "the_caller_waits" else 0
+        staged_next = [k for k, e in enumerate(log) if e[0] == "stage" and k > arrival]
+        before_it = log[: staged_next[0]] if staged_next else log
+        at_arrival = sum(by_name[n].nbytes for kind, n in before_it if kind == "start")
+        assert at_arrival >= min(DEEP, sum(by_name[n].nbytes for n in copying))
+        assert peak == gauge <= max(DEEP + largest, (1 + REQS) * largest) + (threads - 1) * largest
+        if threads == 1:
+            assert counters.get("dtoh.deep_starts", 0) == deep_starts
+            assert counters.get("dtoh.deep_bytes", 0) == deep_bytes
+        assert deep_starts > 0 or sum(by_name[n].nbytes for n in copying[: 1 + REQS]) + largest > DEPTH
+    lookahead = counters["dtoh.lookahead_starts"]
     # All but the first request's own, which the first dispatch starts.
     assert 0 < lookahead <= len(copying) - (by_name[order[0]].copies)
     if threads == 1:
         assert lookahead == ahead_starts
+
+
+@pytest.mark.parametrize("threads", [1, 2], ids=["one_thread", "two_threads"])
+@pytest.mark.parametrize("who", WHO[1:])
+def test_with_nobody_to_step_the_host_memory_budget_is_the_depth(tmp_path, who, threads):
+    """Twelve leaves of 64 MiB under a host-memory budget of 512 MiB,
+    which is past PR 41's depth and short of the state: the bytes started
+    and not yet staged stop at the budget (a leaf may straddle it, as one
+    straddles the stepping depth), and further copies start as leaves are
+    staged."""
+    budget, leaf = 512 * MIB, 64 * MIB
+    assert DEPTH < budget < 12 * leaf
+    log, order, by_name, summary = _run_recorded(
+        tmp_path, [leaf] * 12, threads=threads, who=who, budget=budget
+    )
+    assert [n for kind, n in log if kind == "start"] == order
+    unfetched = peak = 0
+    for kind, name in log:
+        if kind == "start":
+            unfetched += leaf
+            peak = max(peak, unfetched)
+        elif kind == "staged":
+            unfetched -= leaf
+    assert peak == summary["gauges"]["dtoh.unfetched_bytes"] == budget
+    counters = summary["counters"]
+    assert counters["dtoh.deep_bytes"] == counters["dtoh.deep_starts"] * leaf
+    # Past the stepping depth from the first dispatch on: every leaf but
+    # those that the stepping depth itself would have had under way then.
+    assert 12 - DEPTH // leaf - threads <= counters["dtoh.deep_starts"] <= 12 - DEPTH // leaf
 
 
 @pytest.mark.parametrize("leaves,leaf_kib,depth_kib", [(6, 64, 64), (8, 64, 128), (12, 16, 64)],

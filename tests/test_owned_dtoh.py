@@ -480,12 +480,12 @@ def test_the_copies_alive_count_against_the_room_until_they_are_let_go(monkeypat
 # ------------------------------------------- where no step runs beside the copy
 
 
-def _scheduler(leaves, **modes):
+def _scheduler(leaves, budget=1 << 30, **modes):
     reqs = [
         WriteReq(path=f"0/w{i}", buffer_stager=_stager(OWNED["f32_rank2"](), f"0/w{i}"))
         for i in range(leaves)
     ]
-    return _WriteScheduler(reqs, None, 1 << 30, rank=0, **modes), [r.buffer_stager for r in reqs]
+    return _WriteScheduler(reqs, None, budget, rank=0, **modes), [r.buffer_stager for r in reqs]
 
 
 def _shut(sched):
@@ -519,15 +519,24 @@ def test_steps_may_run_only_beside_a_take_that_returns_before_it_has_staged(on_a
     )
 
 
-def test_a_caller_inside_wait_staged_gets_no_copy_made_for_its_sake(on_an_accelerator, monkeypatch):
+@pytest.mark.parametrize(
+    "budget,owned",
+    [(96 * 256 * 4, [True, True, False, False, True, True]), (1 << 30, [True, True] + [False] * 4)],
+    ids=["a_host_budget_of_one_leaf", "a_host_budget_over_the_state"],
+)
+def test_a_caller_inside_wait_staged_gets_no_copy_made_for_its_sake(on_an_accelerator, monkeypatch, budget, owned):
     """A donating trainer: the leaves started before it came back to wait
     cross from owned copies, those started while it stands in
     ``wait_staged()`` cross as they lie and are counted as waived, and
-    those started after it left are copied again. Two waiters count twice."""
+    those started after it left are copied again. Two waiters count twice.
+    While it stands there no step can run, so the copies are started four
+    times as far ahead, within the host-memory budget (PR 52): with room
+    for the state, all that are left, at the first dispatch that finds it
+    waiting."""
     from tpusnap.scheduler import PendingIOWork
 
     monkeypatch.setattr("tpusnap.scheduler._DTOH_LOOKAHEAD_BYTES", 0)
-    sched, stagers = _scheduler(6, pipelined_staging=True)
+    sched, stagers = _scheduler(6, budget=budget, pipelined_staging=True)
     pending = PendingIOWork(sched)
     before = _counts()
 
@@ -550,11 +559,15 @@ def test_a_caller_inside_wait_staged_gets_no_copy_made_for_its_sake(on_an_accele
         dispatch()
     finally:
         _shut(sched)
-    assert [st._owned is not None for st in stagers] == [True, True, False, False, True, True]
+    assert [st._owned is not None for st in stagers] == owned
     leaf = stagers[0].arr.nbytes
     assert _grown(before) == _only(
-        owned_leaves=4, owned_bytes=4 * leaf, owned_waived=2, enqueued_bytes=6 * leaf
+        owned_leaves=sum(owned), owned_bytes=sum(owned) * leaf, owned_waived=6 - sum(owned),
+        enqueued_bytes=6 * leaf,
     )
+    # What crossed as it lies stages the host value kept on the caller's
+    # array, and says so to the budget; an owned copy's host value is tpusnap's.
+    assert [st.stages_callers_host_value() for st in stagers] == [not o for o in owned]
 
 
 def test_wait_staged_tells_the_scheduler_that_its_caller_waits(tmp_path, monkeypatch):

@@ -197,10 +197,10 @@ class Parked:
         jax.block_until_ready(types.SimpleNamespace(block_until_ready=self.gates["wait_device"].wait))
         jax.device_put(1.0)
         pending = PendingSnapshot.__new__(PendingSnapshot)
-        pending._cow_rendezvous = False
         pending._done = threading.Event()
         pending._pending_io_work = types.SimpleNamespace(
-            wait_staged=self.gates["tpusnap"].wait, caller_waits=contextlib.nullcontext)
+            wait_staged=self.gates["tpusnap"].wait, caller_waits=contextlib.nullcontext,
+            went_cow=lambda: False)
         pending.wait_staged()
 
     def frame(self):

@@ -109,6 +109,9 @@ class BatchedBufferStager(BufferStager):
         # members: [(offset, nbytes, stager)]
         self.members = members
         self.total = sum(n for _, n, _ in members)
+        # Whether a member handed over the caller's live bytes under
+        # copy-on-write (``io_types.stager_went_cow``).
+        self.took_cow_members = False
         _note_members(members)
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
@@ -147,6 +150,7 @@ class BatchedBufferStager(BufferStager):
                 # a blob whose checksum mismatches its bytes.
                 stager.verify_cow_after_write(slab[offset : offset + nbytes])
                 stager.cow_pending = False
+                self.took_cow_members = True
             from ._staging_pool import release
 
             release(buf)  # async member clones reuse warm pages next take
@@ -225,6 +229,9 @@ class DeviceBatchedBufferStager(BufferStager):
     def __init__(self, members: List[Tuple[int, int, ArrayBufferStager]]) -> None:
         self.members = members
         self.total = sum(n for _, n, _ in members)
+        # The host fallback's answer (``BatchedBufferStager``); a slab
+        # packed on the device took no live bytes.
+        self.took_cow_members = False
         _note_members(members)
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
@@ -243,9 +250,10 @@ class DeviceBatchedBufferStager(BufferStager):
             logger.warning(
                 "device slab packing failed (%s); falling back to host packing", e
             )
-            return await BatchedBufferStager(list(self.members)).stage_buffer(
-                executor
-            )
+            on_host = BatchedBufferStager(list(self.members))
+            slab = await on_host.stage_buffer(executor)
+            self.took_cow_members = on_host.took_cow_members
+            return slab
 
     def _stage_blocking(self) -> BufferType:
         from .knobs import is_checksum_disabled

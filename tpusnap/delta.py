@@ -616,6 +616,12 @@ class DeltaStream:
             plan = self._plan_open()
             plan["inc"] = uuid.uuid4().hex[:8]
         plan = self._comm.broadcast_object(plan, src=0)
+        # Every rank of the world is inside this open before the root
+        # says `live` (the broadcast has no barrier of its own): a rank
+        # that read the registration a few tens of milliseconds after
+        # rank 0 wrote it would join solo (`_open_join`, no collective)
+        # and leave rank 0 waiting in the base take's first gather.
+        self._comm.barrier()
         self._apply_plan(plan)
         self._inc = plan["inc"]
         self._members = list(range(self._comm.world_size))

@@ -21,7 +21,11 @@ seen on the host (``_crosses_owned``; docs/design.md, "The owned
 crossing": the steps beside a draining take lose a third less). A caller
 that stands in the take or in ``wait_staged()`` runs no step the copy could
 protect and would only wait for it, and a device that has not the room
-free keeps it for the caller: the leaf then crosses as it lies.
+free keeps it for the caller: the leaf then crosses as it lies. Such a
+leaf's host value is kept by the runtime on the caller's own array, and
+staging stages that value (unless it has to turn or compress it): the end
+of its write frees nothing, so the write scheduler does not charge it to
+its staging budget (``stages_callers_host_value``).
 
 Differences by design:
 - JAX arrays are immutable, so the reference's in-place load
@@ -136,6 +140,21 @@ def _lies_on_one_accelerator(arr: ArrayLike) -> bool:
         and len(arr.devices()) == 1
         and not _may_alias_live_memory(arr, None)
     )
+
+
+def _reaches_host_turned(arr: jax.Array) -> bool:
+    """Whether ``np.asarray(arr)`` will come in another order than C, for
+    ``_relayout`` to turn into a buffer of tpusnap's own: read off the
+    device's own layout of the leaf (a TPU lays a leaf whose minor
+    dimension is no multiple of 128 out with its dimensions swapped, and
+    the host value keeps that order), before a byte has crossed. A
+    runtime that cannot be asked gets the answer that is charged."""
+    if arr.ndim < 2 or array_nbytes(arr) < RELAYOUT_MIN_BYTES:
+        return False
+    try:
+        return tuple(arr.format.layout.major_to_minor) != tuple(range(arr.ndim))
+    except Exception:
+        return True
 
 
 def _is_out_of_device_memory(e: BaseException) -> bool:
@@ -265,6 +284,16 @@ class ArrayBufferStager(BufferStager):
         # until its bytes are seen on the host (see _crosses_owned): what
         # copy_to_host_async() was called on.
         self._owned: Optional[jax.Array] = None
+        # Set where the leaf's copy was started from the caller's own
+        # buffer on an accelerator (it crosses as it lies): the host
+        # value then lives on the caller's array
+        # (``stages_callers_host_value``).
+        self._as_it_lies = False
+        # The leaf's host value is in another order than C and staging
+        # turns it into a pooled buffer: foreseen from the device's
+        # layout when the copy is started, set by staging where it
+        # turned one that was not foreseen.
+        self._turned = False
 
     def _prefetches(self) -> bool:
         """Whether ``start_dtoh`` has a copy to start: an accelerator's
@@ -302,8 +331,22 @@ class ArrayBufferStager(BufferStager):
                     else:
                         telemetry.incr("dtoh.owned_waived")
                 if self.dtoh_started is None:
-                    self.dtoh_started = enqueue_dtoh(self.arr)
+                    self._start_as_it_lies()
         return array_nbytes(self.arr) if self.dtoh_started is not None else 0
+
+    def _start_as_it_lies(self) -> None:
+        """Start the transfer of the caller's own buffer. On an
+        accelerator the runtime lands a host value and keeps it with the
+        caller's array; staging stages that value unless it has to turn
+        it."""
+        self.dtoh_started = enqueue_dtoh(self.arr)
+        if (
+            self.dtoh_started is not None
+            and not self.in_slab  # a member's bytes are staged by its slab
+            and _lies_on_one_accelerator(self.arr)
+        ):
+            self._as_it_lies = True
+            self._turned = _reaches_host_turned(self.arr)
 
     def _start_owned(self) -> Optional[float]:
         """Copy the leaf on the chip and start that copy's transfer;
@@ -352,7 +395,7 @@ class ArrayBufferStager(BufferStager):
                 # it lies, and that crossing is counted too.
                 self._owned = None
                 telemetry.incr("dtoh.owned_fallbacks")
-                enqueue_dtoh(self.arr)
+                self._start_as_it_lies()
         return np.asarray(self.arr)
 
     def host_bytes_are_free(self) -> bool:
@@ -370,6 +413,14 @@ class ArrayBufferStager(BufferStager):
 
     def aliases_caller_memory(self) -> bool:
         return self._aliases_caller_memory
+
+    def stages_callers_host_value(self) -> bool:
+        """See ``BufferStager``: an accelerator leaf that crosses as it
+        lies (known once ``start_dtoh()`` has run) and is neither turned
+        nor compressed. The caller cannot write that host value (its
+        array is immutable; a donating step only drops it, and the staged
+        view keeps it alive), so it is not cloned either."""
+        return self._as_it_lies and not self._turned and self.compress_codec is None
 
     def raise_if_donated(self) -> None:
         """Fail the take if a step has donated (and so deleted) the
@@ -452,6 +503,8 @@ class ArrayBufferStager(BufferStager):
         # and where no other buffer is staged the pool's own array is
         # returned, for the write pipeline to hand back.
         relaid = _relayout(host)
+        if relaid is not None:
+            self._turned = True
         mv = memoryview(relaid) if relaid is not None else array_as_memoryview(host)
         staged = relaid if relaid is not None else mv
         want_crc = self.entry is not None and not is_checksum_disabled()

@@ -180,6 +180,19 @@ class BufferStager(abc.ABC):
         default is the safe answer: it counts."""
         return True
 
+    def stages_callers_host_value(self) -> bool:
+        """Whether what this request stages is the host value that the
+        runtime keeps on the caller's own array (an accelerator leaf
+        that crosses as it lies and is written as it landed): memory
+        the caller holds until it deletes the array, of which the end
+        of the write gives nothing back. The write scheduler charges
+        its staging budget what a write's end frees, so it asks at the
+        dispatch (the leaf's copy has been started by then) and again
+        when the request is staged; every buffer of tpusnap's own (a
+        clone, a slab, a turned or compressed blob, the host value of
+        an owned copy) answers False, which is the default: charged."""
+        return False
+
 
 def stager_aliases_caller_memory(stager: BufferStager) -> bool:
     """``stager.aliases_caller_memory()``; a stager that is no
@@ -200,6 +213,26 @@ def stager_start_dtoh(stager: BufferStager, beside_steps: bool = True) -> int:
     if not beside_steps and hasattr(stager, "beside_steps"):
         stager.beside_steps = False
     return int(start())
+
+
+def stager_stages_callers_host_value(stager: BufferStager) -> bool:
+    """``stager.stages_callers_host_value()``; a stager that has no such
+    method stages memory of its own, and is charged."""
+    ask = getattr(stager, "stages_callers_host_value", None)
+    return False if ask is None else bool(ask())
+
+
+def stager_went_cow(stager: BufferStager) -> bool:
+    """Whether ``stager`` staged the caller's live bytes under
+    copy-on-write: itself (``cow_pending``: the buffer it returned is
+    the live memory, verified after its write) or, a slab, for a member
+    it took so (``took_cow_members``). A take with such a stager lets a
+    caller mutate or donate its state only once this rank's writes have
+    drained (``PendingIOWork.staged``)."""
+    return bool(
+        getattr(stager, "cow_pending", False)
+        or getattr(stager, "took_cow_members", False)
+    )
 
 
 @dataclass

@@ -465,7 +465,12 @@ def get_async_stage_window_bytes() -> Optional[int]:
     does not answer otherwise); an accelerator-resident leaf is held
     by reference and never counts. The background drain stages the
     rest interleaved with storage I/O under this in-flight bound —
-    blocked time and clone RSS are O(window) instead of O(state).
+    blocked time and clone RSS are O(window) instead of O(state). The
+    bound charges the buffers that are tpusnap's own (clones, slabs,
+    turned or compressed blobs, the host value of an owned copy); an
+    accelerator leaf that crosses as it lies is staged as the host
+    value kept on the caller's own array, of which a write's end frees
+    nothing, and is not charged.
     ``0`` disables pipelining: ``async_take`` then stages the WHOLE
     state, device leaves too, before returning (the pre-pipeline strict
     semantics, for callers that mutate host-aliasing state in place or
@@ -486,9 +491,12 @@ def is_async_cow_enabled() -> bool:
     CRC32C(+XXH64) hash of the live bytes and the write path re-hashes
     after the storage write — a mismatch (the caller mutated the array
     mid-take) fails the take loudly instead of committing torn data.
-    ``PendingSnapshot.staged()/wait_staged()`` are COW-aware (they
-    report THIS RANK's write drain), so ``staged() ⟹ safe to mutate``
-    holds exactly as before. ``TPUSNAP_ASYNC_COW=0`` is the escape
+    ``PendingSnapshot.staged()/wait_staged()`` are COW-aware: for a
+    take in which a stager went copy-on-write they report THIS RANK's
+    write drain, so ``staged() ⟹ safe to mutate`` holds exactly as
+    before. They read that off the take, not off this knob: a state of
+    accelerator leaves has no such stager whatever the knob says, and
+    its rendezvous is staging-complete. ``TPUSNAP_ASYNC_COW=0`` is the escape
     hatch back to defensive cloning, which strengthens the guarantee
     from "mutation is detected and fails the take" to "mutation cannot
     corrupt" at the cost of a full clone pass per take."""

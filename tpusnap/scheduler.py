@@ -31,7 +31,10 @@ Semantics preserved:
 - Memory budget = min(0.6 × available host RAM / local_world_size, 32GB),
   env-overridable; local world size discovered by all-gathering hostnames
   (scheduler.py:27-65). Pipelined async takes further clamp their
-  in-flight staging budget to TPUSNAP_ASYNC_STAGE_WINDOW_BYTES.
+  in-flight staging budget to TPUSNAP_ASYNC_STAGE_WINDOW_BYTES. The
+  budget charges a request what the end of its write gives back: an
+  accelerator leaf staged as the host value that the runtime keeps on
+  the caller's own array is charged nothing.
 """
 
 from __future__ import annotations
@@ -59,7 +62,9 @@ from .io_types import (
     WriteReq,
     run_on_loop,
     stager_aliases_caller_memory,
+    stager_stages_callers_host_value,
     stager_start_dtoh,
+    stager_went_cow,
     start_all_workers,
 )
 from .knobs import get_memory_budget_override_bytes
@@ -70,7 +75,8 @@ import os as _os
 
 _MAX_IO_CONCURRENCY = 16
 # How far ahead of the request it dispatches the write scheduler starts
-# copies to the host (`_WriteScheduler._start_dtoh_ahead`): the
+# copies to the host (`_WriteScheduler._start_dtoh_ahead`) where a step
+# of the caller's may run beside them (`_steps_may_run`): the
 # dispatched request's own and the next one's always, further ones while
 # the bytes started and not yet staged are under this many. On the TPU
 # runtime a program dispatched after a copy waits behind it, and how long
@@ -81,6 +87,17 @@ _MAX_IO_CONCURRENCY = 16
 # and they reach the host at 1.6 GB/s two in flight, 2.9 GB/s at eight).
 _DTOH_LOOKAHEAD_REQS = 1
 _DTOH_LOOKAHEAD_BYTES = 256 * 1024 * 1024
+# The same depth where no step can run beside the copies (the caller
+# stands in the take, or in `wait_staged()`), and so nobody is held up
+# behind them; the take's host-memory budget where that is less. Copies
+# queued together share the bus, so each is seen on the host later than
+# it would be alone and its write starts later: on a v5e a state of 4.86
+# GB in leaves of 192-256 MiB was all on the host 1.55-1.68 s after
+# `async_take`'s return at 256 MiB, 1.42-1.51 s at 1 GiB and 1.35-1.46 s
+# with every copy queued, and durable after 2.2-2.4, 2.4-2.7 and 3.0-4.1
+# s (PERF.md 6, PR 52): past four leaves the wait gains little and the
+# time to durable pays for it.
+_DTOH_LOOKAHEAD_BYTES_NO_STEPS = 1024 * 1024 * 1024
 # Staging/consume threads do memory-bandwidth work (memcpy, CRC,
 # deserialize) with the GIL released; more threads than cores only adds
 # GIL ping-pong and context switching (measured on the 1-vCPU dev host:
@@ -419,15 +436,40 @@ class PendingIOWork:
     def wait_staged(self, timeout: Optional[float] = None) -> bool:
         return self.scheduler.staging_done_event.wait(timeout)
 
+    def went_cow(self) -> bool:
+        """Whether a stager of this take staged the caller's live bytes
+        under copy-on-write (``io_types.stager_went_cow``). Every stager
+        has decided by staging-complete; before it the answer may still
+        turn true."""
+        return self.scheduler.went_cow
+
+    def safe_to_mutate(self) -> bool:
+        """Whether no byte the caller can write or delete is read any
+        more: staging is complete and, where a stager went copy-on-write
+        (its blob is written from the live memory and verified after),
+        THIS RANK's writes have drained too. Read off what the take did,
+        not off ``TPUSNAP_ASYNC_COW``: on an accelerator no stager goes
+        copy-on-write, and the staged host values are out of the caller's
+        reach."""
+        return self.staging_complete() and (
+            not self.went_cow() or self.drained()
+        )
+
     @contextlib.contextmanager
     def caller_waits(self) -> Iterator[None]:
         """Around a caller's wait for the staging
         (``PendingSnapshot.wait_staged``): a thread that stands there
         dispatches no step, which the scheduler reads as it starts the
-        copies to the host (``_WriteScheduler._steps_may_run``)."""
+        copies to the host (``_WriteScheduler._steps_may_run``). The
+        drain is told at once (a callback on its loop, which may start
+        every remaining copy now: ``_start_dtoh_ahead``), not at its
+        next completion: that is a whole leaf's crossing away, and until
+        then only the copies started for a caller that might step are
+        under way."""
         scheduler = self.scheduler
         with scheduler.waiting_lock:
             scheduler.callers_waiting += 1
+        scheduler.look_again()
         try:
             yield
         finally:
@@ -484,6 +526,11 @@ class _WritePipeline:
         # Bytes of this request's copy to the host that the scheduler
         # started and staging has not fetched yet.
         self.dtoh_unfetched = 0
+        # What of the staging budget this request holds now: its
+        # staging cost from the dispatch, its staged buffer's size from
+        # staging's end to its write's; nothing, all along, where it
+        # stages the caller's own host value.
+        self.charged = 0
 
     async def stage(self, executor: ThreadPoolExecutor) -> "_WritePipeline":
         from .io_types import SKIP_WRITE
@@ -600,7 +647,14 @@ class _WriteScheduler:
     request is staged AND written. A request's copy to the host is
     started when the dispatch reaches it, a fixed depth ahead of the
     thread that fetches it (``_start_dtoh_ahead``): never for the whole
-    state at once, whatever the mode. Three modes:
+    state at once, and four times as deep where no step of the
+    caller's can run beside the copies (the caller stands in the take,
+    or in ``wait_staged()``). While a caller waits
+    for the staging the take runs at the bus's pace and storage's pace
+    is behind it: the leaves it waits for cross as they lie, their host
+    values stay with the caller's arrays and are not charged to the
+    staging budget, and the wait ends at staging-complete unless a
+    stager went copy-on-write (``PendingIOWork.safe_to_mutate``). Three modes:
 
     - default (sync takes): blocked window = staging complete, staging
       and storage I/O fully overlapped throughout (the metric is total
@@ -732,6 +786,10 @@ class _WriteScheduler:
             telemetry.incr(
                 "scheduler.window_released_bytes", released_cost, rec=tele
             )
+        # What the process may hold on the host for this take, before the
+        # window's clamp: it bounds the copies started ahead while no
+        # step can run beside them (``_start_dtoh_ahead``).
+        self.host_budget_bytes = memory_budget_bytes
         if self.pipelined:
             window = get_async_stage_window_bytes()
             if window is not None:
@@ -740,9 +798,15 @@ class _WriteScheduler:
                 # over-budget admission), whatever the host-RAM budget
                 # would allow.
                 memory_budget_bytes = min(memory_budget_bytes, window)
-        # The budget governs IN-FLIGHT staging buffers: every dispatch
-        # debits staging_cost, every write completion credits buf_size —
-        # unconditionally. Buffers the staging pool retains after a
+        # The budget governs IN-FLIGHT staging buffers of tpusnap's own:
+        # a dispatch debits staging_cost, a write completion credits
+        # buf_size. A request that stages the host value kept on the
+        # caller's own array (``BufferStager.stages_callers_host_value``:
+        # an accelerator leaf that crosses as it lies) is debited and
+        # credited nothing: the end of its write frees no byte, so
+        # holding its staging back for the writers would bound nothing
+        # (``scheduler.uncharged_bytes`` counts what went so). Every
+        # other request is charged unconditionally. Buffers the staging pool retains after a
         # write are NOT withheld from the credit (ADVICE r4: withholding
         # re-debited the same resident bytes every reuse cycle, and a
         # budget-capped take whose cumulative clone bytes exceeded the
@@ -776,10 +840,17 @@ class _WriteScheduler:
         # finishes — the COW-mode safe-to-mutate boundary, strictly
         # earlier than the cross-rank commit barrier.
         self.drained_event = threading.Event()
+        # Whether a stager staged the caller's live bytes under
+        # copy-on-write: read off each request as its staging ends, so
+        # final at staging-complete (``PendingIOWork.safe_to_mutate``).
+        self.went_cow = False
         # Threads inside ``PendingSnapshot.wait_staged()`` now
         # (``PendingIOWork.caller_waits``).
         self.callers_waiting = 0
         self.waiting_lock = threading.Lock()
+        # The loop that drives this take, from its first turn
+        # (``look_again``).
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._stall_start: Optional[float] = None
         self._stage_phase_start = tele.now() if tele is not None else 0.0
         self._window_index = 0
@@ -800,21 +871,52 @@ class _WriteScheduler:
             # in-flight stagings completed in one wait batch before any
             # I/O was dispatched) and unenforced the budget entirely.
             in_flight = self.staging_tasks or self.io_tasks or self.ready_for_io
-            if head.staging_cost > self.budget and in_flight:
+            if self._charge(head) > self.budget and in_flight:
                 break  # wait for memory to free up
             self.pipelines.popleft()
             self._start_dtoh_ahead()
-            self.budget -= head.staging_cost
-            if self.tele is not None:
-                # High-water mark of budget in use (can exceed the
-                # budget via the ≥1 over-budget admission).
-                self.tele.gauge_max(
-                    "scheduler.budget_used_bytes",
-                    self.memory_budget_bytes - self.budget,
-                )
+            # Its copy has been started by now, so its stager knows how
+            # the leaf crosses (the first request of a take is the one
+            # whose copy the line above started: it was admitted as
+            # charged, and any take admits its first).
+            self._settle(head, self._charge(head))
             self.staging_tasks.add(
                 asyncio.ensure_future(head.stage(self.executor))
             )
+
+    def _charge(self, pipeline: "_WritePipeline") -> int:
+        """What dispatching ``pipeline`` debits: its staging cost, or
+        nothing where it stages the caller's own host value."""
+        if stager_stages_callers_host_value(pipeline.write_req.buffer_stager):
+            return 0
+        return pipeline.staging_cost
+
+    def _settle(self, pipeline: "_WritePipeline", holds: int) -> None:
+        """``pipeline`` holds ``holds`` bytes of the budget from now on,
+        whatever it held before."""
+        self.budget += pipeline.charged - holds
+        pipeline.charged = holds
+        if self.tele is not None:
+            # High-water mark of budget in use (can exceed the budget
+            # via the ≥1 over-budget admission, or where staging turned
+            # a leaf that its device's layout had not foretold).
+            self.tele.gauge_max(
+                "scheduler.budget_used_bytes",
+                self.memory_budget_bytes - self.budget,
+            )
+
+    def look_again(self) -> None:
+        """From any thread: have the loop that drives this take run the
+        lookahead now, because what it reads has changed (a caller has
+        come to wait). Nothing before the loop's first turn, which reads
+        it anyway, and nothing once the loop is closed."""
+        loop = self._loop
+        if loop is None:
+            return
+        try:
+            loop.call_soon_threadsafe(self._start_dtoh_ahead)
+        except RuntimeError:
+            pass  # closed: the take is over
 
     def _steps_may_run(self) -> bool:
         """Whether the caller may dispatch steps while the copy about to
@@ -831,23 +933,34 @@ class _WriteScheduler:
         """Start the copy to the host of the request just dispatched
         (already off ``pipelines``) and of those that follow it in the
         queue, ``_DTOH_LOOKAHEAD_REQS`` of them at least and further
-        while the bytes started and not yet staged are under
-        ``_DTOH_LOOKAHEAD_BYTES``. By position in the queue, whether or
-        not the budget admits those requests yet: this is the prefetch,
-        and the depth is what bounds the host copies that the runtime
-        holds outside the budget. With more than one staging thread the
+        while the bytes started and not yet staged are under the depth:
+        ``_DTOH_LOOKAHEAD_BYTES`` where the caller's steps may run
+        beside the copies (a step dispatched after a copy waits behind
+        it), ``_DTOH_LOOKAHEAD_BYTES_NO_STEPS`` or the take's
+        host-memory budget, whichever is less, where none can (nobody
+        is there to be held up, and the bus is kept full;
+        ``dtoh.deep_starts`` / ``dtoh.deep_bytes`` count what was
+        started past the stepping depth). By position in the queue, whether or not the staging
+        budget admits those requests yet: this is the prefetch, and the
+        depth is what bounds the host copies that the runtime holds
+        outside that budget. With more than one staging thread the
         depth counts from the last request dispatched."""
+        beside_steps = self._steps_may_run()
+        depth = (
+            _DTOH_LOOKAHEAD_BYTES
+            if beside_steps
+            else min(_DTOH_LOOKAHEAD_BYTES_NO_STEPS, self.host_budget_bytes)
+        )
         while self._dtoh_ahead:
             # -1: the dispatched request itself, not asked before.
             ahead = len(self.pipelines) - len(self._dtoh_ahead)
-            if (
-                ahead >= _DTOH_LOOKAHEAD_REQS
-                and self.dtoh_unfetched_bytes >= _DTOH_LOOKAHEAD_BYTES
-            ):
+            further = ahead >= _DTOH_LOOKAHEAD_REQS
+            if further and self.dtoh_unfetched_bytes >= depth:
                 break
+            deep = further and self.dtoh_unfetched_bytes >= _DTOH_LOOKAHEAD_BYTES
             pipeline = self._dtoh_ahead.popleft()
             started = stager_start_dtoh(
-                pipeline.write_req.buffer_stager, self._steps_may_run()
+                pipeline.write_req.buffer_stager, beside_steps
             )
             if not started:
                 continue
@@ -855,6 +968,9 @@ class _WriteScheduler:
             self.dtoh_unfetched_bytes += started
             if ahead >= 0:
                 telemetry.incr("dtoh.lookahead_starts", rec=self.tele)
+            if deep:
+                telemetry.incr("dtoh.deep_starts", rec=self.tele)
+                telemetry.incr("dtoh.deep_bytes", started, rec=self.tele)
             if self.tele is not None:
                 self.tele.gauge_max(
                     "dtoh.unfetched_bytes", self.dtoh_unfetched_bytes
@@ -864,7 +980,7 @@ class _WriteScheduler:
         return (
             bool(self.pipelines)
             and len(self.staging_tasks) < self.stage_concurrency
-            and self.pipelines[0].staging_cost > self.budget
+            and self._charge(self.pipelines[0]) > self.budget
         )
 
     def _io_gate_open(self) -> bool:
@@ -974,14 +1090,15 @@ class _WriteScheduler:
             return
         self.staging_complete = True
         self.reporter.mark_staging_complete()
-        if self._stall_start is not None:
-            if self.tele is not None:
-                self.tele.record_span(
-                    "budget_wait",
-                    self._stall_start,
-                    self.tele.now() - self._stall_start,
-                )
-            self._stall_start = None
+        if self.tele is not None:
+            # The episode open at staging's end; empty where none is, so
+            # that a take which never waited for its budget says so (a
+            # reader then reads 0, where no span at all reads as "not
+            # recorded").
+            now = self.tele.now()
+            start = now if self._stall_start is None else self._stall_start
+            self.tele.record_span("budget_wait", start, now - start)
+        self._stall_start = None
         if self.pipelined:
             self._close_window()
             self.reporter.stage_windows = max(self._window_index, 1)
@@ -1000,6 +1117,7 @@ class _WriteScheduler:
     # --- the loop ------------------------------------------------------
 
     async def _pump(self, stop_at_first_window: bool) -> None:
+        self._loop = asyncio.get_running_loop()
         self._dispatch_staging()
         while self.staging_tasks or self.pipelines:
             if stop_at_first_window and self._first_window_done():
@@ -1013,10 +1131,21 @@ class _WriteScheduler:
                 if task in self.staging_tasks:
                     self.staging_tasks.discard(task)
                     pipeline = task.result()  # re-raises staging failure
-                    # Staged buffer may be smaller than the staging cost
-                    # (e.g. cost model overestimates); credit the
-                    # difference.
-                    self.budget += pipeline.staging_cost - pipeline.buf_size
+                    stager = pipeline.write_req.buffer_stager
+                    # From here to its write's end the request holds its
+                    # staged buffer, which may be smaller than the
+                    # staging cost (e.g. cost model overestimates), or
+                    # nothing where that buffer is the caller's own host
+                    # value (asked again: staging knows what it staged).
+                    uncharged = stager_stages_callers_host_value(stager)
+                    self._settle(pipeline, 0 if uncharged else pipeline.buf_size)
+                    if uncharged and pipeline.buf_size:
+                        telemetry.incr(
+                            "scheduler.uncharged_bytes",
+                            pipeline.buf_size,
+                            rec=self.tele,
+                        )
+                    self.went_cow = self.went_cow or stager_went_cow(stager)
                     self.dtoh_unfetched_bytes -= pipeline.dtoh_unfetched
                     self.eager_pending.discard(id(pipeline))
                     # Heartbeat feed: bytes past the staging stage (the
@@ -1033,12 +1162,7 @@ class _WriteScheduler:
                     else:
                         self.ready_for_io.append(pipeline)
                 elif task in self.io_tasks:
-                    self.io_tasks.discard(task)
-                    pipeline = task.result()
-                    self.budget += pipeline.buf_size
-                    if self.probe is not None:
-                        self.probe.note_written(pipeline.buf_size)
-                    self.reporter.report_request_done(pipeline.buf_size)
+                    self._on_written(task)
             # Staging first: the I/O gate must see the REFILLED staging
             # set, or it opens spuriously in the instant between one
             # stager finishing and the next starting.
@@ -1048,7 +1172,16 @@ class _WriteScheduler:
             self._update_reporter()
         self._finish_staging()
 
+    def _on_written(self, task: asyncio.Task) -> None:
+        self.io_tasks.discard(task)
+        pipeline = task.result()  # re-raises a write's or a verify's failure
+        self._settle(pipeline, 0)
+        if self.probe is not None:
+            self.probe.note_written(pipeline.buf_size)
+        self.reporter.report_request_done(pipeline.buf_size)
+
     async def _abort(self) -> None:
+        self._dtoh_ahead.clear()  # a callback that looks again starts nothing
         await _cancel_and_drain(self.staging_tasks | self.io_tasks)
         self.executor.shutdown(wait=True)
         self.hash_executor.shutdown(wait=True)
@@ -1092,12 +1225,7 @@ class _WriteScheduler:
                     self.io_tasks, return_when=asyncio.FIRST_COMPLETED
                 )
                 for task in done:
-                    self.io_tasks.discard(task)
-                    pipeline = task.result()
-                    self.budget += pipeline.buf_size
-                    if self.probe is not None:
-                        self.probe.note_written(pipeline.buf_size)
-                    self.reporter.report_request_done(pipeline.buf_size)
+                    self._on_written(task)
                 self._update_reporter()
             if (
                 self.probe is not None

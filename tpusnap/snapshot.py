@@ -530,7 +530,6 @@ class Snapshot:
                 late_checksums=late_checksums,
                 abort_ctx=abort_ctx,
                 tele_commit=tele_commit,
-                force_clone_staging=_force_clone_staging,
             )
         except BaseException as e:
             telemetry.end_take(tele)
@@ -3020,7 +3019,6 @@ class PendingSnapshot(_BackgroundWork):
         late_checksums: Optional["_LateChecksums"] = None,
         abort_ctx: Optional["_TakeAbortContext"] = None,
         tele_commit: Optional["_TelemetryCommit"] = None,
-        force_clone_staging: bool = False,
     ) -> None:
         self.path = path
         self._pending_io_work = pending_io_work
@@ -3033,16 +3031,6 @@ class PendingSnapshot(_BackgroundWork):
         self._abort_ctx = abort_ctx
         self._tele_commit = tele_commit
         self._snapshot: Optional[Snapshot] = None
-        # Captured at take time: under COW the staged() rendezvous must
-        # report the SAFE-TO-MUTATE boundary (writes+verifies drained,
-        # live bytes no longer read), not merely staging-complete.
-        # A force-clone take (delta micro-commits) staged real copies,
-        # so its rendezvous is the plain staging-complete boundary.
-        from .knobs import is_async_cow_enabled
-
-        self._cow_rendezvous = (
-            is_async_cow_enabled() and not force_clone_staging
-        )
 
         # Barrier identity must be agreed on the MAIN thread (this may
         # broadcast); the background thread then only touches the KV store.
@@ -3344,19 +3332,24 @@ class PendingSnapshot(_BackgroundWork):
         name, at every state size: accelerator-resident leaves are
         never in the blocked window.
 
-        Ordinarily this is staging-complete (no buffer aliases live
-        arrays any more): true at construction for non-pipelined takes
+        This is staging-complete (no staged buffer is memory the
+        caller can write or delete) wherever no stager of the take went
+        copy-on-write: true at construction for non-pipelined takes
         (incremental, or window 0); pipelined takes stage their
         accelerator-resident leaves, and what the window did not hold
-        of the rest, on the background drain. Under TPUSNAP_ASYNC_COW
-        the live bytes stay aliased until each blob's write+verify
-        lands, so this reports THIS RANK's write-drain boundary instead
-        (strictly earlier than the cross-rank commit barrier) — the
-        rendezvous CONTRACT (staged() ⟹ safe to mutate) holds either
-        way."""
-        if self._cow_rendezvous:
-            return self._pending_io_work.drained()
-        return self._pending_io_work.staging_complete()
+        of the rest, on the background drain. A stager that went
+        copy-on-write (TPUSNAP_ASYNC_COW, the default: a numpy leaf, a
+        ``pinned_host`` or CPU-backend array, or a slab with such a
+        member) wrote or writes its blob from the live bytes and
+        verifies it after, so a take with one reports THIS RANK's
+        write-drain boundary instead (strictly earlier than the
+        cross-rank commit barrier). Which of the two is read off what
+        the take did (``PendingIOWork.safe_to_mutate``), not off the
+        knob: an accelerator's leaves never go copy-on-write, and a
+        trainer that donates them waits for their bytes to reach the
+        host, not for storage. The rendezvous CONTRACT (staged() ⟹ safe
+        to mutate, safe to donate) holds either way."""
+        return self._pending_io_work.safe_to_mutate()
 
     def wait_staged(self, timeout: Optional[float] = None) -> bool:
         """Block until :meth:`staged` is True (or ``timeout`` elapses;
@@ -3376,12 +3369,12 @@ class PendingSnapshot(_BackgroundWork):
                     if remaining <= 0:
                         return self.staged()
                     step = min(step, remaining)
-                settled = (
-                    self._pending_io_work.wait_drained(step)
-                    if self._cow_rendezvous
-                    else self._pending_io_work.wait_staged(step)
-                )
-                if settled:
+                # Staging first; by then every stager has said whether
+                # it went copy-on-write, and only then the drain.
+                io_work = self._pending_io_work
+                if io_work.wait_staged(step) and (
+                    not io_work.went_cow() or io_work.wait_drained(step)
+                ):
                     return True
                 if self.done():
                     self._join_and_reraise()
